@@ -28,8 +28,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 from benchmark._bench_common import (  # noqa: E402
-    env_int as _env_int, make_mark, guarded_backend_init,
-    start_stall_watchdog, with_last_good)
+    env_int as _env_int, make_mark, place_compile_cache)
 
 _mark = make_mark("dec")
 
@@ -42,9 +41,6 @@ VOCAB = _env_int("DEC_VOCAB", 50304)
 STEPS = _env_int("DEC_STEPS", 64)
 BATCHES = [int(b) for b in
            os.environ.get("DEC_BATCHES", "1,32").split(",")]
-
-_ERR_BASE = {"metric": "decode_tokens_per_sec", "value": None,
-             "unit": "tokens/sec", "vs_baseline": None}
 
 
 def _bench_batch(B, kw):
@@ -94,19 +90,11 @@ def main():
     if cpu_smoke:
         from cpu_pin import pin_cpu
         pin_cpu(1)
-    dev, err = guarded_backend_init(
-        _mark, env_prefix="DEC", error_json=with_last_good(_ERR_BASE),
-        refuse_timeout_parent=not cpu_smoke,
-        enforce_deadline=not cpu_smoke)
-    if dev is None:
-        print(json.dumps(dict(with_last_good(_ERR_BASE),
-                              error="backend init failed: %s" % err)),
-              flush=True)
-        return 1
+    else:
+        place_compile_cache()
+    import jax
+    dev = jax.devices()[0]
     _mark("backend up: %s" % dev.device_kind)
-    if not cpu_smoke or os.environ.get("DEC_STALL_DEADLINE_S"):
-        start_stall_watchdog(_mark, with_last_good(_ERR_BASE),
-                             env_prefix="DEC")
 
     kv = int(KV_HEADS) if KV_HEADS else None
     kw = dict(num_layers=LAYERS, d_model=DMODEL, num_heads=HEADS,

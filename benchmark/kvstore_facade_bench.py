@@ -22,8 +22,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 from benchmark._bench_common import (  # noqa: E402
-    env_int as _env_int, guarded_backend_init, make_hard_sync, make_mark,
-    shrink_iters, start_stall_watchdog, is_cpu_device, bench_log_path)
+    env_int as _env_int, make_hard_sync, make_mark, is_cpu_device,
+    bench_log_path, place_compile_cache)
 
 _mark = make_mark("kvf")
 
@@ -36,21 +36,12 @@ def main():
     if cpu_smoke:
         from cpu_pin import pin_cpu
         pin_cpu(1)
-    dev, err = guarded_backend_init(
-        _mark, env_prefix="KVF", error_json=dict(_ERR_BASE),
-        refuse_timeout_parent=not cpu_smoke,
-        enforce_deadline=not cpu_smoke)
-    if dev is None:
-        print(json.dumps(dict(_ERR_BASE,
-                              error="backend init failed: %s" % err)),
-              flush=True)
-        return 1
-    _mark("backend up: %s" % dev.device_kind)
-    if not cpu_smoke:
-        start_stall_watchdog(_mark, dict(_ERR_BASE), env_prefix="KVF")
-
+    else:
+        place_compile_cache()
     import jax
     import jax.numpy as jnp
+    dev = jax.devices()[0]
+    _mark("backend up: %s" % dev.device_kind)
     import mxnet_tpu as mx
     from mxnet_tpu import models
 
@@ -96,11 +87,6 @@ def main():
         step()
         hard_sync()
         _mark("first step done (compile)")
-        t0 = time.perf_counter()
-        step()
-        hard_sync()
-        probe = time.perf_counter() - t0
-        n_iters = shrink_iters(probe, n_iters, _mark)
         t0 = time.perf_counter()
         for _ in range(n_iters):
             step()

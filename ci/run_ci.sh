@@ -295,7 +295,7 @@ JAX_PLATFORMS=cpu timeout -k 10 240 \
     python tests/dist/dist_sparse_embed.py
 
 echo "== fused-dist smoke (K-step scan over the dist_async wire, overlapped)"
-# The two headline wins finally compose (ISSUE 10 / PERF_NOTES round 10):
+# The two headline wins finally compose (ISSUE 10):
 # run_steps on update-on-kvstore drives the chunked scanned driver — one
 # dispatch per chunk — with the grad-push/weight-pull round overlapped
 # behind the next chunk's compute.  Two workers train eager vs fused

@@ -25,9 +25,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 '..', '..'))
-from cpu_pin import pin_if_cpu  # noqa: E402
-pin_if_cpu()
-
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

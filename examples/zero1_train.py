@@ -20,8 +20,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 '..'))
-from cpu_pin import pin_if_cpu  # noqa: E402
-pin_if_cpu()
 import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu import parallel as par  # noqa: E402
 

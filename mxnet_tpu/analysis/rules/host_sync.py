@@ -1,6 +1,6 @@
 """host-sync: host readbacks in hot-path modules must be deliberate.
 
-The sync-free training loop (docs/PERF_NOTES.md round 8) holds because
+The sync-free training loop holds because
 every device->host readback in the hot path is one of a handful of
 counted, contract-bearing sites: ``NDArray.asnumpy``/``wait_to_read``
 record themselves, ``EvalMetric.sync`` and

@@ -8,10 +8,9 @@ fit-on-the-fly cost model in the TpuGraphs spirit (arXiv:2308.13490):
   ``base.declare_env`` registry's ``tune=`` metadata: an undeclared
   knob can never be tuned (and a target axis naming one is an
   ``env-knob`` lint finding);
-* :mod:`measure` — subprocess executors with the
-  ``fresh_process_probe`` deadline/kill discipline: a hung trial is
-  SIGKILLed (whole process group) and recorded, never serializing the
-  sweep;
+* :mod:`measure` — subprocess executors with a hard deadline: a hung
+  trial is SIGKILLed (whole process group) and recorded, never
+  serializing the sweep;
 * :mod:`targets` — the built-in measurement targets: ``bench``
   (bench.py throughput), ``serving`` (p99/QPS via serving_stats),
   ``failover`` (elastic coordinator-kill rebuild cost), and ``stub``
@@ -24,8 +23,8 @@ fit-on-the-fly cost model in the TpuGraphs spirit (arXiv:2308.13490):
 * :mod:`promote` — winners banked into the per-topology
   BENCH_DEFAULTS.json schema (device kind x host count x worker/server
   count) that bench.py loads for that topology and only that topology;
-* :mod:`history` — seed-import of the banked BENCH_r0*.json rounds and
-  BENCH_LOG.jsonl so the cost model starts warm.
+* :mod:`history` — seed-import of the banked BENCH_LOG.jsonl rows so
+  the cost model starts warm.
 
 Entry point: ``python -m mxnet_tpu.autotune`` (see ``--help``).
 """

@@ -119,8 +119,8 @@ def main(argv=None) -> int:
     ap.add_argument("--no-promote", action="store_true",
                     help="measure and journal only")
     ap.add_argument("--import-history", action="store_true",
-                    help="seed-import BENCH_LOG.jsonl + BENCH_r0*.json "
-                         "into the journal and exit")
+                    help="seed-import BENCH_LOG.jsonl into the journal "
+                         "and exit")
     args = ap.parse_args(argv)
 
     journal = Journal(args.journal or

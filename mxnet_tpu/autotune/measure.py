@@ -1,12 +1,15 @@
 """Measurement executors: run one config in a fresh subprocess.
 
-The ``fresh_process_probe`` discipline (benchmark/_bench_common.py)
-applied to whole trials: every measurement runs in its OWN child
-process with a hard deadline — a config that hangs (the BENCH_r02–r05
-stuck-tunnel shape), OOMs, or crashes is killed/recorded and the sweep
+Every measurement runs in its OWN child process with a hard deadline —
+a config that hangs, OOMs, or crashes is killed/recorded and the sweep
 moves on; nothing a trial does can wedge the harness.  The child's
 whole process GROUP is SIGKILLed on timeout because targets like the
 launcher-driven smokes spawn their own children.
+
+One process per chip: the children run ONE AT A TIME and the parent
+(``python -m mxnet_tpu.autotune``) never initialises a JAX backend — it
+learns the device from the child's JSON row — so each child finds the
+chip free (tests/test_import_hermetic.py pins the import side of that).
 
 Contract with targets: the child prints ONE JSON object line on stdout
 (the bench.py output contract); stderr/progress marks are free-form.

@@ -95,8 +95,7 @@ TARGETS: Dict[str, Target] = {t.name: t for t in [
                "BENCH_REMAT"),
         objective="value", maximize=True,
         doc="bench.py ResNet-50 fused-step throughput (imgs/sec) — the "
-            "queued steps-per-call x batch x remat x layout sweep from "
-            "PERF_NOTES rounds 6-10",
+            "queued steps-per-call x batch x remat x layout sweep",
         defaults_map=(("BENCH_BATCH", "batch"),
                       ("BENCH_DTYPE", "dtype"),
                       ("BENCH_OPT", "opt"),

@@ -1,6 +1,6 @@
 """Training callbacks (reference: python/mxnet/callback.py).
 
-SYNC CONTRACT (the sync-free training loop, docs/PERF_NOTES.md round 8):
+SYNC CONTRACT (the sync-free training loop):
 metric accumulation in fit/score is device-resident, and a callback that
 reads the metric — ``get_name_value()`` → ``EvalMetric.sync()`` — is the
 ONLY point where the host blocks on a device readback.  Callbacks that

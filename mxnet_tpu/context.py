@@ -4,11 +4,12 @@ TPU-native equivalent of the reference's ``Context`` (python/mxnet/context.py,
 include/mxnet/base.h:129-210).  A ``Context`` names a logical device; it maps
 onto a PJRT :class:`jax.Device`.  ``mx.tpu(i)`` is the first-class accelerator
 context (the reference's ``mx.gpu(i)``); ``mx.gpu`` is kept as an alias so
-reference user code runs unchanged.  When no TPU backend is present (unit
-tests run with ``JAX_PLATFORMS=cpu`` and a virtual 8-device CPU mesh),
-``tpu(i)`` transparently resolves to host device *i*, mirroring how the
-reference unit-tests multi-device logic with multiple CPU contexts
-(SURVEY.md §4 "Multi-device (fake cluster)").
+reference user code runs unchanged.  Only when the process was explicitly
+pinned to the CPU platform (``JAX_PLATFORMS=cpu`` — the unit tests, with a
+virtual 8-device CPU mesh) does ``tpu(i)`` resolve to host device *i*,
+mirroring how the reference unit-tests multi-device logic with multiple CPU
+contexts (SURVEY.md §4 "Multi-device (fake cluster)").  Anywhere else
+``tpu(i)`` is chip *i* or an error: it never lands on the host unasked.
 """
 from __future__ import annotations
 
@@ -16,6 +17,18 @@ import threading
 from typing import Optional
 
 from .base import MXNetError
+
+
+def platform_pinned_to_cpu() -> bool:
+    """True when the process selected the CPU platform on purpose
+    (``JAX_PLATFORMS=cpu`` or ``jax.config.update("jax_platforms",
+    "cpu")``, as tests/conftest.py and cpu_pin.pin_cpu do).  THE rule for
+    every place that may stand in for the chip — ``tpu(i)`` resolving to
+    a virtual CPU device, the Pallas kernels running interpreted: a
+    machine that merely lacks a TPU does not qualify."""
+    import jax
+    first = (jax.config.jax_platforms or "").split(",")[0]
+    return first.strip().lower() == "cpu"
 
 
 class Context:
@@ -54,10 +67,23 @@ class Context:
             except RuntimeError:
                 devs = jax.local_devices()
             return devs[self.device_id % len(devs)]
-        # tpu / gpu-alias: prefer a real accelerator, else fall back to the
-        # default backend (virtual CPU devices in tests).
-        devs = jax.local_devices()
-        return devs[self.device_id % len(devs)]
+        # tpu / gpu-alias: a real chip, or (CPU pin only) virtual device i
+        if platform_pinned_to_cpu():
+            devs = jax.local_devices(backend="cpu")
+        else:
+            try:
+                devs = jax.local_devices(backend="tpu")
+            except RuntimeError as e:
+                raise MXNetError(
+                    f"{self} needs a TPU but JAX found none (default "
+                    f"backend: {jax.default_backend()!r}); set "
+                    f"JAX_PLATFORMS=cpu to map tpu(i) onto virtual CPU "
+                    f"devices") from e
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                f"{self} is out of range: this process addresses "
+                f"{len(devs)} {devs[0].platform} device(s)")
+        return devs[self.device_id]
 
     def __hash__(self):
         return hash((self.device_type, self.device_id))

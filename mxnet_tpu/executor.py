@@ -455,8 +455,15 @@ class Executor:
         self._monitor_callback = None
         self._monitor_all = False
         self._mesh = None
-        self._arg_shardings = None   # name -> NamedSharding
-        self._aux_shardings = None
+        # name -> where the program reads that value.  Until a mesh is
+        # set (set_shardings) that is the bound context's device: host-fed
+        # batches (NDArrayIter arrays sit on cpu(0)) and loaded
+        # checkpoints are moved TO the chip instead of dragging the whole
+        # step onto the host backend
+        from jax.sharding import SingleDeviceSharding
+        on_ctx = SingleDeviceSharding(self._ctx.jax_device())
+        self._arg_shardings = dict.fromkeys(arg_names, on_ctx)
+        self._aux_shardings = dict.fromkeys(aux_names, on_ctx)
 
         self._out_arrays: Optional[List[NDArray]] = None
         self._snapshot = None
@@ -626,7 +633,7 @@ class Executor:
             for n in self._aux_names}
 
     def _sharded(self, val, sh):
-        if sh is None:
+        if sh is None or isinstance(val, jax.core.Tracer):
             return val
         cur = getattr(val, "sharding", None)
         if cur is not None:
@@ -635,10 +642,14 @@ class Executor:
             # mismatch here would force the host round-trip below, which
             # cannot work for process-spanning arrays
             try:
-                same = cur.is_equivalent_to(sh, np.ndim(val))
+                same = cur == sh or cur.is_equivalent_to(sh, np.ndim(val))
             except Exception:  # noqa: BLE001 — foreign sharding types
-                same = cur == sh
-            if same:
+                same = False
+            # an uncommitted value (fresh jnp output) on the right device
+            # is still committed below — no copy — because jit keys its
+            # programs on it: the same step fed resident and host-fed
+            # batches would otherwise compile twice
+            if same and val.committed:
                 return val
         if sh.is_fully_addressable:
             return jax.device_put(val, sh)
@@ -652,17 +663,33 @@ class Executor:
         return jax.make_array_from_callback(
             arr.shape, sh, lambda idx: arr[idx])
 
+    def _placed(self, arrays, names, shardings):
+        """Values of ``arrays`` where the program reads them.  Off-mesh a
+        value that had to move is written back (same version handle — the
+        value did not change), so a checkpoint loaded on the host moves
+        once, not once per step."""
+        vals = []
+        for n, a in zip(names, arrays):
+            val = a._data
+            placed = self._sharded(val, shardings[n])
+            if placed is not val and self._mesh is None:
+                a._payload = placed
+            vals.append(placed)
+        return tuple(vals)
+
     def _arg_vals(self):
-        if self._arg_shardings is None:
-            return tuple(a._data for a in self.arg_arrays)
-        return tuple(self._sharded(a._data, self._arg_shardings[n])
-                     for n, a in zip(self._arg_names, self.arg_arrays))
+        return self._placed(self.arg_arrays, self._arg_names,
+                            self._arg_shardings)
 
     def _aux_vals(self):
-        if self._aux_shardings is None:
-            return tuple(a._data for a in self.aux_arrays)
-        return tuple(self._sharded(a._data, self._aux_shardings[n])
-                     for n, a in zip(self._aux_names, self.aux_arrays))
+        return self._placed(self.aux_arrays, self._aux_names,
+                            self._aux_shardings)
+
+    def _placed_like(self, name, val):
+        """``val`` where the program keeps argument ``name`` — for values
+        that meet the program's outputs in a later jit (a batch's labels
+        in a device-resident metric) or stack its inputs (run_steps)."""
+        return self._sharded(val, self._arg_shardings[name])
 
     def _out_aval_list(self, is_train):
         cache = getattr(self, "_aval_cache", None)

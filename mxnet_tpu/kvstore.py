@@ -2044,7 +2044,7 @@ class KVStoreDistAsync(KVStore):
         # devices allow it) and ONLY the per-host leader ships the
         # reduced gradient over the TCP wire, fanning the pulled
         # weights back in-mesh — wire bytes per step drop by ~the
-        # workers-per-host factor (docs/PERF_NOTES.md round 11).
+        # workers-per-host factor.
         self._hier = False
         self._mesh_leader = None    # leader-side endpoint
         self._mesh_conn = None      # follower-side channel to the leader

@@ -262,10 +262,9 @@ def _set_nodelay(sock):
     ``sendall`` calls (header+skeleton, then each raw tensor buffer);
     with Nagle on, the small header write can sit in the kernel waiting
     on the peer's delayed ACK before the tensor bytes follow — a
-    ~40 ms-class stall per frame on a real network (docs/PERF_NOTES.md
-    round 9).  Loopback never shows it, which is exactly why it must be
-    set unconditionally at connect/accept rather than found later on a
-    chip."""
+    ~40 ms-class stall per frame on a real network.  Loopback never
+    shows it, which is exactly why it must be set unconditionally at
+    connect/accept rather than found later on a chip."""
     import socket as _socket
     try:
         sock.setsockopt(_socket.IPPROTO_TCP, _socket.TCP_NODELAY, 1)

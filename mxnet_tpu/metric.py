@@ -13,7 +13,7 @@ Two accumulation paths:
   one (or three) per step.  This is the MXNet paper's "everything stays
   on the async engine" discipline applied to scoring: per-batch
   ``EvalMetric.update`` readbacks were the last host serialization in
-  ``fit``/``score`` (docs/PERF_NOTES.md round 8).
+  ``fit``/``score``.
 
 ``device_update`` is functional (state in, state out) so the same math
 rides a ``lax.scan`` carry: ``Module.run_steps`` folds K steps of

@@ -11,9 +11,18 @@ The backward pass is a dual Pallas kernel in the FA2 style (_flash_bwd
 below): one kernel for dQ, one for dK/dV, both recomputing the attention
 probabilities blockwise from the forward's saved logsumexp — O(S) memory
 end-to-end, with GQA/MQA handled at the block-spec level so repeated KV
-heads are never materialized.  On non-TPU backends the same kernels run
-in pallas interpret mode, so unit tests cover the identical code path
-(SURVEY.md §4 device-consistency strategy).
+heads are never materialized.
+
+Where the kernels run.  A process pinned to the CPU platform
+(``JAX_PLATFORMS=cpu`` — the unit tests) runs them in Pallas interpret
+mode; everywhere else they compile through Mosaic, and a compile error is
+an error (nothing falls back to the XLA reference).  Both trace the SAME
+program — same blocks, same sequence padding, same index maps, traced
+with 64-bit types off — so the CPU tests cover everything but Mosaic
+itself.  The Pallas->Mosaic lowering is covered without a chip by
+tests/test_attention.py's cross-lowering tests, and Mosaic's own compile
+and the numbers it produces by chip_smoke.py on the chip (SURVEY.md §4
+device-consistency strategy).
 """
 from __future__ import annotations
 
@@ -27,132 +36,203 @@ from jax import lax
 from .registry import register
 
 _NEG_INF = -1e30
+_LANES = 128     # minor (lane) tile of a TPU vector register
+_SUBLANES = 8    # second-minor tile for 32-bit types
 
 
 def _pick_interpret():
-    return jax.default_backend() != "tpu"
+    """Interpret the kernels only where the process was pinned to the CPU
+    on purpose (context.platform_pinned_to_cpu); anything else compiles
+    them — a machine that merely lacks a TPU gets the compile error, not
+    a silent interpreter."""
+    from ..context import platform_pinned_to_cpu
+    return platform_pinned_to_cpu()
+
+
+def _x32(fn):
+    """Trace ``fn`` with 64-bit types off.  The package enables x64 at
+    import (base.py), under which every Python int that meets a traced
+    int32 inside a jnp function (``b // G`` in an index map, a loop
+    bound) enters as int64 — and Mosaic has no 64-bit types."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.enable_x64(False):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
+def _round_up(x, m):
+    return -(-x // m) * m
+
+
+def _geometry(q, k, block_q, block_k):
+    """Static tile geometry shared by the forward and backward calls.
+    Blocks are lane multiples (the (block_q, block_k) score tile and the
+    (1, block_q) logsumexp rows must both be lane-dense), except that a
+    sequence shorter than its block is ONE block spanning the whole
+    sublane-padded array, which the block rule also allows; sequences
+    are padded to block multiples.  The head dim is a whole block dim
+    and travels unpadded.  All of it on every backend, so the
+    interpreted CPU tests trace the program the chip compiles."""
+    B, H, Sq, D = q.shape
+    Hk, Sk = k.shape[1], k.shape[2]
+    if H % Hk:
+        raise ValueError(f"q heads {H} not divisible by kv heads {Hk}")
+    if block_q % _LANES or block_k % _LANES:
+        raise ValueError(
+            f"flash attention blocks must be multiples of {_LANES}, got "
+            f"block_q={block_q} block_k={block_k}")
+    block_q = min(block_q, _round_up(Sq, 16))
+    block_k = min(block_k, _round_up(Sk, 16))
+    Sqp, Skp = _round_up(Sq, block_q), _round_up(Sk, block_k)
+    return (B, H, Hk, H // Hk, Sq, Sk, D,
+            block_q, block_k, Sqp, Skp, Sqp // block_q, Skp // block_k)
+
+
+def _pad_heads(x, Sp):
+    """(B, h, S, D) -> (B*h, Sp, D), rows zero padded."""
+    B, h, S, D = x.shape
+    return jnp.pad(x, ((0, 0), (0, 0), (0, Sp - S), (0, 0))) \
+        .reshape(B * h, Sp, D)
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+    # the last grid axis walks the reduction (scratch accumulators carry
+    # across it); the first two are independent programs
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _tile_mask(q_lo, k_lo, shape, Sk, Skp, causal, q_axis):
+    """Validity of one score tile, or None when every entry is valid.
+    ``q_axis`` is the tile axis that walks query rows (0 for the
+    (block_q, block_k) tile, 1 for its transpose)."""
+    valid = None
+    if Skp != Sk or causal:
+        k_pos = k_lo + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+        if Skp != Sk:
+            valid = k_pos < Sk                      # mask K padding
+        if causal:
+            q_pos = q_lo + lax.broadcasted_iota(jnp.int32, shape, q_axis)
+            c = k_pos <= q_pos
+            valid = c if valid is None else valid & c
+    return valid
+
+
+def _kv_spec(pl, D, G, block_q, block_k, causal):
+    """K/V BlockSpec of a (B*H, q blocks, k blocks) grid: program b walks
+    q heads and its KV head is b // G (GQA sharing).  Causal: k blocks
+    strictly above the diagonal contribute nothing; their programs are
+    skipped (``last_k``) and re-name the last needed block, so no DMA is
+    issued for them.  Returns (spec, last_k)."""
+    def last_k(i):
+        return (i * block_q + block_q - 1) // block_k
+
+    if causal:
+        def kv_map(b, i, j):
+            return (b // G, jnp.minimum(j, last_k(i)), 0)
+    else:
+        def kv_map(b, i, j):
+            return (b // G, j, 0)
+    return pl.BlockSpec((1, block_k, D), kv_map), last_k
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "interpret",
                                              "return_lse"))
+@_x32
 def _flash_fwd(q, k, v, causal=False, scale=None, block_q=128,
                block_k=128, interpret=None, return_lse=False):
     """q: (B, H, Sq, D); k/v: (B, Hk, Sk, D) with Hk dividing H (GQA/MQA:
     each group of H/Hk query heads shares one KV head — the kernel maps
     query-head programs onto the shared KV block, so grouped KV is NEVER
     materialized at H heads) → (B, H, Sq, D)
-    [, lse (B, H, Sq) when return_lse — consumed by the Pallas backward]."""
-    from jax.experimental import pallas as pl
+    [, lse (B, H, Sq) when return_lse — consumed by the Pallas backward].
 
-    B, H, Sq, D = q.shape
-    Hk = k.shape[1]
-    if H % Hk:
-        raise ValueError(f"q heads {H} not divisible by kv heads {Hk}")
-    G = H // Hk
-    Sk = k.shape[2]
+    Grid (B*H, q blocks, k blocks): one (block_q, D) query tile meets one
+    (block_k, D) K/V tile per program, so VMEM residency is set by the
+    block sizes and not by the sequence length; the online-softmax state
+    (m, l, acc) lives in VMEM scratch across the k axis."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (B, H, Hk, G, Sq, Sk, D, block_q, block_k, Sqp, Skp, nq,
+     nk) = _geometry(q, k, block_q, block_k)
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
         interpret = _pick_interpret()
 
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
+    kv_spec, last_k = _kv_spec(pl, D, G, block_q, block_k, causal)
 
-    # pad head dim to the 128-lane tile and seqs to block multiples
-    Dp = max(128, D) if not interpret else D
-    pad_q = (-Sq) % block_q
-    pad_k = (-Sk) % block_k
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, Dp - D)))
-    kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, Dp - D)))
-    vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, Dp - D)))
-    Sqp, Skp = Sq + pad_q, Sk + pad_k
-    nq = Sqp // block_q
-    nk = Skp // block_k
+    def kernel(q_ref, k_ref, v_ref, o_ref, *rest):
+        lse_ref = rest[0] if return_lse else None
+        m_ref, l_ref, acc_ref = rest[-3:]
+        i, j = pl.program_id(1), pl.program_id(2)
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref=None):
-        qi = pl.program_id(1)
-        qb = q_ref[0].astype(jnp.float32)          # (BQ, Dp)
-        q_pos = qi * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)            # global q rows
+        @pl.when(j == 0)
+        def _():
+            m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+            l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        def step():
+            s = lax.dot_general(
+                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (BQ, BK)
+            valid = _tile_mask(i * block_q, j * block_k,
+                               (block_q, block_k), Sk, Skp, causal, 0)
+            if valid is not None:
+                s = jnp.where(valid, s, _NEG_INF)
+            m_prev = m_ref[...]                     # (BQ, 128), lanes equal
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, :1])
+            l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+            m_ref[...] = m_new
+            acc_ref[...] = acc_ref[...] * alpha[:, :1] + lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
         if causal:
-            # blocks strictly above the diagonal contribute nothing
-            hi = jnp.minimum(
-                jnp.int32(nk),
-                (qi * block_q + block_q + block_k - 1) // block_k
-            ).astype(jnp.int32)
+            pl.when(j <= last_k(i))(step)
         else:
-            hi = nk
+            step()
 
-        def body(i, carry):
-            m, l, acc = carry
-            kb = k_ref[0, pl.ds(i * block_k, block_k), :] \
-                .astype(jnp.float32)               # (BK, Dp)
-            vb = v_ref[0, pl.ds(i * block_k, block_k), :] \
-                .astype(jnp.float32)
-            s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (BQ, BK)
-            k_pos = i * block_k + lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            valid = k_pos < Sk                      # mask K padding
-            if causal:
-                valid = valid & (k_pos <= q_pos)
-            s = jnp.where(valid, s, _NEG_INF)
-            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m - m_new)
-            l = l * alpha + p.sum(axis=-1, keepdims=True)
-            acc = acc * alpha + jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return m_new, l, acc
+        @pl.when(j == nk - 1)
+        def _():
+            l = jnp.maximum(l_ref[...], 1e-30)
+            o_ref[0] = (acc_ref[...] / l[:, :1]).astype(o_ref.dtype)
+            if return_lse:
+                lse_ref[0] = m_ref[...] + jnp.log(l)
 
-        m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((block_q, 1), jnp.float32)
-        a0 = jnp.zeros((block_q, Dp), jnp.float32)
-        m, l, acc = lax.fori_loop(0, hi, body, (m0, l0, a0))
-        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        if lse_ref is not None:
-            lse_ref[0] = (m + jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
-
-    qr = qp.reshape(B * H, Sqp, Dp)
-    kr = kp.reshape(B * Hk, Skp, Dp)
-    vr = vp.reshape(B * Hk, Skp, Dp)
-
-    # program b walks q heads; its KV head is b // G (GQA sharing)
-    in_specs = [
-        pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((1, Skp, Dp), lambda b, i: (b // G, 0, 0)),
-        pl.BlockSpec((1, Skp, Dp), lambda b, i: (b // G, 0, 0)),
-    ]
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+    out_specs = [q_spec]
+    out_shape = [jax.ShapeDtypeStruct((B * H, Sqp, D), q.dtype)]
     if return_lse:
-        out, lse = pl.pallas_call(
-            kernel,
-            grid=(B * H, nq),
-            in_specs=in_specs,
-            out_specs=(
-                pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
-            ),
-            out_shape=(
-                jax.ShapeDtypeStruct((B * H, Sqp, Dp), q.dtype),
-                jax.ShapeDtypeStruct((B * H, Sqp), jnp.float32),
-            ),
-            interpret=interpret,
-        )(qr, kr, vr)
-        return (out.reshape(B, H, Sqp, Dp)[:, :, :Sq, :D],
-                lse.reshape(B, H, Sqp)[:, :, :Sq])
-    out = pl.pallas_call(
+        # lane-replicated rows: a (block_q, 1) column is not a legal tile
+        out_specs.append(pl.BlockSpec((1, block_q, _LANES),
+                                      lambda b, i, j: (b, i, 0)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((B * H, Sqp, _LANES), jnp.float32))
+    res = pl.pallas_call(
         kernel,
-        grid=(B * H, nq),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sqp, Dp), q.dtype),
+        grid=(B * H, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, D), jnp.float32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
-    )(qr, kr, vr)
-    return out.reshape(B, H, Sqp, Dp)[:, :, :Sq, :D]
+        name="flash_fwd",
+    )(_pad_heads(q, Sqp), _pad_heads(k, Skp), _pad_heads(v, Skp))
+    out = res[0].reshape(B, H, Sqp, D)[:, :, :Sq]
+    if return_lse:
+        return out, res[1][:, :, 0].reshape(B, H, Sqp)[:, :, :Sq]
+    return out
 
 
 def _attn_reference(q, k, v, causal, scale):
@@ -179,168 +259,161 @@ def _attn_reference(q, k, v, causal, scale):
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "interpret"))
+@_x32
 def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
                block_q=128, block_k=128, interpret=None):
     """FlashAttention-2 backward: two Pallas kernels (dq; dk+dv), each
     recomputing p = exp(s - lse) blockwise from the saved logsumexp — the
     O(S) memory story of the forward carries to the backward (the
-    time-dominant path for long-context training, VERDICT r1 weak #7)."""
+    time-dominant path for long-context training).  Same one-tile-per-
+    program grids as the forward, accumulators in VMEM scratch."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    B, H, Sq, D = q.shape
-    Hk = k.shape[1]
-    G = H // Hk  # GQA group size (validated in the forward)
-    Sk = k.shape[2]
+    (B, H, Hk, G, Sq, Sk, D, block_q, block_k, Sqp, Skp, nq,
+     nk) = _geometry(q, k, block_q, block_k)
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
         interpret = _pick_interpret()
-    block_q = min(block_q, Sq)
-    block_k = min(block_k, Sk)
-    Dp = max(128, D) if not interpret else D
-    pad_q = (-Sq) % block_q
-    pad_k = (-Sk) % block_k
-    Sqp, Skp = Sq + pad_q, Sk + pad_k
-    nq, nk = Sqp // block_q, Skp // block_k
 
     f32 = jnp.float32
     # delta_i = rowsum(dO_i * O_i) (the FA2 `D` term), computed in f32
     delta = jnp.sum(g.astype(f32) * out.astype(f32), axis=-1)  # (B,H,Sq)
 
-    def padp(x, pad_s):
-        return jnp.pad(x, ((0, 0), (0, 0), (0, pad_s), (0, Dp - D))) \
-            .reshape(-1, x.shape[2] + pad_s, Dp)
+    def rows(x, fill):
+        # per-row statistics as lane-dense rows, replicated over one
+        # sublane tile: (B*H, 8, Sqp) blocks as (1, 8, block_q)
+        x = jnp.pad(x.astype(f32), ((0, 0), (0, 0), (0, Sqp - Sq)),
+                    constant_values=fill).reshape(B * H, 1, Sqp)
+        return jnp.broadcast_to(x, (B * H, _SUBLANES, Sqp))
 
-    qr, gr = padp(q, pad_q), padp(g, pad_q)
-    kr, vr = padp(k, pad_k), padp(v, pad_k)  # (B*Hk, Skp, Dp)
+    qr, gr = _pad_heads(q, Sqp), _pad_heads(g, Sqp)
+    kr, vr = _pad_heads(k, Skp), _pad_heads(v, Skp)
     # pad lse with +inf-ish so padded rows give p = exp(-inf) = 0
-    lser = jnp.pad(lse.astype(f32), ((0, 0), (0, 0), (0, pad_q)),
-                   constant_values=1e30).reshape(B * H, Sqp)
-    deltar = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q))) \
-        .reshape(B * H, Sqp)
+    lser, deltar = rows(lse, 1e30), rows(delta, 0.0)
 
-    def dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref):
-        qi = pl.program_id(1)
-        qb = q_ref[0].astype(f32)                    # (BQ, Dp)
-        gb = g_ref[0].astype(f32)
-        lb = lse_ref[0][:, None]                     # (BQ, 1)
-        db = dlt_ref[0][:, None]
-        q_pos = qi * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        hi = jnp.minimum(
-            jnp.int32(nk),
-            (qi * block_q + block_q + block_k - 1) // block_k
-        ).astype(jnp.int32) if causal else nk
+    kv_spec, last_k = _kv_spec(pl, D, G, block_q, block_k, causal)
 
-        def body(i, dq_acc):
-            kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(f32)
-            vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(f32)
-            s = lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=f32) * scale
-            k_pos = i * block_k + lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            valid = k_pos < Sk
-            if causal:
-                valid = valid & (k_pos <= q_pos)
-            s = jnp.where(valid, s, _NEG_INF)
-            p = jnp.exp(s - lb)                       # (BQ, BK)
-            dp = lax.dot_general(gb, vb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=f32)
-            ds = p * (dp - db) * scale
-            return dq_acc + lax.dot_general(
-                ds, kb, (((1,), (0,)), ((), ())),
-                preferred_element_type=f32)
+    def first_q(j):
+        # causal: q blocks strictly before this k block see nothing
+        return (j * block_k) // block_q
 
-        dq0 = jnp.zeros((block_q, Dp), f32)
-        dq_ref[0] = lax.fori_loop(0, hi, body, dq0).astype(dq_ref.dtype)
+    def p_and_ds_t(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, i, j):
+        """Transposed tiles pT, dsT: (BK, BQ).  k @ q^T keeps every
+        per-query statistic a (1, BQ) row that broadcasts over sublanes —
+        no row->column relayout of lse/delta inside the kernel."""
+        s_t = lax.dot_general(k_ref[0], q_ref[0], (((1,), (1,)), ((), ())),
+                              preferred_element_type=f32) * scale
+        valid = _tile_mask(i * block_q, j * block_k, (block_k, block_q),
+                           Sk, Skp, causal, 1)
+        if valid is not None:
+            s_t = jnp.where(valid, s_t, _NEG_INF)
+        p_t = jnp.exp(s_t - lse_ref[0, :1, :])
+        dp_t = lax.dot_general(v_ref[0], g_ref[0], (((1,), (1,)), ((), ())),
+                               preferred_element_type=f32)
+        return p_t, p_t * (dp_t - dlt_ref[0, :1, :]) * scale
 
+    def dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref,
+                  acc_ref):
+        i, j = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(j == 0)
+        def _():
+            acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+        def step():
+            _, ds_t = p_and_ds_t(q_ref, k_ref, v_ref, g_ref, lse_ref,
+                                 dlt_ref, i, j)
+            acc_ref[...] += lax.dot_general(
+                ds_t.astype(k_ref.dtype), k_ref[0],
+                (((0,), (0,)), ((), ())), preferred_element_type=f32)
+
+        if causal:
+            pl.when(j <= last_k(i))(step)
+        else:
+            step()
+
+        @pl.when(j == nk - 1)
+        def _():
+            dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+    row_spec = pl.BlockSpec((1, _SUBLANES, block_q),
+                            lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(B * H, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Skp, Dp), lambda b, i: (b // G, 0, 0)),
-            pl.BlockSpec((1, Skp, Dp), lambda b, i: (b // G, 0, 0)),
-            pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, Dp), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sqp, Dp), q.dtype),
+        grid=(B * H, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B * H, Sqp, D), q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, D), f32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qr, kr, vr, gr, lser, deltar)
 
+    # dk/dv: program (b, j) owns one K/V tile of KV head b and walks the
+    # G query heads of its group times the q blocks on the last axis, so
+    # the GQA reduction over the group happens in the accumulators
     def dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
-                   dk_ref, dv_ref):
-        ki = pl.program_id(1)
-        kb = k_ref[0].astype(f32)                    # (BK, Dp)
-        vb = v_ref[0].astype(f32)
-        k_pos = ki * block_k + lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)              # also used as col ids
-        # causal: q blocks strictly before this k block see nothing
-        lo = (ki * block_k) // block_q if causal else 0
+                   dk_ref, dv_ref, dk_acc, dv_acc):
+        j, t = pl.program_id(1), pl.program_id(2)
+        i = t % nq
 
-        def body(i, carry):
-            dk_acc, dv_acc = carry
-            qb = q_ref[0, pl.ds(i * block_q, block_q), :].astype(f32)
-            gb = g_ref[0, pl.ds(i * block_q, block_q), :].astype(f32)
-            lb = lse_ref[0, pl.ds(i * block_q, block_q)][:, None]
-            db = dlt_ref[0, pl.ds(i * block_q, block_q)][:, None]
-            s = lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=f32) * scale
-            q_pos = i * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            valid = k_pos < Sk
-            if causal:
-                valid = valid & (k_pos <= q_pos)
-            s = jnp.where(valid, s, _NEG_INF)
-            p = jnp.exp(s - lb)                       # (BQ, BK)
-            dv_acc = dv_acc + lax.dot_general(
-                p, gb, (((0,), (0,)), ((), ())),
-                preferred_element_type=f32)           # (BK, Dp)
-            dp = lax.dot_general(gb, vb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=f32)
-            ds = p * (dp - db) * scale
-            dk_acc = dk_acc + lax.dot_general(
-                ds, qb, (((0,), (0,)), ((), ())),
-                preferred_element_type=f32)           # (BK, Dp)
-            return dk_acc, dv_acc
+        @pl.when(t == 0)
+        def _():
+            dk_acc[...] = jnp.zeros(dk_acc.shape, f32)
+            dv_acc[...] = jnp.zeros(dv_acc.shape, f32)
 
-        z = jnp.zeros((block_k, Dp), f32)
-        dk_acc, dv_acc = lax.fori_loop(lo, nq, body, (z, z))
-        dk_ref[0] = dk_acc.astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc.astype(dv_ref.dtype)
+        def step():
+            p_t, ds_t = p_and_ds_t(q_ref, k_ref, v_ref, g_ref, lse_ref,
+                                   dlt_ref, i, j)
+            dv_acc[...] += lax.dot_general(
+                p_t.astype(g_ref.dtype), g_ref[0],
+                (((1,), (0,)), ((), ())), preferred_element_type=f32)
+            dk_acc[...] += lax.dot_general(
+                ds_t.astype(q_ref.dtype), q_ref[0],
+                (((1,), (0,)), ((), ())), preferred_element_type=f32)
 
-    # dk/dv come out PER QUERY HEAD (grid over B*H, KV indexed b//G); the
-    # GQA reduction over each group's G query heads happens outside the
-    # kernel — a (B, Hk, G, S, D) sum XLA fuses with the reshape
+        if causal:
+            pl.when(i >= first_q(j))(step)
+        else:
+            step()
+
+        @pl.when(t == G * nq - 1)
+        def _():
+            dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    def q_block(j, t):
+        i = t % nq
+        return jnp.maximum(i, first_q(j)) if causal else i
+
+    dkv_q_spec = pl.BlockSpec(
+        (1, block_q, D), lambda b, j, t: (b * G + t // nq, q_block(j, t), 0))
+    dkv_row_spec = pl.BlockSpec(
+        (1, _SUBLANES, block_q),
+        lambda b, j, t: (b * G + t // nq, 0, q_block(j, t)))
+    dkv_kv_spec = pl.BlockSpec((1, block_k, D), lambda b, j, t: (b, j, 0))
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(B * H, nk),
-        in_specs=[
-            pl.BlockSpec((1, Sqp, Dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, Dp), lambda b, i: (b // G, i, 0)),
-            pl.BlockSpec((1, block_k, Dp), lambda b, i: (b // G, i, 0)),
-            pl.BlockSpec((1, Sqp, Dp), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, Sqp), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, Sqp), lambda b, i: (b, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_k, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, Dp), lambda b, i: (b, i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((B * H, Skp, Dp), jnp.float32),
-            jax.ShapeDtypeStruct((B * H, Skp, Dp), jnp.float32),
-        ),
+        grid=(B * Hk, nk, G * nq),
+        in_specs=[dkv_q_spec, dkv_kv_spec, dkv_kv_spec, dkv_q_spec,
+                  dkv_row_spec, dkv_row_spec],
+        out_specs=[dkv_kv_spec, dkv_kv_spec],
+        out_shape=[jax.ShapeDtypeStruct((B * Hk, Skp, D), k.dtype),
+                   jax.ShapeDtypeStruct((B * Hk, Skp, D), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, D), f32),
+                        pltpu.VMEM((block_k, D), f32)],
+        compiler_params=_compiler_params(),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qr, kr, vr, gr, lser, deltar)
 
-    dq = dq.reshape(B, H, Sqp, Dp)[:, :, :Sq, :D]
-    dk = dk.reshape(B, Hk, G, Skp, Dp).sum(axis=2)[:, :, :Sk, :D] \
-        .astype(k.dtype)
-    dv = dv.reshape(B, Hk, G, Skp, Dp).sum(axis=2)[:, :, :Sk, :D] \
-        .astype(v.dtype)
+    dq = dq.reshape(B, H, Sqp, D)[:, :, :Sq]
+    dk = dk.reshape(B, Hk, Skp, D)[:, :, :Sk]
+    dv = dv.reshape(B, Hk, Skp, D)[:, :, :Sk]
     return dq, dk, dv
 
 

@@ -20,7 +20,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError
@@ -82,14 +82,6 @@ def pipeline_apply(stage_fn, stage_params, x, mesh: Mesh,
         # only the last stage holds real outputs; psum broadcasts them
         return lax.psum(outputs, axis)
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    import inspect
-    sig = inspect.signature(shard_map).parameters
-    relax = {"check_rep": False} if "check_rep" in sig else \
-        ({"check_vma": False} if "check_vma" in sig else {})
     pspec_params = P(axis)
     pspec_x = P()        # microbatch stream replicated over pp
     fn = shard_map(
@@ -97,6 +89,6 @@ def pipeline_apply(stage_fn, stage_params, x, mesh: Mesh,
         in_specs=(jax.tree.map(lambda _: pspec_params, stage_params),
                   pspec_x),
         out_specs=P(),
-        **relax)
+        check_vma=False)
     out = fn(stage_params, xm)
     return out.reshape((B,) + x.shape[1:])

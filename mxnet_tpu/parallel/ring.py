@@ -20,10 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..base import MXNetError
 from .mesh import mesh_shape
@@ -90,17 +87,9 @@ def ring_attention(q, k, v, mesh, causal=False, scale=None,
         l = jnp.zeros(q.shape[:3] + (1,), jnp.float32)
         acc = jnp.zeros(q.shape, jnp.float32)
         # accumulators are per-shard state: mark them device-varying on
-        # every sharded axis so the fori carry types stay consistent.
-        # jax grew this surface twice (pvary, then pcast); a jax that
-        # predates BOTH has no varying-type system and needs no marking
-        # — the carries are already consistent there.
-        _pcast = getattr(lax, "pcast", None)
-        _pvary = getattr(lax, "pvary", None)
-        if _pcast is not None:
-            m, l, acc = (_pcast(x, spec_axes, to="varying")
-                         for x in (m, l, acc))
-        elif _pvary is not None:
-            m, l, acc = (_pvary(x, spec_axes) for x in (m, l, acc))
+        # every sharded axis so the fori carry types stay consistent
+        m, l, acc = (lax.pcast(x, spec_axes, to="varying")
+                     for x in (m, l, acc))
 
         def step(s, carry):
             k_cur, v_cur, m, l, acc = carry

@@ -347,7 +347,7 @@ def channel_bytes() -> dict:
 # counter FAMILY from the TCP wire, because the whole point of the tier
 # is moving bytes from the wire onto the mesh: bench.py reports
 # ici_bytes_per_step next to wire_bytes_per_step so the shift is a
-# banked, regression-gateable number (docs/PERF_NOTES.md round 11).
+# banked, regression-gateable number.
 ICI_BYTE_PREFIX = "ici_"
 
 # Control-plane traffic (heartbeats, roster beats/leaves, codec hellos)
@@ -435,7 +435,7 @@ def reset_channel_bytes():
 # Deliberately its own dict, not more _channel_bytes kinds: the
 # fault-injection tests assert channel counters by exact equality, and
 # the hot-path acceptance pin is pickle_bytes == 0 over a measured
-# window — bench.py banks both per-step (docs/PERF_NOTES.md round 12).
+# window — bench.py banks both per-step.
 _serialization: dict = {}
 _serialization_lock = threading.Lock()
 
@@ -478,7 +478,7 @@ def reset_serialization():
 
 # -- kvstore wire-overlap counters -------------------------------------------
 # The fused-dist K-step driver overlaps the push/pull wire round of chunk
-# j-1 behind chunk j's scanned compute (docs/PERF_NOTES.md round 10).
+# j-1 behind chunk j's scanned compute.
 # Two clocks make the overlap CPU-testable the way host_syncs made the
 # sync-free loop testable:
 #   * wire_wait  — host time actually BLOCKED on a pull future (the
@@ -562,7 +562,7 @@ def reset_wire_counters():
 # waiting for every follower's round to arrive — the serialization the
 # parallel acceptor pool + shm lane exist to shrink.  bench.py banks
 # mesh_fanin_ms_per_step next to shm_bytes_per_step so the acceptors ×
-# shm A/B (docs/PERF_NOTES.md round 13) is a regression-gateable number.
+# shm A/B is a regression-gateable number.
 _fanin_lock = threading.Lock()
 _fanin = {"wait_s": 0.0, "rounds": 0}
 

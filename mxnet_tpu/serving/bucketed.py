@@ -263,7 +263,7 @@ class BucketedPredictor:
         host = jax.device_get(outs)
         # the reply crosses the wire as host bytes: this readback is the
         # serving loop's one deliberate sync, counted like every other
-        # contract site (docs/PERF_NOTES.md round 8)
+        # contract site
         _prof.record_host_sync("serving.predict_readback")
         return version, [np.asarray(o)[:n] for o in host]
 

@@ -1,7 +1,6 @@
 """Same-host shared-memory lane for the hierarchical mesh tier.
 
-The hierarchy tier (MXNET_KVSTORE_HIERARCHY, docs/PERF_NOTES.md round
-11) moves gradient bytes off the TCP wire onto the in-host mesh — but
+The hierarchy tier (MXNET_KVSTORE_HIERARCHY) moves gradient bytes off the TCP wire onto the in-host mesh — but
 the mesh CHANNEL itself still rode TCP loopback, paying two kernel
 copies and a syscall per frame for bytes that never leave the host.
 This module is the hardware-speed replacement: one POSIX shared-memory

@@ -1,7 +1,7 @@
 """Precision-layout guard on the COMPILED fused train step.
 
 The round-2/3 MFU work moved BatchNorm onto a bf16 data path with fp32
-statistics (docs/PERF_NOTES.md; reference contract:
+statistics (reference contract:
 src/operator/cudnn_batch_norm-inl.h — fp32 stats over a low-precision
 data path).  These tests pin that contract at the StableHLO level, on
 CPU, so an AMP regression (an op silently upcasting the activation
@@ -86,7 +86,7 @@ def test_fp32_mode_keeps_fp32_convolution():
 
 def _sweep_step_hlo(stem, remat_policy):
     """Lower the fused step in a sweep configuration (s2d stem and/or
-    remat) — the exact configs tools/chip_session.sh measures; an fp32
+    remat) — the configs the chip sweeps measure; an fp32
     activation leak in one of them would waste the chip session.
 
     The stem only exists on the imagenet branch (height > 32,

@@ -1,7 +1,10 @@
 """Flash/ring attention tests (new TPU-native capability, SURVEY.md §5.7).
 
-Pallas kernel runs in interpret mode on the CPU mesh — same code path as
-TPU (SURVEY.md §4 consistency strategy)."""
+The Pallas kernels run in interpret mode on the CPU mesh: the same traced
+program the chip compiles (same padding, blocks and index maps), executed
+by the Pallas interpreter instead of Mosaic.  What the interpreter cannot
+show — that Mosaic accepts the program — is covered by the cross-lowering
+tests at the bottom and by chip_smoke.py on the chip."""
 import os
 import time
 
@@ -140,25 +143,33 @@ def test_flash_backward_kernel_matches_reference(causal):
                                rtol=2e-3, atol=2e-3)
 
 
-def test_flash_backward_small_blocks():
-    """Multi-block path (several q and k blocks) with causal masking."""
+def test_flash_backward_multi_block():
+    """Several q and k blocks (3x3 tiles of 128 after padding 300) with
+    causal masking and a GQA group: block skipping, the clamped K/V and q
+    index maps and the in-kernel group reduction all run."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops import attention as A
     rng = np.random.RandomState(3)
-    q = jnp.asarray(rng.randn(1, 2, 96, 8).astype('f'))
-    k = jnp.asarray(rng.randn(1, 2, 96, 8).astype('f'))
-    v = jnp.asarray(rng.randn(1, 2, 96, 8).astype('f'))
-    g = jnp.asarray(rng.randn(1, 2, 96, 8).astype('f'))
+    q = jnp.asarray(rng.randn(1, 2, 300, 8).astype('f'))
+    k = jnp.asarray(rng.randn(1, 1, 300, 8).astype('f'))
+    v = jnp.asarray(rng.randn(1, 1, 300, 8).astype('f'))
+    g = jnp.asarray(rng.randn(1, 2, 300, 8).astype('f'))
     ref = jax.vjp(lambda a, b, c: A._attn_reference(a, b, c, True, None),
                   q, k, v)[1](g)
     got = A._flash_bwd(q, k, v,
                        *A._flash_fwd(q, k, v, causal=True,
                                      return_lse=True),
-                       g, causal=True, block_q=32, block_k=32)
+                       g, causal=True)
     for x, y in zip(got, ref):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    rtol=2e-3, atol=2e-3)
+
+
+def test_flash_blocks_must_be_lane_multiples():
+    q, k, v = _rand_qkv(S=64)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        flash_attention(q, k, v, True, None, 32, 32)
 
 
 # --- Ulysses all-to-all sequence parallelism (parallel/ulysses.py) ---------
@@ -375,3 +386,43 @@ def test_attention_impl_dispatch(monkeypatch, tmp_path):
     assert att.pick_attention_config(256, False) == ("xla", 256, 128)
     monkeypatch.setenv("MXNET_ATTENTION_IMPL", "auto")
     monkeypatch.setattr(att, "_dispatch_cache", None)
+
+
+# --- the program the chip compiles ------------------------------------------
+# The interpreter (every test above) shows the traced program computes the
+# right numbers; these show Mosaic is handed a program it accepts, without a
+# chip: the Pallas->Mosaic lowering of each kernel for the TPU platform with
+# interpret=False, under the package's own x64 setting.  At the seed this
+# failed twice over: lse/delta blocks of shape (1, block_q) over a 2-D array
+# broke the (8, 128) block rule, and with x64 on every Python int in an index
+# map or loop bound entered as int64, which Mosaic does not have.
+def _tpu_lowered(fn, *avals):
+    return jax.jit(fn).trace(*avals).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "fwd_lse", "bwd"])
+@pytest.mark.parametrize("D,Hk", [(64, 4), (64, 1), (128, 4), (128, 2)])
+def test_flash_kernels_cross_lower_for_tpu(kernel, D, Hk):
+    from mxnet_tpu.ops import attention as A
+    assert jax.config.jax_enable_x64       # the package's setting, not ours
+    B, H, S = 2, 4, 512
+    q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, Hk, S, D), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((B, H, S), jnp.float32)
+    if kernel == "bwd":
+        txt = _tpu_lowered(
+            lambda q, k, v, o, l, g: A._flash_bwd(
+                q, k, v, o, l, g, causal=True, interpret=False),
+            q, kv, kv, q, lse, q)
+        names = ["flash_bwd_dq", "flash_bwd_dkv"]
+    else:
+        txt = _tpu_lowered(
+            lambda q, k, v: A._flash_fwd(
+                q, k, v, causal=True, interpret=False,
+                return_lse=kernel == "fwd_lse"),
+            q, kv, kv)
+        names = ["flash_fwd"]
+    assert txt.count("tpu_custom_call") == len(names)
+    for n in names:
+        assert 'kernel_name = "%s"' % n in txt

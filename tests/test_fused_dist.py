@@ -1,6 +1,6 @@
 """Fused dist_async K-step driver (Module.run_steps / Trainer.step_k on
 update-on-kvstore): the chunked scan with the wire overlapped behind
-compute (docs/PERF_NOTES.md round 10).
+compute.
 
 The contracts pinned here, all CPU-provable:
 

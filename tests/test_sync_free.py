@@ -1,7 +1,7 @@
 """Sync-free training loop: device-resident metrics, one host readback
 per log interval (ci/run_ci.sh runs this file as its own gate).
 
-The contract under test (docs/PERF_NOTES.md round 8): every
+The contract under test: every
 device->host readback is counted by profiler.record_host_sync, metric
 accumulation in fit/score/run_steps stays on the async engine, and the
 ONLY sync points in a training loop are the callbacks that read the

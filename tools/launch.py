@@ -25,7 +25,10 @@ servers are torn down by the launcher once all workers exit.
 
 Two launchers:
 
-* ``--launcher local`` (default) — W processes on this machine.
+* ``--launcher local`` (default) — W processes on this machine.  With
+  W > 1 this is the CPU test plane: the workers are pinned to
+  ``JAX_PLATFORMS=cpu`` and a launch that names another platform is
+  refused, because a chip belongs to one process (``_local_platform``).
 * ``--launcher ssh`` — W processes spread round-robin over the hosts in
   ``-H/--hostfile`` (reference: tools/launch.py:64-80 ssh mode via
   dmlc-tracker), each started as ``ssh <host> 'cd <dir> && env DMLC_*=…
@@ -110,11 +113,43 @@ def _server_env(args, sid):
     return env
 
 
+def _local_platform(args):
+    """JAX_PLATFORMS for the workers of a local launch.
+
+    A chip belongs to one process, and W local workers inherit one
+    environment, so on a chip host every one of them would claim the
+    same chips.  Several workers on one machine are therefore the CPU
+    test plane (every tests/dist script runs there): they are pinned to
+    the CPU, and a launch that asks for anything else is refused instead
+    of left to contend.  One process per host drives that host's chips —
+    a single local worker keeps whatever platform its environment
+    names."""
+    asked = dict(e.split("=", 1) for e in args.env).get(
+        "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS"))
+    if args.num_workers <= 1:
+        return asked
+    if asked is None:
+        print("launch.py: %d local workers share this machine: pinning "
+              "them to JAX_PLATFORMS=cpu (a chip belongs to one process)"
+              % args.num_workers, file=sys.stderr, flush=True)
+        return "cpu"
+    if asked.split(",")[0].strip().lower() != "cpu":
+        raise SystemExit(
+            "launch.py: refusing to start %d local workers with "
+            "JAX_PLATFORMS=%s: they would all claim the same chips.  Local "
+            "multi-worker launches run on the CPU (JAX_PLATFORMS=cpu); on "
+            "a chip host start one process, which drives every chip of "
+            "the host." % (args.num_workers, asked))
+    return asked
+
+
 def _spawn_local(args, port):
     procs = []
     for wid in range(args.num_workers):
         env = dict(os.environ)
         env.update(_worker_env(args, "127.0.0.1", port, wid))
+        if args.local_platform is not None:
+            env["JAX_PLATFORMS"] = args.local_platform
         procs.append(subprocess.Popen(args.command, env=env))
     return procs
 
@@ -259,6 +294,9 @@ def main():
         ap.error("no command given")
     if args.launcher == "ssh" and not args.hostfile:
         ap.error("--launcher ssh requires -H/--hostfile")
+    if args.launcher == "local":
+        # resolved (and possibly refused) BEFORE any server is started
+        args.local_platform = _local_platform(args)
     # parameter servers (kvstore dist_async): pick their ports up front so
     # workers AND servers share one MXT_SERVER_URIS view
     sprocs = []

@@ -7,9 +7,8 @@ promotes the winner into its PER-TOPOLOGY entry of BENCH_DEFAULTS.json
 worker/server count) — bench.py resolves exactly its own topology's
 entry, so a b256-TPU winner can never leak into a CPU or MULTICHIP
 run.  The >2% hysteresis lives in promote(): noise can't flip defaults
-back and forth, and other topologies' rows are never touched.  Run by
-tools/chip_session.sh after the MFU sweep; safe to run any time (no
-log → no file → bench keeps built-in defaults).  The richer sweep
+back and forth, and other topologies' rows are never touched.  Safe to
+run any time (no log → no file → bench keeps built-in defaults).  The richer sweep
 driver (`python -m mxnet_tpu.autotune --target bench`) promotes
 through the same schema.
 """
