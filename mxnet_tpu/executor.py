@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from .base import MXNetError, env as _base_env
 from .context import Context, current_context
 from . import random as _rnd
+from . import tracing as _tracing
 from .ndarray import NDArray
 from .ndarray.ndarray import zeros as nd_zeros
 from .ops import registry as _reg
@@ -143,16 +144,19 @@ def build_interpreter(sym: Symbol, compute_dtype=None):
             opdef = _reg.get(n.op)
             _reg.record_execution(n.op)
             ins = [env[(id(src), i)] for src, i in n.inputs]
-            if cd is not None:
-                ins = _amp_cast(ins, n.op)
             kwargs = dict(n.attrs)
             kwargs.pop("name", None)
             if opdef.takes_is_train:
                 kwargs["is_train"] = is_train
-            if opdef.needs_rng:
-                outs = opdef.fn(keys[rng_index[id(n)]], *ins, **kwargs)
-            else:
-                outs = opdef.fn(*ins, **kwargs)
+            # a location, not an operation: the node's name reaches each
+            # device event's op_name as jvp(<node>) / transpose(jvp(<node>))
+            with jax.named_scope(n.name):
+                if cd is not None:
+                    ins = _amp_cast(ins, n.op)
+                if opdef.needs_rng:
+                    outs = opdef.fn(keys[rng_index[id(n)]], *ins, **kwargs)
+                else:
+                    outs = opdef.fn(*ins, **kwargs)
             if not isinstance(outs, (tuple, list)):
                 outs = (outs,)
             if opdef.num_aux and opdef.takes_is_train and is_train:
@@ -198,12 +202,13 @@ def build_multi_step(step_body, donate=True):
     in place in HBM across all K steps, exactly like the single fused
     step does for one.
     """
-    def k_steps(carry, xs, const):
+    # a stable name: the compiled module is jit_mx_run_steps
+    def mx_run_steps(carry, xs, const):
         def body(c, x):
             return step_body(c, x, const)
         return jax.lax.scan(body, carry, xs)
 
-    return jax.jit(k_steps, donate_argnums=(0,) if donate else ())
+    return jax.jit(mx_run_steps, donate_argnums=(0,) if donate else ())
 
 
 def fused_dist_knobs(k):
@@ -261,7 +266,7 @@ def drive_chunked_dist(num_steps, chunk_size, staleness, dispatch_chunk,
     Returns the FINAL round's pulled values — the server-authoritative
     weights at the sync point — or None when num_steps == 0."""
     import math
-    from . import tracing as _tr
+
     from . import health as _health
     n_chunks = math.ceil(num_steps / chunk_size)
     pending = {}
@@ -275,23 +280,23 @@ def drive_chunked_dist(num_steps, chunk_size, staleness, dispatch_chunk,
         # the full overlapped round) — the overlap the driver buys
         # becomes VISIBLE on the merged timeline, not just a percentage
         # (docs/OBSERVABILITY.md)
-        with _tr.span("fused.chunk", cat="fused", args={"chunk": j}):
+        with _tracing.span("fused.chunk", cat="fused", args={"chunk": j}):
             due = j - 1 - staleness
             if due in pending:
-                with _tr.span("fused.adopt_wait", cat="fused",
+                with _tracing.span("fused.adopt_wait", cat="fused",
                               args={"due": due}):
                     adopted = pending.pop(due).wait()
             else:
                 adopted = None
             lo = j * chunk_size
             hi = min(num_steps, lo + chunk_size)
-            with _tr.span("fused.chunk_compute", cat="fused",
+            with _tracing.span("fused.chunk_compute", cat="fused",
                           args={"lo": lo, "hi": hi}):
                 grads = dispatch_chunk(j, lo, hi, adopted)
             pending[j] = ship_chunk(j, grads)
     final = None
     for j in sorted(pending):
-        with _tr.span("fused.drain_wait", cat="fused", args={"chunk": j}):
+        with _tracing.span("fused.drain_wait", cat="fused", args={"chunk": j}):
             final = pending[j].wait()
     return final
 
@@ -592,8 +597,9 @@ class Executor:
         self._last_key = self._next_key()
         # snapshot the input values: later arg mutation (or a second
         # forward) must not change what THIS forward's outputs resolve to
-        snapshot = (self._arg_vals(), self._aux_vals(), self._last_key,
-                    is_train)
+        with _tracing.span("mx.executor.place", "executor"):
+            snapshot = (self._arg_vals(), self._aux_vals(), self._last_key,
+                        is_train)
         self._snapshot = snapshot
         out_avals = self._out_aval_list(is_train)
         out_arrays = make_lazy_outputs(
@@ -652,16 +658,18 @@ class Executor:
             if same and val.committed:
                 return val
         if sh.is_fully_addressable:
-            return jax.device_put(val, sh)
+            with _tracing.span("mx.executor.device_put", "executor"):
+                return jax.device_put(val, sh)
         # mesh spans processes (multi-host SPMD): device_put cannot target
         # non-addressable shardings.  Every process feeds the same global
         # host value (the SPMD data contract — dist scripts use identical
         # seeds/batches), so build the global array from the shards THIS
         # process addresses.
-        # analysis: allow(host-sync): multi-host staging — val is the HOST feed value every process supplies (SPMD data contract); the copy builds the global array, it does not read a device buffer back
-        arr = np.asarray(val)
-        return jax.make_array_from_callback(
-            arr.shape, sh, lambda idx: arr[idx])
+        with _tracing.span("mx.executor.device_put", "executor"):
+            # analysis: allow(host-sync): multi-host staging — val is the HOST feed value every process supplies (SPMD data contract); the copy builds the global array, it does not read a device buffer back
+            arr = np.asarray(val)
+            return jax.make_array_from_callback(
+                arr.shape, sh, lambda idx: arr[idx])
 
     def _placed(self, arrays, names, shardings):
         """Values of ``arrays`` where the program reads them.  Off-mesh a
@@ -707,9 +715,8 @@ class Executor:
     def _materialize(self, snapshot, out_arrays, monitor=False):
         arg_vals, aux_vals, key, is_train = snapshot
         if monitor:
-            from . import profiler as _prof
             collected = []
-            with _prof.scope("executor_forward_monitored", "symbolic"):
+            with _tracing.span("mx.executor.forward.monitored", "executor"):
                 outs, new_aux = self._run(
                     arg_vals, aux_vals, key, is_train,
                     _collect=lambda n, os: collected.append((n, os)))
@@ -722,7 +729,7 @@ class Executor:
         else:
             from . import profiler as _prof
             _prof.record_dispatch("executor.forward")
-            with _prof.scope("executor_forward", "symbolic"):
+            with _tracing.span("mx.executor.forward.call", "executor"):
                 outs, new_aux = self._jit_fwd(arg_vals, aux_vals, key,
                                               is_train)
         for oa, v in zip(out_arrays, outs):
@@ -777,7 +784,7 @@ class Executor:
             cts = tuple(vals[i] for i in diff_idx)
         from . import profiler as _prof
         _prof.record_dispatch("executor.fwd_bwd")
-        with _prof.scope("executor_fwd_bwd", "symbolic"):
+        with _tracing.span("mx.executor.fwd_bwd.call", "executor"):
             outs, new_aux, grads = self._jit_fwd_bwd(arg_vals, aux_vals,
                                                      key, cts)
         if self._out_arrays is None:

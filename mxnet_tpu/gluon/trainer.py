@@ -713,7 +713,7 @@ class Trainer:
                                             _writeback)
             else:
                 _prof.record_dispatch("step_k.dispatch")
-                with _prof.scope("step_k_scan", "symbolic"):
+                with _prof.span("mx.trainer.step_k.call", "trainer"):
                     (new_ws, new_auxs, new_sts, new_m), losses = fn(
                         (ws, auxs, sts, init_m),
                         (data_t, label_t, lrs, wds, ts), ())
@@ -798,7 +798,7 @@ class Trainer:
                   tuple(v[lo:hi] for v in wds),
                   tuple(v[lo:hi] for v in ts))
             _prof.record_dispatch("step_k.dist_chunk")
-            with _prof.scope("step_k_dist_chunk", "symbolic"):
+            with _prof.span("mx.trainer.step_k.dist_chunk.call", "trainer"):
                 (nws, nauxs, nsts, nm), (losses, grads) = fn(
                     (carry["ws"], carry["auxs"], carry["sts"],
                      carry["m"]), xs, ())

@@ -244,7 +244,8 @@ class EvalMetric:
                 return self
         import jax
         from . import profiler as _prof
-        host = jax.device_get(state)
+        with _prof.span("mx.sync.metric.sync", "sync"):
+            host = jax.device_get(state)
         _prof.record_host_sync("metric.sync")
         self._fold_synced(host)
         return self
@@ -477,7 +478,8 @@ class CompositeEvalMetric(EvalMetric):
             return self
         import jax
         from . import profiler as _prof
-        host = jax.device_get([m._device_state for m in pend])
+        with _prof.span("mx.sync.metric.sync", "sync"):
+            host = jax.device_get([m._device_state for m in pend])
         _prof.record_host_sync("metric.sync")
         for m, h in zip(pend, host):
             m._device_state = None

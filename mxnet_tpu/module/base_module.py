@@ -12,6 +12,7 @@ import numpy as np
 from ..base import MXNetError
 from .. import metric as metric_mod
 from .. import io as io_mod
+from .. import tracing as _tracing
 from ..model import BatchEndParam
 from ..initializer import Uniform
 from ..ndarray import NDArray
@@ -297,7 +298,8 @@ class BaseModule:
             nbatch = 0
             data_iter = iter(train_data)
             end_of_batch = False
-            next_data_batch = next(data_iter)
+            with _tracing.span("mx.fit.next_batch", "fit"):
+                next_data_batch = next(data_iter)
             while not end_of_batch:
                 data_batch = next_data_batch
                 if monitor is not None:
@@ -305,7 +307,9 @@ class BaseModule:
                 self.forward_backward(data_batch)
                 self.update()
                 try:
-                    next_data_batch = next(data_iter)
+                    # the iterator wait: what the step loses to input
+                    with _tracing.span("mx.fit.next_batch", "fit"):
+                        next_data_batch = next(data_iter)
                     self.prepare(next_data_batch)
                 except StopIteration:
                     end_of_batch = True
@@ -316,15 +320,17 @@ class BaseModule:
                 # Speedometer every `frequent` batches) and at the
                 # epoch-end log below: <= nbatch/frequent + 1 syncs
                 # per epoch, asserted by tests/test_sync_free.py.
-                self.update_metric(eval_metric, data_batch.label)
+                with _tracing.span("mx.fit.update_metric", "fit"):
+                    self.update_metric(eval_metric, data_batch.label)
                 if monitor is not None:
                     monitor.toc_print()
                 if batch_end_callback is not None:
-                    batch_end_params = BatchEndParam(
-                        epoch=epoch, nbatch=nbatch, eval_metric=eval_metric,
-                        locals=locals())
-                    for callback in _as_list(batch_end_callback):
-                        callback(batch_end_params)
+                    with _tracing.span("mx.fit.callbacks", "fit"):
+                        batch_end_params = BatchEndParam(
+                            epoch=epoch, nbatch=nbatch,
+                            eval_metric=eval_metric, locals=locals())
+                        for callback in _as_list(batch_end_callback):
+                            callback(batch_end_params)
                 nbatch += 1
 
             for name, val in eval_metric.get_name_value():
@@ -477,7 +483,8 @@ def chunked_device_get(groups, tag, chunk=None):
     if chunk is None:
         chunk = max(1, int(env("MXNET_PREDICT_READBACK_BATCHES", 64)))
     for lo in range(0, len(groups), chunk):
-        host = jax.device_get(groups[lo:lo + chunk])
+        with _tracing.span("mx.sync." + tag, "sync"):
+            host = jax.device_get(groups[lo:lo + chunk])
         _prof.record_host_sync(tag)
         groups[lo:lo + chunk] = host
     return groups

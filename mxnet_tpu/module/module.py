@@ -12,6 +12,7 @@ kvstore round trip per step).
 from __future__ import annotations
 
 import logging
+import sys
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -27,8 +28,45 @@ from ..model import (_create_kvstore, _initialize_kvstore, _update_params,
                      save_checkpoint)
 from ..ndarray import NDArray
 from .. import optimizer as opt_mod
+from .. import profiler as _prof
 from .. import random as _rnd
+from .. import tracing as _tracing
 from .base_module import BaseModule, _check_input_names, _parse_data_desc
+
+
+# what jit keys a compiled entry on, per argument leaf
+_JIT_KEY_FIELDS = ("shape", "dtype", "weak_type", "sharding", "committed")
+
+
+def _jit_leaf_key(v):
+    """The fields of _JIT_KEY_FIELDS for one argument leaf.  A host value
+    (numpy or Python scalar) has no sharding and is never committed; a
+    donated, deleted array still answers all five."""
+    aval = v.aval if isinstance(v, jax.Array) \
+        else jax.api_util.shaped_abstractify(v)
+    return {"shape": tuple(aval.shape), "dtype": str(aval.dtype),
+            "weak_type": bool(aval.weak_type),
+            "sharding": str(getattr(v, "sharding", None)),
+            "committed": bool(getattr(v, "committed", False))}
+
+
+def _report_step_compile(instant, what, moved):
+    """One stderr line and one trace instant for a fused step that was
+    compiled or built again: ``moved`` maps (field, before, after) to the
+    labels it moved on; the line names at most six labels a field, the
+    instant's args carry all of them."""
+    parts = ["%s %s -> %s on %d: %s%s"
+             % (field, was, now, len(labels), ", ".join(labels[:6]),
+                " (+%d more)" % (len(labels) - 6) if len(labels) > 6 else "")
+             for (field, was, now), labels in moved.items()]
+    line = "%s: %s" % (what, "; ".join(parts) or
+                       "nothing jit keys on differs (the cache was "
+                       "cleared, or a transform's context changed)")
+    _tracing.instant(instant, "module", args={
+        "moved": [{"field": f, "before": str(a), "after": str(b),
+                   "leaves": labels}
+                  for (f, a, b), labels in moved.items()]})
+    print("mxnet_tpu: " + line, file=sys.stderr, flush=True)
 
 
 class Module(BaseModule):
@@ -135,6 +173,12 @@ class Module(BaseModule):
         self._grad_req = None
         self._exec: Optional[Executor] = None
         self._fused_step = None
+        self._fused_hparam_sig = None
+        # compiled entries of _fused_step at its last call, and what jit
+        # keyed the newest of them on (_note_step_compiled)
+        self._fused_cache_size = 0
+        self._fused_jit_key = None
+        self._updated_once = False
         self._run_steps_cache: Dict[tuple, object] = {}
         self._opt_states: Dict[str, tuple] = {}
         self._pending_backward = False
@@ -204,7 +248,12 @@ class Module(BaseModule):
         if self.params_initialized and not force_init:
             return
         assert self.binded, 'call bind before initializing the parameters'
+        with _tracing.phase("mx.module.init_params"):
+            self._init_params(initializer, arg_params, aux_params,
+                              allow_missing, allow_extra)
 
+    def _init_params(self, initializer, arg_params, aux_params,
+                     allow_missing, allow_extra):
         def _impl(name, arr, cache):
             if cache is not None and name in cache:
                 cache_arr = cache[name]
@@ -260,6 +309,12 @@ class Module(BaseModule):
         if self.binded:
             self.logger.warning('Already bound, ignoring bind()')
             return
+        with _tracing.phase("mx.module.bind"):
+            self._bind(data_shapes, label_shapes, for_training,
+                       inputs_need_grad, shared_module, grad_req)
+
+    def _bind(self, data_shapes, label_shapes, for_training,
+              inputs_need_grad, shared_module, grad_req):
         self.for_training = for_training
         self.inputs_need_grad = inputs_need_grad
         self.binded = True
@@ -379,6 +434,10 @@ class Module(BaseModule):
         if self.optimizer_initialized and not force_init:
             self.logger.warning('optimizer already initialized, ignoring...')
             return
+        with _tracing.phase("mx.module.init_optimizer"):
+            self._init_optimizer(kvstore, optimizer, optimizer_params)
+
+    def _init_optimizer(self, kvstore, optimizer, optimizer_params):
         if self._params_dirty:
             self._sync_params_from_devices()
 
@@ -474,27 +533,29 @@ class Module(BaseModule):
         assert self.binded and self.params_initialized
         if is_train is None:
             is_train = self.for_training
-        kwargs = {}
-        for name, arr in zip(self._data_names, data_batch.data):
-            kwargs[name] = arr
-        if data_batch.label is not None and self._label_names:
-            for name, arr in zip(self._label_names, data_batch.label):
+        with _tracing.span("mx.module.forward", "module"):
+            kwargs = {}
+            for name, arr in zip(self._data_names, data_batch.data):
                 kwargs[name] = arr
-        # shape change (e.g. final partial batch with pad) → jit recompiles;
-        # data AND label shapes must move together (reference: module.py
-        # reshape(data_shapes, label_shapes))
-        io_names = self._data_names + self._label_names
-        cur = {n: tuple(self._exec.arg_dict[n].shape)
-               for n in io_names if n in self._exec.arg_dict}
-        new = {n: tuple(kwargs[n].shape) for n in io_names if n in kwargs}
-        if any(cur.get(n) != s for n, s in new.items()):
-            self._exec = self._exec.reshape(**new)
-            self._apply_shardings()
-            self._fused_step = None
-            self._run_steps_cache = {}
-        self._exec.forward(is_train=is_train, **kwargs)
-        self._pending_backward = False
-        self._out_grads = None
+            if data_batch.label is not None and self._label_names:
+                for name, arr in zip(self._label_names, data_batch.label):
+                    kwargs[name] = arr
+            # shape change (e.g. final partial batch with pad) → jit
+            # recompiles; data AND label shapes must move together
+            # (reference: module.py reshape(data_shapes, label_shapes))
+            io_names = self._data_names + self._label_names
+            cur = {n: tuple(self._exec.arg_dict[n].shape)
+                   for n in io_names if n in self._exec.arg_dict}
+            new = {n: tuple(kwargs[n].shape)
+                   for n in io_names if n in kwargs}
+            if any(cur.get(n) != s for n, s in new.items()):
+                self._exec = self._exec.reshape(**new)
+                self._apply_shardings()
+                self._fused_step = None
+                self._run_steps_cache = {}
+            self._exec.forward(is_train=is_train, **kwargs)
+            self._pending_backward = False
+            self._out_grads = None
 
     def backward(self, out_grads=None):
         """Mark backward pending; gradients materialize lazily (or fuse into
@@ -515,12 +576,12 @@ class Module(BaseModule):
             and self.optimizer_initialized
         self._params_dirty = True
         opt = self._optimizer
-        names = self._update_names()
         use_fused = (env("MXNET_EXEC_BULK_EXEC_TRAIN", True)
                      and getattr(opt, "pure_update", False)
                      and not self._update_on_kvstore
                      and getattr(self, '_out_grads', None) is None)
         if not use_fused:
+            names = self._update_names()
             self._exec.backward(out_grads=getattr(self, '_out_grads', None))
             if self._update_on_kvstore:
                 _update_params_on_kvstore(
@@ -535,66 +596,156 @@ class Module(BaseModule):
                     kvstore=self._kvstore, param_names=names)
             self._pending_backward = False
             return
+        if self._updated_once:
+            self._update_fused(opt)
+        else:
+            # the first update of a Module: trace, lower, compile or
+            # cache load
+            with _tracing.phase("mx.module.first_update"):
+                self._update_fused(opt)
+            self._updated_once = True
 
-        sig = opt.hyperparam_signature()
-        if self._fused_step is None or \
-                getattr(self, "_fused_hparam_sig", None) != sig:
-            # hyperparameters (momentum, betas, rescale_grad...) are baked
-            # into the trace — rebuild if they were mutated mid-run
-            self._fused_step = self._build_fused_step(names)
-            self._fused_hparam_sig = sig
-        for n in names:
-            opt._update_count(n)
-        t = opt._index_update_count[names[0]] if names else 1
-        lrs = tuple(np.float32(opt._get_lr(n)) for n in names)
-        wds = tuple(np.float32(opt._get_wd(n)) for n in names)
-        # cache lr/wd device buffers while unchanged: per-step host→device
-        # scalar transfers (2 per param) would dominate step latency on a
-        # remote-attached chip
-        cache = getattr(self, "_lrwd_cache", None)
-        if cache is not None and cache[0] == (lrs, wds):
-            lrs, wds = cache[1]
-        else:
-            key_ = (lrs, wds)
-            lrs = tuple(jnp.asarray(v) for v in lrs)
-            wds = tuple(jnp.asarray(v) for v in wds)
-            self._lrwd_cache = (key_, (lrs, wds))
-        snapshot = self._exec._snapshot
-        if snapshot is None:
-            raise MXNetError("update() called before forward()")
-        arg_vals, aux_vals, key, _ = snapshot
-        pvals = tuple(arg_vals[i] for i in self._fused_upd_idx)
-        io_vals = tuple(arg_vals[i] for i in self._fused_io_idx)
-        states = tuple(tuple(s._data for s in self._opt_states[n])
-                       for n in names)
-        # t is only read by needs_t optimizers (Adam bias correction);
-        # otherwise reuse one cached device scalar instead of a per-step
-        # host→device transfer
-        if getattr(opt, "needs_t", False):
-            t_dev = jnp.asarray(t, jnp.int32)
-        else:
-            t_dev = getattr(self, "_t_const", None)
-            if t_dev is None:
-                t_dev = self._t_const = jnp.asarray(0, jnp.int32)
-        from .. import profiler as _prof
+    def _update_fused(self, opt):
+        # the span closes after _fused_step_call's frame is gone: freeing
+        # its ~5 references a parameter is part of what an update costs
+        with _tracing.span("mx.module.update", "module") as sp:
+            self._fused_step_call(opt, sp)
+
+    def _fused_step_call(self, opt, sp):
+        """One jitted call, between the host spans that split what
+        dispatching it costs (docs/OBSERVABILITY.md); ``sp`` is the
+        enclosing ``mx.module.update`` span's ring record, or None."""
+        with _tracing.span("mx.module.update.prep", "module"):
+            names = self._update_names()
+            sig = opt.hyperparam_signature()
+            if self._fused_step is None or \
+                    self._fused_hparam_sig != sig:
+                # hyperparameters (momentum, betas, rescale_grad...)
+                # are baked into the trace — rebuild if they were
+                # mutated mid-run
+                if self._fused_step is not None:
+                    self._report_rebuild(self._fused_hparam_sig, sig)
+                self._fused_step = self._build_fused_step(names)
+            for n in names:
+                opt._update_count(n)
+            t = opt._index_update_count[names[0]] if names else 1
+            lrs = tuple(np.float32(opt._get_lr(n)) for n in names)
+            wds = tuple(np.float32(opt._get_wd(n)) for n in names)
+            # cache lr/wd device buffers while unchanged: per-step
+            # host→device scalar transfers (2 per param) would
+            # dominate step latency on a remote-attached chip
+            cache = getattr(self, "_lrwd_cache", None)
+            if cache is not None and cache[0] == (lrs, wds):
+                lrs, wds = cache[1]
+            else:
+                key_ = (lrs, wds)
+                lrs = tuple(jnp.asarray(v) for v in lrs)
+                wds = tuple(jnp.asarray(v) for v in wds)
+                self._lrwd_cache = (key_, (lrs, wds))
+            snapshot = self._exec._snapshot
+            if snapshot is None:
+                raise MXNetError("update() called before forward()")
+            arg_vals, aux_vals, key, _ = snapshot
+            pvals = tuple(arg_vals[i] for i in self._fused_upd_idx)
+            io_vals = tuple(arg_vals[i] for i in self._fused_io_idx)
+            states = tuple(tuple(s._data for s in self._opt_states[n])
+                           for n in names)
+            # t is only read by needs_t optimizers (Adam bias
+            # correction); otherwise reuse one cached device scalar
+            # instead of a per-step host→device transfer
+            if getattr(opt, "needs_t", False):
+                t_dev = jnp.asarray(t, jnp.int32)
+            else:
+                t_dev = getattr(self, "_t_const", None)
+                if t_dev is None:
+                    t_dev = self._t_const = jnp.asarray(0, jnp.int32)
+            args = (pvals, io_vals, aux_vals, key, states, lrs, wds,
+                    t_dev)
+        if sp is not None:
+            sp.args = {"step": t}
         _prof.record_dispatch("fused_step.dispatch")
-        with _prof.scope("fused_train_step", "symbolic"):
-            outs, new_aux, new_params, new_states = self._fused_step(
-                pvals, io_vals, aux_vals, key, states, lrs, wds, t_dev)
-        exec_ = self._exec
-        if exec_._out_arrays is not None:
-            for oa, v in zip(exec_._out_arrays, outs):
-                oa._set_data(v)
-        for a, v in zip(exec_.aux_arrays, new_aux):
-            a._set_data(v)
-        for n, w in zip(names, new_params):
-            exec_.arg_dict[n]._set_data(w)
-        for n, st in zip(names, new_states):
-            for s, v in zip(self._opt_states[n], st):
-                s._set_data(v)
-        if self._fused_donate:
-            self._poison_after_donate()
-        self._pending_backward = False
+        with _tracing.span("mx.module.update.call", "module"):
+            outs, new_aux, new_params, new_states = \
+                self._fused_step(*args)
+        # one integer compare a step: a second entry means jit keyed
+        # this call's arguments apart from the last compile's
+        entries = self._fused_step._cache_size()
+        if entries != self._fused_cache_size:
+            self._note_step_compiled(names, args, entries)
+        with _tracing.span("mx.module.update.writeback", "module"):
+            exec_ = self._exec
+            if exec_._out_arrays is not None:
+                for oa, v in zip(exec_._out_arrays, outs):
+                    oa._set_data(v)
+            for a, v in zip(exec_.aux_arrays, new_aux):
+                a._set_data(v)
+            for n, w in zip(names, new_params):
+                exec_.arg_dict[n]._set_data(w)
+            for n, st in zip(names, new_states):
+                for s, v in zip(self._opt_states[n], st):
+                    s._set_data(v)
+            if self._fused_donate:
+                self._poison_after_donate()
+            self._pending_backward = False
+
+    def _step_arg_leaves(self, names, args):
+        """(label, value) for every leaf of the fused step's arguments,
+        labelled by what the Module calls it."""
+        pvals, io_vals, aux_vals, key, states, lrs, wds, t = args
+        arg_names = self._exec._arg_names
+        leaves = [("param %s" % n, v) for n, v in zip(names, pvals)]
+        leaves += [("input %s" % arg_names[i], v)
+                   for i, v in zip(self._fused_io_idx, io_vals)]
+        leaves += [("aux %s" % n, v)
+                   for n, v in zip(self._exec._aux_names, aux_vals)]
+        leaves.append(("rng key", key))
+        for n, st in zip(names, states):
+            leaves += [("optimizer state %d of %s" % (j, n), v)
+                       for j, v in enumerate(st)]
+        leaves += [("lr of %s" % n, v) for n, v in zip(names, lrs)]
+        leaves += [("wd of %s" % n, v) for n, v in zip(names, wds)]
+        leaves.append(("t", t))
+        return leaves
+
+    def _note_step_compiled(self, names, args, entries):
+        """The fused step's jit cache grew at this call.  The first entry
+        is the expected compile; a later one is a recompile: counted
+        (``fused_step.recompile``), marked in the trace, and explained on
+        stderr by what differs, leaf by leaf, between this call's
+        arguments and those of the last compile in what ``jit`` keys on.
+        Runs only when the cache grows."""
+        key = {label: _jit_leaf_key(v)
+               for label, v in self._step_arg_leaves(names, args)}
+        before, self._fused_jit_key = self._fused_jit_key, key
+        self._fused_cache_size = entries
+        if entries <= 1 or before is None:
+            return
+        _prof.record_dispatch("fused_step.recompile")
+        moved = {}      # (field, before, after) -> [leaf labels]
+        for label, now in key.items():
+            was = before.get(label, {})
+            for field in _JIT_KEY_FIELDS:
+                if was.get(field) != now[field]:
+                    moved.setdefault((field, was.get(field), now[field]),
+                                     []).append(label)
+        _report_step_compile(
+            "mx.module.update.recompile",
+            "the fused step compiled again (jit cache entry %d): between "
+            "the last compile's arguments and this call's" % entries,
+            moved)
+
+    def _report_rebuild(self, was, now):
+        """The optimizer's hyperparameter signature moved between two
+        updates: the step is built, traced and compiled anew."""
+        was, now = dict(was or ()), dict(now)
+        moved = {(k, was.get(k), now.get(k)): ["optimizer"]
+                 for k in sorted(set(was) | set(now))
+                 if was.get(k) != now.get(k)}
+        _prof.record_dispatch("fused_step.rebuild")
+        _report_step_compile(
+            "mx.module.update.rebuild",
+            "the fused step is rebuilt: the optimizer's hyperparameter "
+            "signature moved", moved)
 
     def _poison_after_donate(self):
         """A donated step consumed the old param/aux/state buffers; the
@@ -671,7 +822,10 @@ class Module(BaseModule):
                                  self._mesh, self._sharding_rules)
                 for n in names]
 
-        def step(pvals, io_vals, aux_vals, key, states, lrs, wds, t):
+        # the name is the compiled module's (jit_mx_fused_step) and part
+        # of its key in the persistent compile cache
+        def mx_fused_step(pvals, io_vals, aux_vals, key, states, lrs, wds,
+                          t):
             def f(pv):
                 av = [None] * len(arg_names)
                 for i, v in zip(upd_idx, pv):
@@ -688,39 +842,46 @@ class Module(BaseModule):
             grads = vjp_fn(cts)[0]
             # per-param dispatch shared with Trainer (optimizer.apply_fused
             # owns the multi-precision contract)
-            new_params, new_states = opt.apply_fused(
-                pvals, grads, states, lrs, wds, use_mp,
-                ts=(t,) * len(names) if needs_t else None)
-            if constrain:
-                # pin the schedule: params leave the step in their rule
-                # sharding (under ZeRO-1 the dp all-gather happens HERE,
-                # inside the fused program, overlapped by XLA)
-                from jax.sharding import NamedSharding
-                mesh_ = self._mesh
-                new_params = tuple(
-                    jax.lax.with_sharding_constraint(
-                        w, NamedSharding(mesh_, ps))
-                    for w, ps in zip(new_params, param_pspecs))
-            if zero1:
-                # state math stays dp-sharded (GSPMD reduce-scatters the
-                # grads feeding it)
-                new_states = _par.constrain_zero_states(
-                    new_states, self._mesh, self._zero_dp())
+            with jax.named_scope("optimizer"):
+                new_params, new_states = opt.apply_fused(
+                    pvals, grads, states, lrs, wds, use_mp,
+                    ts=(t,) * len(names) if needs_t else None)
+            with jax.named_scope("param_constraint"):
+                if constrain:
+                    # pin the schedule: params leave the step in their
+                    # rule sharding (under ZeRO-1 the dp all-gather
+                    # happens HERE, inside the fused program, overlapped
+                    # by XLA)
+                    from jax.sharding import NamedSharding
+                    mesh_ = self._mesh
+                    new_params = tuple(
+                        jax.lax.with_sharding_constraint(
+                            w, NamedSharding(mesh_, ps))
+                        for w, ps in zip(new_params, param_pspecs))
+                if zero1:
+                    # state math stays dp-sharded (GSPMD reduce-scatters
+                    # the grads feeding it)
+                    new_states = _par.constrain_zero_states(
+                        new_states, self._mesh, self._zero_dp())
             if with_grads:
                 return (outs, new_aux, tuple(new_params),
                         tuple(new_states), tuple(grads))
             return outs, new_aux, tuple(new_params), tuple(new_states)
 
-        return step
+        return mx_fused_step
 
     def _build_fused_step(self, names):
         # Donate the buffers the step replaces — params, aux (BN stats),
         # optimizer state — so XLA updates them in place in HBM (the analog
         # of the reference's in-place engine writes; halves peak param
         # memory and removes copy traffic).
-        self._fused_donate = bool(env("MXNET_FUSED_DONATE", True))
-        donate = (0, 2, 4) if self._fused_donate else ()
-        return jax.jit(self._make_step_body(names), donate_argnums=donate)
+        with _tracing.phase("mx.module.build_step"):
+            self._fused_donate = bool(env("MXNET_FUSED_DONATE", True))
+            donate = (0, 2, 4) if self._fused_donate else ()
+            self._fused_cache_size = 0
+            self._fused_hparam_sig = self._optimizer.hyperparam_signature()
+            return jax.jit(self._make_step_body(names),
+                           donate_argnums=donate)
 
     # -- multi-step driver --------------------------------------------------
     def run_steps(self, data, label=None, k=None, eval_metric=None):
@@ -893,7 +1054,6 @@ class Module(BaseModule):
         # with the dispatch: a failed compile/launch must not leave
         # counts K steps ahead of the params.
         from ..executor import precompute_step_schedules, schedule_rollback
-        from .. import profiler as _prof
         with schedule_rollback(opt):
             lrs, wds, tcols = precompute_step_schedules(opt, names, k)
             ts = tcols[0]
@@ -925,7 +1085,7 @@ class Module(BaseModule):
                 if use_dev_metric else ()
 
             _prof.record_dispatch("run_steps.dispatch")
-            with _prof.scope("run_steps_scan", "symbolic"):
+            with _tracing.span("mx.module.run_steps.call", "module"):
                 (new_pvals, new_aux, new_states, new_m), ys = fn(
                     (pvals, aux_vals, states, init_m),
                     (step_io, keys, lrs, wds, ts), const)
@@ -1029,7 +1189,6 @@ class Module(BaseModule):
         use_mp = [opt.mp_states_active(exec_.arg_dict[n],
                                        self._opt_states[n])
                   for n in names]
-        from .. import profiler as _prof
         with schedule_rollback(opt):
             # worker-side schedules advance per step exactly as the
             # server's per-push counts do (single worker: identical lr
@@ -1090,7 +1249,8 @@ class Module(BaseModule):
                       tuple(v[lo:hi] for v in lrs),
                       tuple(v[lo:hi] for v in wds), ts[lo:hi])
                 _prof.record_dispatch("run_steps.dist_chunk")
-                with _prof.scope("run_steps_dist_chunk", "symbolic"):
+                with _tracing.span("mx.module.run_steps.dist_chunk.call",
+                                   "module"):
                     (new_p, new_aux, new_st, new_m), (outs, grads) = fn(
                         (carry["pvals"], carry["aux"], carry["states"],
                          carry["m"]), xs, const)
@@ -1191,7 +1351,6 @@ class Module(BaseModule):
         metric per step.  Values are NDArray-wrapped — the classic
         custom-metric contract (user update() may call .asnumpy()), at
         the price of the legacy path's per-value syncs."""
-        from .. import profiler as _prof
         host_outs = jax.device_get(ys)
         _prof.record_dispatch("run_steps.readback")
         _prof.record_host_sync("run_steps.metric_fold")
@@ -1285,7 +1444,6 @@ class Module(BaseModule):
         else:
             import pickle
             import jax
-            from .. import profiler as _prof
             # ONE stacked readback for every state tensor (was one
             # np.asarray sync per state), recorded under the host-sync
             # contract like every other deliberate readback site

@@ -28,6 +28,7 @@ from ..context import Context, current_context, cpu
 from .. import autograd as _ag
 from .. import profiler as _prof
 from .. import random as _rnd
+from .. import tracing as _tracing
 from ..ops import registry as _reg
 
 
@@ -88,7 +89,8 @@ class NDArray:
     # -- engine sync points (reference: NDArray::WaitToRead/WaitToWrite) ----
     def wait_to_read(self):
         _prof.record_host_sync("ndarray.wait_to_read")
-        self._data.block_until_ready()
+        with _tracing.span("mx.sync.ndarray.wait_to_read", "sync"):
+            self._data.block_until_ready()
         return self
 
     wait_to_write = wait_to_read
@@ -142,15 +144,16 @@ class NDArray:
         # the regression gate; see metric.EvalMetric.sync)
         _prof.record_host_sync("ndarray.asnumpy")
         data = self._data
-        if (hasattr(data, "sharding")
-                and not getattr(data, "is_fully_addressable", True)):
-            # global array from a multi-process SPMD mesh: gather the
-            # non-addressable shards over the coordination backend (the
-            # analog of the reference's kvstore pull to host)
-            from jax.experimental import multihost_utils
-            return np.asarray(
-                multihost_utils.process_allgather(data, tiled=True))
-        return np.asarray(data)
+        with _tracing.span("mx.sync.ndarray.asnumpy", "sync"):
+            if (hasattr(data, "sharding")
+                    and not getattr(data, "is_fully_addressable", True)):
+                # global array from a multi-process SPMD mesh: gather the
+                # non-addressable shards over the coordination backend
+                # (the analog of the reference's kvstore pull to host)
+                from jax.experimental import multihost_utils
+                return np.asarray(
+                    multihost_utils.process_allgather(data, tiled=True))
+            return np.asarray(data)
 
     def asscalar(self):
         if self.size != 1:
@@ -501,7 +504,6 @@ def _invoke(op_name: str, inputs, attrs, out=None):
 
     vals = [x._data for x in inputs]
     fn = opdef.fn
-    from .. import profiler as _prof
     with _prof.scope(opdef.name, require_mode="all"):
         if rng_key is not None:
             outs = fn(rng_key, *vals, **kwargs)

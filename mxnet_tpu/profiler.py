@@ -4,10 +4,13 @@ TPU-native re-design of the reference profiler (src/engine/profiler.h:79
 OprExecStat collection inside the engine; python/mxnet/profiler.py:27-55
 set_config/set_state/dump_profile).  Two layers:
 
-* **host events** — the dispatch layer (eager `_invoke`, Executor
-  forward/backward, fused Module steps) records {name, start µs, dur µs}
-  pairs exactly like the reference's per-opr stats, dumped in
-  chrome://tracing format so the same tooling opens both.
+* **host events** — every ``tracing.span`` in the program (eager
+  `_invoke`, Executor forward/backward, fused Module steps, kvstore,
+  serving) becomes a {name, start µs, dur µs} event while the profiler
+  runs, exactly like the reference's per-opr stats, dumped in
+  chrome://tracing format so the same tooling opens both.  This module
+  is a SINK: the spans, their clock (``tracing.now_us``) and their
+  other two sinks live in :mod:`mxnet_tpu.tracing`.
 * **device truth** — `start()/stop()` also drive `jax.profiler`
   (``MXNET_PROFILER_XLA_LOGDIR``), capturing the XLA/TPU xplane trace;
   per-op names survive into HLO metadata.
@@ -52,8 +55,9 @@ class _Profiler:
         self._xla_running = False
 
     # -- event capture -----------------------------------------------------
-    def record(self, name, start_us, dur_us, category="operator",
-               tid=None):
+    def record(self, name, category, start_us, dur_us):
+        """The chrome-trace sink ``tracing.span`` feeds while the profiler
+        runs; times are on ``tracing.now_us()``'s clock."""
         if self.state != PROFILER_STATE_RUN:
             return
         with self._lock:
@@ -61,12 +65,15 @@ class _Profiler:
                 "name": name, "cat": category, "ph": "X",
                 "ts": start_us, "dur": dur_us,
                 "pid": os.getpid(),
-                "tid": tid if tid is not None else
-                threading.get_ident() % 100000,
+                "tid": threading.get_ident() % 100000,
             })
 
-    def scope(self, name, category="operator"):
-        return _Scope(self, name, category)
+    def record_ended(self, name, category, dur_s):
+        """An interval of ``dur_s`` seconds that ends now (the wire and
+        latency clocks, which time themselves)."""
+        if self.state == PROFILER_STATE_RUN:
+            dur_us = float(dur_s) * 1e6
+            self.record(name, category, tracing.now_us() - dur_us, dur_us)
 
     # -- lifecycle ---------------------------------------------------------
     def set_state(self, state):
@@ -80,6 +87,8 @@ class _Profiler:
                 self.state = state
                 self.dump()
         self.state = state
+        tracing.set_chrome_sink(
+            self.record if state == PROFILER_STATE_RUN else None)
 
     def _maybe_start_xla(self):
         logdir = self._xla_logdir or env("MXNET_PROFILER_XLA_LOGDIR", None)
@@ -101,25 +110,6 @@ class _Profiler:
                 self._events = []
         with open(self.filename, "w") as f:
             json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
-
-
-class _Scope:
-    __slots__ = ("_p", "_name", "_cat", "_t0")
-
-    def __init__(self, p, name, cat):
-        self._p = p
-        self._name = name
-        self._cat = cat
-
-    def __enter__(self):
-        self._t0 = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *a):
-        if self._p.state == PROFILER_STATE_RUN:
-            t1 = time.perf_counter_ns()
-            self._p.record(self._name, self._t0 // 1000,
-                           (t1 - self._t0) // 1000, self._cat)
 
 
 _profiler = _Profiler()
@@ -174,10 +164,6 @@ def is_running():
     return _profiler.state == PROFILER_STATE_RUN
 
 
-def record_event(name, start_us, dur_us, category="operator"):
-    _profiler.record(name, start_us, dur_us, category)
-
-
 # -- span tracing (mxnet_tpu.tracing; docs/OBSERVABILITY.md) -----------------
 # The profiler's cross-process face: span_begin/span_end with a
 # thread-local current span, monotonic clocks, a bounded ring and the
@@ -190,6 +176,9 @@ span_begin = tracing.span_begin
 span_end = tracing.span_end
 trace_instant = tracing.instant
 trace_enabled = tracing.enabled
+# set-up phases (bind, init_params, the first update's compile), always
+# on: {name: [seconds of each occurrence, in order]}
+phase_seconds = tracing.phase_seconds
 
 
 # -- host-dispatch counters --------------------------------------------------
@@ -503,11 +492,7 @@ def record_wire_wait(dur_s: float):
     trace export."""
     with _wire_lock:
         _wire["wait_s"] += float(dur_s)
-    if _profiler.state == PROFILER_STATE_RUN:
-        dur_us = float(dur_s) * 1e6
-        _profiler.record("kvstore.wire_wait",
-                         time.perf_counter_ns() // 1000 - int(dur_us),
-                         dur_us, "wire")
+    _profiler.record_ended("kvstore.wire_wait", "wire", dur_s)
 
 
 def record_wire_round(dur_s: float):
@@ -517,11 +502,7 @@ def record_wire_round(dur_s: float):
     with _wire_lock:
         _wire["round_s"] += float(dur_s)
         _wire["rounds"] += 1
-    if _profiler.state == PROFILER_STATE_RUN:
-        dur_us = float(dur_s) * 1e6
-        _profiler.record("kvstore.wire_round",
-                         time.perf_counter_ns() // 1000 - int(dur_us),
-                         dur_us, "wire")
+    _profiler.record_ended("kvstore.wire_round", "wire", dur_s)
 
 
 def wire_wait_ms() -> float:
@@ -573,11 +554,7 @@ def record_mesh_fanin_wait(dur_s: float):
     with _fanin_lock:
         _fanin["wait_s"] += float(dur_s)
         _fanin["rounds"] += 1
-    if _profiler.state == PROFILER_STATE_RUN:
-        dur_us = float(dur_s) * 1e6
-        _profiler.record("kvstore.mesh_fanin",
-                         time.perf_counter_ns() // 1000 - int(dur_us),
-                         dur_us, "wire")
+    _profiler.record_ended("kvstore.mesh_fanin", "wire", dur_s)
 
 
 def mesh_fanin_wait_ms() -> float:
@@ -620,15 +597,9 @@ def record_latency(kind: str, dur_s: float, ts: Optional[float] = None):
     injectable so the QPS arithmetic is testable without sleeping)."""
     if ts is None:
         ts = time.monotonic()
-    if _profiler.state == PROFILER_STATE_RUN:
-        # latency samples used to live only in the percentile ring and
-        # never reached the chrome-trace export; emit each completed
-        # request as a trace event so a single-process serving trace
-        # shows queue-wait + forward time per request
-        dur_us = float(dur_s) * 1e6
-        _profiler.record(kind,
-                         time.perf_counter_ns() // 1000 - int(dur_us),
-                         dur_us, "latency")
+    # each completed request is also a chrome-trace event, so a
+    # single-process serving trace shows queue-wait + forward time
+    _profiler.record_ended(kind, "latency", dur_s)
     with _latency_lock:
         st = _latency.get(kind)
         if st is None:
@@ -697,14 +668,12 @@ _NULL = __import__("contextlib").nullcontext()
 
 
 def scope(name, category="operator", require_mode=None):
-    """Context manager for dispatch sites.  Returns a no-op context when
-    the profiler is stopped (or the mode doesn't match), so call sites
-    are just ``with profiler.scope(...):`` — all gating lives here."""
-    if _profiler.state != PROFILER_STATE_RUN:
-        return _NULL
+    """API-parity alias of ``tracing.span(name, category)``, the one span
+    entry point.  ``require_mode="all"`` (the eager per-operator site)
+    keeps its meaning: nothing unless the profiler's mode is "all"."""
     if require_mode is not None and _profiler.mode != require_mode:
         return _NULL
-    return _profiler.scope(name, category)
+    return tracing.span(name, category)
 
 
 # -- the universal snapshot ---------------------------------------------------

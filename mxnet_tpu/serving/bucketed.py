@@ -258,7 +258,7 @@ class BucketedPredictor:
             self._compiled.add(bucket)
             _prof.record_dispatch("serving.predict_compile")
         _prof.record_dispatch("serving.predict")
-        with _prof.scope("serving_predict", "symbolic"):
+        with _prof.span("mx.serving.predict.call", "serving"):
             outs = self._jit(tuple(arg_vals), aux_vals, self._key)
         host = jax.device_get(outs)
         # the reply crosses the wire as host bytes: this readback is the

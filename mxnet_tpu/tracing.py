@@ -30,10 +30,23 @@ arXiv:1605.08695; MXNet's engine-integrated profiler, arXiv:1512.01274):
   timeline with cross-process flow arrows.
 
 Master switch: ``MXNET_TRACE=1``.  Off (the default) every entry point
-returns before touching a lock or allocating — call sites guard with
-``tracing.enabled()`` or use ``span()``'s shared null context — and the
-kvstore envelope stays byte-identical to the untraced wire (pinned by
+returns before touching a lock or allocating a record — call sites guard
+with ``tracing.enabled()`` or use ``span()`` — and the kvstore envelope
+stays byte-identical to the untraced wire (pinned by
 tests/test_tracing.py via ``profiler.channel_bytes``).
+
+**One entry point, three sinks.**  ``span(name, cat)`` is the one call
+every program site makes (``profiler.scope`` is its alias).  Beneath the
+switch it always enters a ``jax.profiler.TraceAnnotation(name)``: while a
+JAX profiler session is live (``jax.profiler.start_trace``, or
+``profiler_set_state('run')`` with an ``xla_logdir``) the span lands on
+the ``/host:CPU`` plane of the same ``.xplane.pb`` as the device
+operations, on the same clock by construction.  The other two sinks are
+host-side and share :func:`now_us`: the ring/journal above
+(``MXNET_TRACE=1``), and the MXNet profiler's chrome-trace events while
+it runs (``profiler`` registers itself through :func:`set_chrome_sink`).
+Set-up phases (:func:`phase`) also feed an always-on clock,
+:func:`phase_seconds`: a handful of entries a process, never per step.
 """
 from __future__ import annotations
 
@@ -46,6 +59,8 @@ import uuid
 from collections import deque
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from .base import env
 
 # wall-clock anchor for the monotonic span clock: epoch_us(span) =
@@ -53,8 +68,6 @@ from .base import env
 # this process shares one mapping; cross-process residual skew is
 # estimated at merge time from envelope send/recv pairs.
 _ANCHOR_NS = time.time_ns() - time.monotonic_ns()
-
-_NULL = __import__("contextlib").nullcontext()
 
 _lock = threading.Lock()
 _tls = threading.local()
@@ -222,8 +235,19 @@ def span_end(sp: Optional[Span], args=None) -> None:
             sp.t0, t1, sp.args)
 
 
+class _Annotation(TraceAnnotation):
+    """What ``span()`` returns with both host sinks off: the profiler
+    annotation alone, one C++ object, yielding None as the disabled
+    contract says."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        TraceAnnotation.__enter__(self)
+
+
 class _SpanCtx:
-    __slots__ = ("_sp", "_a")
+    __slots__ = ("_a", "_sp", "_ann", "_t0")
 
     def __init__(self, name, cat, ctx, args):
         self._a = (name, cat, ctx, args)
@@ -231,19 +255,76 @@ class _SpanCtx:
 
     def __enter__(self):
         name, cat, ctx, args = self._a
+        self._ann = TraceAnnotation(str(name))
+        self._ann.__enter__()
+        self._t0 = time.monotonic_ns()
         self._sp = span_begin(name, cat=cat, ctx=ctx, args=args)
         return self._sp
 
     def __exit__(self, *exc):
+        t1 = time.monotonic_ns()
         span_end(self._sp)
+        sink = _chrome_sink
+        if sink is not None:
+            sink(self._a[0], self._a[1], (self._t0 + _ANCHOR_NS) / 1e3,
+                 (t1 - self._t0) / 1e3)
+        self._ann.__exit__(*exc)
+
+
+# the MXNet profiler's chrome-trace sink while it runs, else None:
+# called as sink(name, cat, ts_us, dur_us) with times from now_us()'s clock
+_chrome_sink = None
+
+
+def set_chrome_sink(sink) -> None:
+    global _chrome_sink
+    _chrome_sink = sink
 
 
 def span(name, cat="span", ctx=None, args=None):
-    """``with tracing.span("kv.pull"):`` — the one-liner form.  Returns
-    a shared null context when tracing is off."""
-    if not _state.on:
-        return _NULL
+    """``with tracing.span("kv.pull"):`` — the one span entry point.
+    Always a ``jax.profiler.TraceAnnotation`` (a live profiler session
+    records it beside the device operations); with ``MXNET_TRACE`` on
+    also a ring/journal record, which the context yields (else None);
+    while the MXNet profiler runs also a chrome-trace event."""
+    if not _state.on and _chrome_sink is None:
+        return _Annotation(name)
     return _SpanCtx(name, cat, ctx, args)
+
+
+# -- set-up phases -----------------------------------------------------------
+_phases: dict = {}
+
+
+class _Phase:
+    __slots__ = ("_name", "_span", "_t0")
+
+    def __init__(self, name, cat):
+        self._name = name
+        self._span = span(name, cat)
+
+    def __enter__(self):
+        self._t0 = time.monotonic_ns()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        dur = (time.monotonic_ns() - self._t0) / 1e9
+        with _lock:
+            _phases.setdefault(self._name, []).append(dur)
+
+
+def phase(name, cat="setup"):
+    """A span around a set-up phase (bind, init_params, the first
+    update's compile): a few a process, so its seconds are also kept,
+    switch or no switch, for :func:`phase_seconds`."""
+    return _Phase(name, cat)
+
+
+def phase_seconds() -> dict:
+    """{phase name: [seconds of each occurrence, in order]}."""
+    with _lock:
+        return {k: list(v) for k, v in _phases.items()}
 
 
 def instant(name, cat="instant", args=None) -> None:
@@ -363,6 +444,7 @@ def ring_records() -> list:
 
 def stats() -> dict:
     """The tracing block of ``profiler.snapshot()``."""
+    phases = phase_seconds()
     with _lock:
         return {
             "enabled": _state.on,
@@ -370,15 +452,17 @@ def stats() -> dict:
             "ring": len(_state.ring),
             "ring_max": _state.ring.maxlen,
             "file": trace_file_path(),
+            "phases": phases,
         }
 
 
 def reset() -> None:
-    """Clear the ring and counters (tests); the file, being append-only
-    evidence, is left alone."""
+    """Clear the ring, counters and phase clock (tests); the file, being
+    append-only evidence, is left alone."""
     with _lock:
         _state.ring.clear()
         _state.recorded = 0
+        _phases.clear()
 
 
 def read_trace_file(path) -> list:

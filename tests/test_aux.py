@@ -35,7 +35,7 @@ def test_profiler_chrome_trace(tmp_path):
         trace = json.load(fin)
     names = {e['name'] for e in trace['traceEvents']}
     assert 'dot' in names
-    assert 'executor_forward' in names
+    assert 'mx.executor.forward.call' in names
     for e in trace['traceEvents']:
         assert e['ph'] == 'X' and 'ts' in e and 'dur' in e
 
