@@ -96,14 +96,21 @@ def judge_deltas(table, output_weight):
 
 
 def judge_window(losses, compiles, attempted, completed):
-    """Rule 3: what the measured window itself has to show."""
-    finite = all(math.isfinite(x) for x in losses)
+    """Rule 3: what the measured window itself has to show.  The details
+    name the first step whose loss is not finite (its index into the
+    window's losses, None where all are finite): a recipe that blows up
+    does so at a step count, and a window that holds fewer steps never
+    sees it (chipbench/README.md, "A cell's recipe")."""
+    nonfinite = next((i for i, x in enumerate(losses)
+                      if not math.isfinite(x)), None)
+    finite = nonfinite is None
     n = min(10, len(losses) // 2)
     first = sum(losses[:n]) / n if n else math.nan
     last = sum(losses[-n:]) / n if n else math.nan
     ok = (finite and compiles == 0 and completed == attempted
           and n > 0 and last < first)
-    return ok, {"losses_finite": finite, "compiles_in_window": compiles,
+    return ok, {"losses_finite": finite, "first_nonfinite_step": nonfinite,
+                "compiles_in_window": compiles,
                 "attempted": attempted, "completed": completed,
                 "loss_first_mean": first, "loss_last_mean": last,
                 "averaged_over": n}

@@ -244,10 +244,15 @@ def reference_check(r, built, batch, first):
            % ("ok" if ok_f else "FAILED", json.dumps(fwd)))
     r.mark("reference check, deltas %s: %s"
            % ("ok" if ok_d else "FAILED", json.dumps(deltas)))
-    own = [table[n]["e"] for n in names
-           if table[n]["c"] <= correct.C_DECIDABLE]
+    decided = [n for n in names if table[n]["c"] <= correct.C_DECIDABLE]
+    own = [table[n]["e"] for n in decided]
     in32 = [table[n]["e32"] for n in deltas["decided_in_float32"]]
-    summary = {"loss_rel": fwd["loss_rel"], "out_rel_l2": fwd["out_rel_l2"],
+    # every decided tensor's reading over its own limit: the worst, limit 1
+    over = [table[n]["e"] / max(correct.DELTA_FACTOR * table[n]["c"],
+                                correct.DELTA_FLOOR) for n in decided] \
+        + [e / correct.DELTA_FLOOR for e in in32]
+    summary = {"loss_rel": fwd["loss_rel"], "loss_tol": fwd["loss_tol"],
+               "out_rel_l2": fwd["out_rel_l2"],
                "out_tol": fwd["out_tol"], "tensors": deltas["tensors"],
                "decidable": deltas["decidable"],
                "decided_in_float32": len(in32),
@@ -255,6 +260,8 @@ def reference_check(r, built, batch, first):
                "failing": deltas["failing"],
                "max_e_decidable": max(own, default=None),
                "max_e32": max(in32, default=None),
+               "max_e_over_tol": max(over, default=None),
+               "max_e_over_tol_limit": 1.0,
                "output_weight_e": table[built["output_weight"]]["e"],
                "output_weight_c": table[built["output_weight"]]["c"]}
     return ok_f and ok_d, summary
@@ -280,6 +287,13 @@ def longest_interval(stamps):
         return math.nan, 0, []
     k = max(range(len(gaps)), key=gaps.__getitem__)
     return gaps[k], k + 1, [round(g, 1) for g in gaps[k + 1:k + 4]]
+
+
+def printable(details):
+    """A verdict's details as the result line can carry them: a loss that
+    is not finite goes as its name ("inf", "nan"), which is no JSON number."""
+    return {k: repr(v) if isinstance(v, float) and not math.isfinite(v)
+            else v for k, v in details.items()}
 
 
 def run(r):
@@ -338,6 +352,10 @@ def run(r):
                   for k, v in prof.dispatch_counts().items()}
     # beside the p90: the longest single interval with what followed it
     longest = longest_interval(stamps)
+    # every step's loss: how near the recipe came to rule 3's edge is in
+    # the curve, and no side file keeps it
+    r.mark("window losses, step 0 on: %s" % " ".join("%.4g" % x
+                                                     for x in losses))
     r.mark("window on %s: %d steps in %.4f s; step_ms_p90 %.4f over %d "
            "intervals of one step; longest interval %.1f ms ending on step "
            "%d, then %s; loss first %.6f last %.6f; %s; host syncs %d; "
@@ -358,7 +376,9 @@ def run(r):
         "steps": steps, "window_s": window_s,
         "items_per_step": built["items_per_step"], "chips": len(r.devices),
         "compile": r.clock.t, "host_dispatch_s": dispatch_s,
-        "host_syncs": syncs, "reference": ref_report,
+        "host_syncs": syncs,
+        # the line's last key: each number compared, beside its limit
+        "reference": dict(ref_report, window=printable(win)),
         "model_flops_per_step": fam.model_flops(cfg, traffic),
         "kernel_costs": (fam.kernel_costs(cfg, traffic)
                          if hasattr(fam, "kernel_costs") else {}),

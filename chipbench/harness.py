@@ -228,11 +228,14 @@ def run(args, t0):
         line["metrics"] = {k: {"value": float(v), "unit": u}
                            for k, (v, u) in record["end_to_end"].items()}
     line["device"] = info
-    if "reference" in record:
-        # not read by the driver: how the Module met its plain reference
-        line["reference"] = record["reference"]
     if rehearsal:
         line["rehearsal"] = "platform %s: not a result" % info["platform"]
+    if "reference" in record:
+        # not read by the driver.  The line's last key and stderr's last
+        # line: each number the check compared, beside its limit
+        line["reference"] = record["reference"]
+        mark("compared, each beside its limit: %s; correct: %s"
+             % (json.dumps(record["reference"]), line["correct"]))
     return line
 
 

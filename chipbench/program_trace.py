@@ -51,10 +51,9 @@ import re
 import sys
 
 from . import common
-from .trace_reduce import DEVICE_PLANE, HOST_PLANE, OPS_LINE, union
+from .trace_reduce import (DEVICE_PLANE, HOST_PLANE, OPS_LINE,
+                           PROGRAM_PREFIX, SPAN_PREFIX, gap_name, union)
 
-PROGRAM_PREFIX = "mx."
-YARDSTICK_PREFIX = "chipbench."
 PHASES = ("forward", "backward", "optimizer", "unscoped")
 OPTIMIZER_SCOPES = ("optimizer", "param_constraint")
 # the stat of an event's metadata that holds the HLO's op_name
@@ -202,7 +201,7 @@ def read_planes(path):
                 events = [[e.name, float(e.start_ns), float(e.duration_ns),
                            None] for e in line.events
                           if e.name.startswith((PROGRAM_PREFIX,
-                                                YARDSTICK_PREFIX))]
+                                                SPAN_PREFIX))]
             elif line.name == OPS_LINE:
                 events = [[e.name, float(e.start_ns), float(e.duration_ns),
                            _scope_path(e.name, scopes)]
@@ -259,17 +258,6 @@ def self_times(events):
     return out
 
 
-def _span_over(spans, t0, t1):
-    """The span covering most of [t0, t1]; of spans covering the same, the
-    innermost (the shortest)."""
-    best, best_key = None, (0.0, 0.0)
-    for name, s, d, _ in spans:
-        cover = min(t1, s + d) - max(t0, s)
-        if cover > 0 and (cover, -d) > best_key:
-            best, best_key = name, (cover, -d)
-    return best
-
-
 def summarize(planes, steps):
     """What the metrics and the report read, from ``read_planes``' form.
     Device times are sums over the ``XLA Ops`` events (the line is
@@ -307,7 +295,6 @@ def summarize(planes, steps):
             entry["self_s"] += own / 1e9
             entry["count"] += count
     every = [e for events in threads for e in events]
-    program = [e for e in every if e[0].startswith(PROGRAM_PREFIX)]
     merged = union([s, s + d] for _, s, d, _ in device[0])
     gaps = sorted(((b[0] - a[1], a[1], b[0])
                    for a, b in zip(merged, merged[1:])), reverse=True)[:5]
@@ -320,8 +307,7 @@ def summarize(planes, steps):
                           for (phase, node), v in node_ns.items()),
                          key=lambda r: -r[2]),
         "spans": spans,
-        "idle_gaps": [[_span_over(program, s, e) or _span_over(every, s, e)
-                       or "(no span)", g / 1e9] for g, s, e in gaps],
+        "idle_gaps": [[gap_name(every, s, e), g / 1e9] for g, s, e in gaps],
     }
 
 

@@ -5,7 +5,8 @@ The file is ``<dir>/plugins/profile/<time>/*.xplane.pb``, read with jax
 alone.  A device plane ``/device:TPU:<n>`` has the lines ``XLA Ops``
 (serial: the union of its events is busy time) and ``Async XLA Ops``
 (overlapping copies whose sum far exceeds the span: never added).  Host
-spans (``jax.profiler.TraceAnnotation``) are events on the lines of plane
+spans (``jax.profiler.TraceAnnotation``: the program's ``mx.*`` and the
+yardstick's ``chipbench.*``) are events on the lines of plane
 ``/host:CPU``, on the same clock.
 
 ``read_planes`` turns the file into plain dicts and lists, which is what
@@ -20,8 +21,11 @@ import sys
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
+# host spans: the program's own (mxnet_tpu.tracing.span) and the
+# yardstick's, around its calls into the program
+PROGRAM_PREFIX = "mx."
 SPAN_PREFIX = "chipbench."
-NO_SPAN = "(no chipbench span)"
+NO_SPAN = "(no span)"
 PLANE_PEAK_STATS = ("peak_teraflops_per_second",
                     "peak_hbm_bw_gigabytes_per_second")
 
@@ -50,7 +54,8 @@ def read_planes(path):
                 continue
             events = [[e.name, float(e.start_ns), float(e.duration_ns)]
                       for e in line.events
-                      if not host or e.name.startswith(SPAN_PREFIX)]
+                      if not host or e.name.startswith((PROGRAM_PREFIX,
+                                                        SPAN_PREFIX))]
             if events:
                 lines.append({"name": line.name, "events": events})
         stats = {}
@@ -91,14 +96,26 @@ def union(intervals):
     return merged
 
 
-def _span_at(spans, t0, t1):
-    """Name of the chipbench span that covers most of [t0, t1]."""
-    best, best_cover = NO_SPAN, 0.0
-    for name, s, d in spans:
-        cover = min(t1, s + d) - max(t0, s)
-        if cover > best_cover:
-            best, best_cover = name, cover
+def span_over(spans, t0, t1, least=0.0):
+    """Name of the span ([name, start, duration, ..]) covering most of
+    [t0, t1], and more than ``least`` of it; of spans covering the same,
+    the innermost (the shortest); None where there is none."""
+    best, best_key = None, (least, 0.0)
+    for e in spans:
+        cover = min(t1, e[1] + e[2]) - max(t0, e[1])
+        if cover > 0 and (cover, -e[2]) > best_key:
+            best, best_key = e[0], (cover, -e[2])
     return best
+
+
+def gap_name(spans, t0, t1):
+    """What the host was doing over [t0, t1]: the innermost ``mx.*`` span
+    that covers most of it (over half), which says where in the program
+    the device was left waiting; where the program had none open, the
+    ``chipbench.*`` span, which says where in the yardstick's loop."""
+    program = [e for e in spans if e[0].startswith(PROGRAM_PREFIX)]
+    return (span_over(program, t0, t1, least=0.5 * (t1 - t0))
+            or span_over(spans, t0, t1) or NO_SPAN)
 
 
 def reduce_planes(planes, steps, kernel_prefixes=()):
@@ -110,7 +127,7 @@ def reduce_planes(planes, steps, kernel_prefixes=()):
     device = [p for p in planes if DEVICE_PLANE.match(p["name"])]
     spans = [e for p in planes if p["name"] == HOST_PLANE
              for ln in p["lines"] for e in ln["events"]
-             if e[0].startswith(SPAN_PREFIX)]
+             if e[0].startswith((PROGRAM_PREFIX, SPAN_PREFIX))]
     ops_by_plane = [[e for ln in p["lines"] if ln["name"] == OPS_LINE
                      for e in ln["events"]] for p in device]
     ops_by_plane = [ops for ops in ops_by_plane if ops]
@@ -145,7 +162,7 @@ def reduce_planes(planes, steps, kernel_prefixes=()):
         "steps": int(steps), "device_planes": n,
         "window_s": (t1 - t0) / 1e9, "busy_s": busy_ns / n / 1e9,
         "device_ops": [[op_label[k], v / n / 1e9] for k, v in top[:10]],
-        "idle_gaps": [[_span_at(spans, s, e), g / 1e9]
+        "idle_gaps": [[gap_name(spans, s, e), g / 1e9]
                       for g, s, e in gaps[:5]],
         "kernel_s": {k: v / n / 1e9 for k, v in kernel_ns.items()},
         "kernel_calls": {k: v // n for k, v in kernel_calls.items()},
@@ -158,17 +175,24 @@ def reduce_file(path, steps, kernel_prefixes=()):
 
 
 def cut(planes, t0_ns, t1_ns, name_chars=100):
-    """The events that start inside [t0_ns, t1_ns), their names (whole HLO
-    lines, kilobytes each) cut to what ``short_name`` reads: how the
-    recorded sample was made from a whole trace."""
+    """The device events that start inside [t0_ns, t1_ns), their names
+    (whole HLO lines, kilobytes each) cut to what ``short_name`` reads, and
+    the host spans that overlap it (a gap is named by a span that may have
+    opened long before): how the recorded sample was made from a whole
+    trace."""
     def compact(name):
         kind = re.search(r"kind=k\w+", name[name_chars:])
         return name[:name_chars] + (" ... " + kind.group(0) if kind else "")
 
+    def keep(plane, s, d):
+        if plane["name"] == HOST_PLANE:
+            return s < t1_ns and s + d > t0_ns
+        return t0_ns <= s < t1_ns
+
     return [{"name": p["name"], "stats": p["stats"], "lines": [
         {"name": ln["name"], "events": [[compact(n), s, d]
                                         for n, s, d in ln["events"]
-                                        if t0_ns <= s < t1_ns]}
+                                        if keep(p, s, d)]}
         for ln in p["lines"]]} for p in planes]
 
 

@@ -5,6 +5,7 @@ no jax device is touched while this file is imported."""
 import json
 import math
 import os
+import shutil
 import sys
 
 import pytest
@@ -22,19 +23,25 @@ def manifest():
         return json.load(f)
 
 
-def run_cell(capsys, name, trace):
+def run_cell_err(capsys, name, trace, root=DATA):
+    """(the cell's result line, the run's stderr lines)."""
     rc = harness.main(["--workload", name, "--seed", "3000000019",
                        "--seconds", "1", "--trace", str(trace),
-                       "--root", DATA])
-    out = capsys.readouterr().out.strip().splitlines()
+                       "--root", root])
+    got = capsys.readouterr()
     assert rc == 0
-    return json.loads(out[-1])
+    return (json.loads(got.out.strip().splitlines()[-1]),
+            got.err.strip().splitlines())
+
+
+def run_cell(capsys, name, trace):
+    return run_cell_err(capsys, name, trace)[0]
 
 
 # -- the harness end to end, one tiny cell per family -------------------------
 @pytest.mark.parametrize("name,trace", [("tiny.rn18", 0), ("tiny.lm", 1)])
 def test_tiny_cell_prints_a_contract_line(capsys, name, trace):
-    line = run_cell(capsys, name, trace)
+    line, err = run_cell_err(capsys, name, trace)
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
     assert line["correct"] is True
     assert line["failed"] == 0 and line["attempted"] >= 4
@@ -60,6 +67,63 @@ def test_tiny_cell_prints_a_contract_line(capsys, name, trace):
     assert ref["decidable"] == ref["tensors"] and not ref["failing"]
     assert ref["max_e_decidable"] < 2e-2
     assert ref["output_weight_e"] < 1e-3
+    assert ref["max_e_over_tol"] < ref["max_e_over_tol_limit"] == 1.0
+    # each number compared stands beside its limit under the line's last
+    # key and on stderr's last line; the window's verdict says where
+    assert list(line)[-1] == "reference"
+    assert ref["window"]["first_nonfinite_step"] is None
+    assert ref["window"]["loss_last_mean"] < ref["window"]["loss_first_mean"]
+    assert err[-1].split("] ", 1)[1].startswith("compared, each beside")
+    assert json.dumps(ref["window"]) in err[-1]
+    window = [ln for ln in err if "] window on " in ln]
+    assert len(window) == 1
+    assert '"first_nonfinite_step": null' in window[0]
+
+
+def test_a_recipe_that_blows_up_is_not_correct_and_names_the_step(
+        capsys, tmp_path):
+    """What gpt2m.train.resident did past step 160 (PERF.md, PR 27), at a
+    learning rate that does it at once: the first step still meets its
+    reference, the window's losses stop being finite, the run prints its
+    line all the same, and the verdict names the step."""
+    root = str(tmp_path / "data")
+    shutil.copytree(DATA, root)
+    path = os.path.join(root, "configs", "tiny-lm.json")
+    with open(path) as f:
+        config = json.load(f)
+    config["optimizer"]["learning_rate"] = 30.0
+    with open(path, "w") as f:
+        json.dump(config, f)
+    line, err = run_cell_err(capsys, "tiny.lm", 0, root)
+    assert line["correct"] is False
+    ref = line["reference"]
+    assert not ref["failing"] and ref["loss_rel"] < 1e-5
+    win = ref["window"]
+    assert win["losses_finite"] is False
+    assert isinstance(win["first_nonfinite_step"], int)
+    assert 0 <= win["first_nonfinite_step"] < line["attempted"]
+    # a loss that is no number travels as its name: the line stays JSON
+    assert win["loss_last_mean"] in ("inf", "nan")
+    assert ('"first_nonfinite_step": %d,' % win["first_nonfinite_step"]
+            in [ln for ln in err if "] window on " in ln][0])
+    assert err[-1].endswith("correct: False")
+
+
+def test_a_step_that_leaves_the_state_unchanged_is_not_correct(
+        capsys, monkeypatch):
+    """The timed path broken underneath: ``Module.update`` does nothing,
+    so no tensor moves (rule 2 reads e = 1 on every one) and the loss
+    does not fall (rule 3)."""
+    import mxnet_tpu as mx
+    monkeypatch.setattr(mx.mod.Module, "update", lambda self: None)
+    line = run_cell(capsys, "tiny.lm", 0)
+    assert line["correct"] is False
+    ref = line["reference"]
+    assert len(ref["failing"]) == ref["tensors"]
+    assert ref["max_e_over_tol"] == pytest.approx(1.0 / 0.05)
+    win = ref["window"]
+    assert win["first_nonfinite_step"] is None
+    assert not win["loss_last_mean"] < win["loss_first_mean"]
 
 
 def test_float32_step_decides_what_bf16_cannot(capsys):
@@ -153,6 +217,23 @@ def test_forward_rule_is_self_calibrated():
 def test_window_rule(losses, compiles, attempted, completed, ok):
     assert correct.judge_window(losses, compiles, attempted,
                                 completed)[0] is ok
+
+
+FALLING = [10.8 - 0.04 * i for i in range(173)]
+
+
+@pytest.mark.parametrize("losses,ok,where", [
+    (FALLING, True, None),                              # finite and falling
+    # PR 26's faster step, seed 7: inf at step 163 of 173, finite after it
+    (FALLING[:163] + [math.inf] + FALLING[164:], False, 163),
+    (FALLING[:-1] + [math.nan], False, 172),            # a nan last
+    (FALLING[::-1], False, None),                       # finite but rising
+])
+def test_window_rule_names_the_first_nonfinite_step(losses, ok, where):
+    got, details = correct.judge_window(losses, 0, len(losses), len(losses))
+    assert got is ok
+    assert details["first_nonfinite_step"] == where
+    assert details["losses_finite"] is (where is None)
 
 
 # -- driven by data --------------------------------------------------------------

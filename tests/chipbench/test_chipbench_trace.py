@@ -1,7 +1,8 @@
 """chipbench/trace_reduce.py on planes built by hand and on the recorded
 sample kept beside it: busy time is the union of ``XLA Ops``, ``Async XLA
-Ops`` is ignored, idle gaps are named by the chipbench span that covers
-them, kernel time is found by name prefix."""
+Ops`` is ignored, idle gaps are named by the innermost ``mx.*`` span that
+covers them and else by the ``chipbench.*`` one, kernel time is found by
+name prefix."""
 import json
 import os
 import sys
@@ -33,6 +34,9 @@ def planes():
            [FUSION, 50 * ms, 50 * ms]]
     async_ops = [["%copy-start.1 = (f32[3]) copy-start(%p)", 0, 1000 * ms]]
     spans = [["chipbench.step_dispatch", 14 * ms, 7 * ms],
+             ["mx.module.update", 14.5 * ms, 6 * ms],
+             ["mx.module.update.call", 15 * ms, 5 * ms],
+             ["mx.module.forward", 26 * ms, 2.5 * ms],
              ["chipbench.stamp_wait", 27 * ms, 14 * ms],
              ["other.span", 0, 100 * ms]]
     return [
@@ -68,8 +72,20 @@ def test_busy_is_the_union_of_xla_ops_and_async_is_ignored():
         == pytest.approx(0.010)
     assert ops["flash_fwd.* bf16[64,1024,64]"] == pytest.approx(0.008)
     assert all(len(name) < 80 for name in ops)
-    # gaps, longest first, by the chipbench span that covers most of each
+    # gaps, longest first.  [28, 40]: mx.module.forward touches half a
+    # millisecond of it, which is not most of it: the yardstick's span
+    # names it.  [15, 20]: update and update.call both cover it, the
+    # program's spans come first and the innermost names it.  [48, 50]:
+    # nothing of the program's or the yardstick's is open
     assert got["idle_gaps"] == [
+        ["chipbench.stamp_wait", pytest.approx(0.012)],
+        ["mx.module.update.call", pytest.approx(0.005)],
+        [tr.NO_SPAN, pytest.approx(0.002)]]
+    # without the program's spans the yardstick's name the gaps, as before
+    no_program = [dict(p, lines=[dict(ln, events=[
+        e for e in ln["events"] if not e[0].startswith("mx.")])
+        for ln in p["lines"]]) for p in planes()]
+    assert tr.reduce_planes(no_program, steps=1)["idle_gaps"] == [
         ["chipbench.stamp_wait", pytest.approx(0.012)],
         ["chipbench.step_dispatch", pytest.approx(0.005)],
         [tr.NO_SPAN, pytest.approx(0.002)]]
@@ -87,7 +103,18 @@ def test_cut_keeps_events_that_start_inside():
         "flash_fwd.37", "flash_fwd.37", "flash_bwd_dkv.2"]
 
 
+def test_cut_keeps_host_spans_that_overlap():
+    host = tr.cut(planes(), 20e6, 46e6)[1]["lines"][0]["events"]
+    # update.call ended as the window opened; stamp_wait opened before it
+    assert [e[0] for e in host] == [
+        "chipbench.step_dispatch", "mx.module.update", "mx.module.forward",
+        "chipbench.stamp_wait", "other.span"]
+
+
 def test_recorded_sample_reduces():
+    """25 ms around a step boundary of gpt2m.train.resident's traced slice
+    (TPU v5 lite, PR 27's tree): the end of one step's backward, the gaps
+    at the boundary, the next step's dispatch with the program's spans."""
     path = os.path.join(os.path.dirname(tr.__file__), "trace_sample.json")
     with open(path) as f:
         sample = json.load(f)
@@ -98,3 +125,16 @@ def test_recorded_sample_reduces():
     assert got["kernel_s"]["flash_fwd"] > 0.0
     assert len(got["device_ops"]) == 10
     assert all(len(name) < 80 for name, _ in got["device_ops"])
+    spans = [e for p in sample if p["name"] == tr.HOST_PLANE
+             for ln in p["lines"] for e in ln["events"]]
+    names = {e[0] for e in spans}
+    assert {"mx.module.forward", "mx.module.update", "mx.module.update.call",
+            "chipbench.step_dispatch", "chipbench.stamp_wait"} <= names
+    # the boundary's gaps fall while the host waits on chipbench's own loss
+    # scalar: no span of the program is open, the yardstick's names them
+    assert got["idle_gaps"][0][0] == "chipbench.stamp_wait"
+    assert 1e-4 < got["idle_gaps"][0][1] < 1e-3
+    # an interval inside the jitted call is the program's, innermost first
+    _, start, dur = next(e for e in spans if e[0] == "mx.module.update.call")
+    assert tr.gap_name(spans, start + 0.25 * dur, start + 0.75 * dur) \
+        == "mx.module.update.call"
