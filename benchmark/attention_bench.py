@@ -58,9 +58,11 @@ def main():
 
     seqs = [int(s) for s in
             os.environ.get("ATTN_SEQS", "1024,4096,16384").split(",")]
-    # kernel tile sweep, e.g. ATTN_BLOCKS=128x128,128x256
-    blocks = [tuple(int(x) for x in spec.split("x")) for spec in
-              os.environ.get("ATTN_BLOCKS", "128x128").split(",")]
+    # kernel tile sweep, e.g. ATTN_BLOCKS=derived,128x128,512x512; the
+    # default is what the op runs: tiles derived from the shapes
+    blocks = [(None, None) if spec == "derived"
+              else tuple(int(x) for x in spec.split("x")) for spec in
+              os.environ.get("ATTN_BLOCKS", "derived").split(",")]
     B, H, D = 4, 16, 128
     rows = []
     for S in seqs:
@@ -92,12 +94,13 @@ def main():
 
             for bq, bk in blocks:
                 try:
-                    mark("flash S=%d gqa=%s %dx%d" % (S, gqa, bq, bk))
+                    mark("flash S=%d gqa=%s %s" % (S, gqa,
+                                                   _blocks_label(bq, bk)))
                     _bench_flash(rows, dev, S, gqa, bq, bk, B, H, Hk, D,
                                  q, k, v, naive)
                 except Exception as e:  # noqa: BLE001 — keep sweeping
                     print(json.dumps({"S": S, "gqa": gqa,
-                                      "blocks": "%dx%d" % (bq, bk),
+                                      "blocks": _blocks_label(bq, bk),
                                       "error": str(e)[:200]}), flush=True)
     print("\n| S | GQA | blocks | flash fwd ms | naive fwd ms | "
           "flash f+b ms | naive f+b ms | fwd speedup | f+b speedup |")
@@ -113,6 +116,10 @@ def main():
                       "flash_bwd_ms", "naive_bwd_ms")}))
     _write_dispatch_table(rows, dev)
     return 0
+
+
+def _blocks_label(bq, bk):
+    return "derived" if bq is None else "%dx%d" % (bq, bk)
 
 
 def _write_dispatch_table(rows, dev):
@@ -145,11 +152,12 @@ def _write_dispatch_table(rows, dev):
         flash_ms = r.get("flash_bwd_ms") or r.get("flash_fwd_ms") or 1e9
         rank = (tier, sp, -flash_ms)
         if key not in best or rank > best[key][0]:
-            best[key] = (rank, r.get("blocks", "128x128"), sp)
+            best[key] = (rank, r.get("blocks", "derived"), sp)
     # each measured S speaks for its neighborhood: ranges split at the
-    # geometric midpoint between adjacent measured lengths.  The winning
-    # BLOCK CONFIG ships with the row — dispatch must run the config
-    # that won, not the default tiles.
+    # geometric midpoint between adjacent measured lengths.  The row's
+    # `blocks` is a record of what was timed; dispatch reads `winner`
+    # only and the kernels derive their tiles from the shapes, so a
+    # table that decides anything is one swept at ATTN_BLOCKS=derived.
     table_rows = []
     for gqa in (False, True):
         seqs = sorted(s for (s, g) in best if g == gqa)
@@ -186,7 +194,7 @@ def _bench_flash(rows, dev, S, gqa, bq, bk, B, H, Hk, D, q, k, v, naive):
 
     flash_b = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))
 
-    row = {"S": S, "gqa": gqa, "blocks": "%dx%d" % (bq, bk),
+    row = {"S": S, "gqa": gqa, "blocks": _blocks_label(bq, bk),
            "B": B, "H": H, "Hk": Hk, "D": D, "device": dev.device_kind}
     row["flash_fwd_ms"] = round(_time(flash_f, q, k, v), 3)
     row["flash_bwd_ms"] = round(_time(flash_b, q, k, v), 3)

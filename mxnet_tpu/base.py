@@ -523,7 +523,8 @@ declare_env("MXNET_FUSED_DONATE", bool, True,
             "step so XLA updates them in place in HBM")
 declare_env("MXNET_ATTENTION_IMPL", str, "auto",
             "attention kernel dispatch: flash (Pallas), xla (fused "
-            "jnp) or auto (the measured winner table decides)")
+            "jnp) or auto (the measured winner table decides); flash or "
+            "xla only, the kernels' tiles come from the shapes")
 # Deterministic fault injection (mxnet_tpu.faultinject) — the env forms
 # of configure(), for reaching into launcher-spawned worker processes.
 declare_env("MXNET_FI_KILL_POINT", str, "before_send",

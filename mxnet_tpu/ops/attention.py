@@ -17,9 +17,11 @@ Where the kernels run.  A process pinned to the CPU platform
 (``JAX_PLATFORMS=cpu`` — the unit tests) runs them in Pallas interpret
 mode; everywhere else they compile through Mosaic, and a compile error is
 an error (nothing falls back to the XLA reference).  Both trace the SAME
-program — same blocks, same sequence padding, same index maps, traced
-with 64-bit types off — so the CPU tests cover everything but Mosaic
-itself.  The Pallas->Mosaic lowering is covered without a chip by
+program — the same tiles (derived from the shapes by _geometry on every
+backend), the same sequence padding and index maps, traced with 64-bit
+types off — so the CPU tests cover everything but Mosaic itself; they
+force 128-row tiles where they want several tiles at a small S.  The
+Pallas->Mosaic lowering is covered without a chip by
 tests/test_attention.py's cross-lowering tests, and Mosaic's own compile
 and the numbers it produces by chip_smoke.py on the chip (SURVEY.md §4
 device-consistency strategy).
@@ -27,12 +29,15 @@ device-consistency strategy).
 from __future__ import annotations
 
 import functools
+import math
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import tracing
 from .registry import register
 
 _NEG_INF = -1e30
@@ -65,28 +70,128 @@ def _round_up(x, m):
     return -(-x // m) * m
 
 
-def _geometry(q, k, block_q, block_k):
-    """Static tile geometry shared by the forward and backward calls.
-    Blocks are lane multiples (the (block_q, block_k) score tile and the
-    (1, block_q) logsumexp rows must both be lane-dense), except that a
-    sequence shorter than its block is ONE block spanning the whole
-    sublane-padded array, which the block rule also allows; sequences
-    are padded to block multiples.  The head dim is a whole block dim
-    and travels unpadded.  All of it on every backend, so the
-    interpreted CPU tests trace the program the chip compiles."""
+# Preferred rows (q, k) of a score tile, from the ladder on the v5e
+# (B 4, H 16, S 1024, D 64, causal, bf16, device ms a call; PERF.md §6,
+# PR 28).  A tile's time is its serial chain (matmul, reduce, exp, matmul,
+# rescale), which wide tiles amortise:
+#   flash_fwd      128x128 1.247, 512x512 0.366, 1024x1024 0.213
+#   flash_bwd_dq   128x128 1.121, 512x512 0.268, 1024x1024 0.289
+#   flash_bwd_dkv  128x128 0.965, 512x512 0.325, 1024x1024 0.413
+_TILE_FWD = (1024, 1024)
+_TILE_BWD = (512, 512)
+# What a grid step may take of VMEM by _vmem_bytes' count.  Mosaic grants
+# a kernel 16 MiB unless told otherwise, and nothing here asks for more.
+_VMEM_BUDGET = 14 << 20
+
+
+class _Geometry(NamedTuple):
+    """Static sizes of one call, shared by its kernels."""
+    B: int
+    H: int
+    Hk: int
+    G: int
+    Sq: int
+    Sk: int
+    D: int
+    block_q: int
+    block_k: int
+    Sqp: int
+    Skp: int
+    nq: int
+    nk: int
+    vmem_bytes: int   # _vmem_bytes of these tiles
+    derived: bool     # no explicit block was given
+
+
+def _vmem_bytes(block_q, block_k, D, itemsize, forward):
+    """VMEM one grid step takes, by count — of ``flash_fwd``, or of the
+    hungrier of the two backward kernels: every in and out block twice
+    (the pipeline's two buffers), the f32 scratch, and two f32 score
+    tiles for s, p, dp, ds and their masks (compiled for a v5e with
+    Mosaic's limit lowered until it refused, the kernels needed blocks +
+    scratch + at most 1.2 tiles; PERF.md §6, PR 26).  A block's minor dim
+    pads to 128 lanes."""
+    Dp = _round_up(D, _LANES)
+    row = Dp * itemsize                  # one row of q/k/v/o/do
+    tile = block_q * block_k * 4
+    if forward:
+        blocks = (2 * block_q + 2 * block_k) * row \
+            + block_q * _LANES * 4                         # q o, k v, lse
+        scratch = block_q * (2 * _LANES + Dp) * 4          # m l, acc
+        return 2 * blocks + scratch + 2 * tile
+    stats = 2 * _SUBLANES * block_q * 4                    # lse, delta rows
+    dq = (3 * block_q + 2 * block_k) * row + stats         # q do dq, k v
+    dkv = (2 * block_q + 4 * block_k) * row + stats        # q do, k v dk dv
+    scratch = max(block_q, 2 * block_k) * Dp * 4           # dq | dk dv acc
+    return 2 * max(dq, dkv) + scratch + 2 * tile
+
+
+def _tile_rows(S, block, preferred):
+    """Rows of a tile along a sequence of S: a lane multiple (the score
+    tile and the rows of per-query statistics are both lane-dense).  An
+    explicit ``block`` is taken as given; derived, it is the fewest equal
+    tiles of at most ``preferred`` rows (S 1100 at 512 is 3 x 384, not
+    3 x 512: padding stays under one lane tile a block).  Either way a
+    shorter sequence is one tile of its own padded length."""
+    if block is None:
+        block = _round_up(-(-S // -(-S // preferred)), _LANES)
+    elif block % _LANES:
+        raise ValueError(
+            f"flash attention blocks must be multiples of {_LANES}, got "
+            f"{block}")
+    return min(block, _round_up(S, _LANES))
+
+
+def _geometry(q, k, block_q, block_k, forward):
+    """The one place that sizes a call: the tiles from what it can see —
+    the two sequence lengths, the head dim, the operand dtype — for
+    ``flash_fwd`` (``forward``) or for the two backward kernels.  Derived
+    tiles start from _TILE_FWD / _TILE_BWD and the wider side is halved
+    until _vmem_bytes fits _VMEM_BUDGET (float32 operands, a wide head);
+    explicit blocks (tests, benchmark/attention_bench.py) must be lane
+    multiples and must fit as given.  Sequences are padded to whole
+    tiles; the head dim is a whole block dim and travels unpadded.  All
+    of it on every backend, so the interpreted CPU tests trace the
+    program the chip compiles."""
     B, H, Sq, D = q.shape
     Hk, Sk = k.shape[1], k.shape[2]
     if H % Hk:
         raise ValueError(f"q heads {H} not divisible by kv heads {Hk}")
-    if block_q % _LANES or block_k % _LANES:
-        raise ValueError(
-            f"flash attention blocks must be multiples of {_LANES}, got "
-            f"block_q={block_q} block_k={block_k}")
-    block_q = min(block_q, _round_up(Sq, 16))
-    block_k = min(block_k, _round_up(Sk, 16))
-    Sqp, Skp = _round_up(Sq, block_q), _round_up(Sk, block_k)
-    return (B, H, Hk, H // Hk, Sq, Sk, D,
-            block_q, block_k, Sqp, Skp, Sqp // block_q, Skp // block_k)
+    itemsize = jnp.dtype(q.dtype).itemsize
+    derived = block_q is None and block_k is None
+    pref_q, pref_k = _TILE_FWD if forward else _TILE_BWD
+    while True:
+        bq = _tile_rows(Sq, block_q, pref_q)
+        bk = _tile_rows(Sk, block_k, pref_k)
+        vmem = _vmem_bytes(bq, bk, D, itemsize, forward)
+        if vmem <= _VMEM_BUDGET:
+            break
+        if not derived:
+            raise ValueError(
+                f"flash attention blocks {bq} x {bk} (head dim {D}, "
+                f"{itemsize}-byte operands) count {vmem} bytes of VMEM a "
+                f"grid step, over the budget of {_VMEM_BUDGET}")
+        if bq == bk == _LANES:
+            break           # nothing left to narrow: Mosaic has the say
+        if bk >= bq:
+            pref_k = bk // 2
+        else:
+            pref_q = bq // 2
+    Sqp, Skp = _round_up(Sq, bq), _round_up(Sk, bk)
+    return _Geometry(B, H, Hk, H // Hk, Sq, Sk, D, bq, bk, Sqp, Skp,
+                     Sqp // bq, Skp // bk, vmem, derived)
+
+
+def _say_geometry(kernel, geo, causal, grid):
+    """One trace instant a compile (this runs while jit traces the
+    kernel's caller, never per call): what engaged, for whoever reads the
+    kernel's time beside it."""
+    tracing.instant("mx.attention.geometry", "attention", args={
+        "kernel": kernel, "Sq": geo.Sq, "Sk": geo.Sk, "D": geo.D,
+        "G": geo.G, "causal": bool(causal), "block_q": geo.block_q,
+        "block_k": geo.block_k, "grid": list(grid),
+        "grid_steps": math.prod(grid), "vmem_bytes": geo.vmem_bytes,
+        "derived": geo.derived})
 
 
 def _pad_heads(x, Sp):
@@ -142,8 +247,8 @@ def _kv_spec(pl, D, G, block_q, block_k, causal):
                                              "block_k", "interpret",
                                              "return_lse"))
 @_x32
-def _flash_fwd(q, k, v, causal=False, scale=None, block_q=128,
-               block_k=128, interpret=None, return_lse=False):
+def _flash_fwd(q, k, v, causal=False, scale=None, block_q=None,
+               block_k=None, interpret=None, return_lse=False):
     """q: (B, H, Sq, D); k/v: (B, Hk, Sk, D) with Hk dividing H (GQA/MQA:
     each group of H/Hk query heads shares one KV head — the kernel maps
     query-head programs onto the shared KV block, so grouped KV is NEVER
@@ -152,13 +257,17 @@ def _flash_fwd(q, k, v, causal=False, scale=None, block_q=128,
 
     Grid (B*H, q blocks, k blocks): one (block_q, D) query tile meets one
     (block_k, D) K/V tile per program, so VMEM residency is set by the
-    block sizes and not by the sequence length; the online-softmax state
+    block sizes (_geometry derives them from the shapes; block_q/block_k
+    force them) and not by the sequence length; the online-softmax state
     (m, l, acc) lives in VMEM scratch across the k axis."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    geo = _geometry(q, k, block_q, block_k, forward=True)
     (B, H, Hk, G, Sq, Sk, D, block_q, block_k, Sqp, Skp, nq,
-     nk) = _geometry(q, k, block_q, block_k)
+     nk, *_) = geo
+    grid = (B * H, nq, nk)
+    _say_geometry("flash_fwd", geo, causal, grid)
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
@@ -218,7 +327,7 @@ def _flash_fwd(q, k, v, causal=False, scale=None, block_q=128,
             jax.ShapeDtypeStruct((B * H, Sqp, _LANES), jnp.float32))
     res = pl.pallas_call(
         kernel,
-        grid=(B * H, nq, nk),
+        grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -261,17 +370,23 @@ def _attn_reference(q, k, v, causal, scale):
                                              "block_k", "interpret"))
 @_x32
 def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
-               block_q=128, block_k=128, interpret=None):
+               block_q=None, block_k=None, interpret=None):
     """FlashAttention-2 backward: two Pallas kernels (dq; dk+dv), each
     recomputing p = exp(s - lse) blockwise from the saved logsumexp — the
     O(S) memory story of the forward carries to the backward (the
     time-dominant path for long-context training).  Same one-tile-per-
-    program grids as the forward, accumulators in VMEM scratch."""
+    program grids as the forward, accumulators in VMEM scratch; the tiles
+    are the backward's own (_geometry, ``forward=False``), not the
+    forward's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    geo = _geometry(q, k, block_q, block_k, forward=False)
     (B, H, Hk, G, Sq, Sk, D, block_q, block_k, Sqp, Skp, nq,
-     nk) = _geometry(q, k, block_q, block_k)
+     nk, *_) = geo
+    dq_grid, dkv_grid = (B * H, nq, nk), (B * Hk, nk, G * nq)
+    _say_geometry("flash_bwd_dq", geo, causal, dq_grid)
+    _say_geometry("flash_bwd_dkv", geo, causal, dkv_grid)
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
@@ -343,7 +458,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
                             lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(B * H, nq, nk),
+        grid=dq_grid,
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, Sqp, D), q.dtype),
@@ -398,7 +513,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
     dkv_kv_spec = pl.BlockSpec((1, block_k, D), lambda b, j, t: (b, j, 0))
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(B * Hk, nk, G * nq),
+        grid=dkv_grid,
         in_specs=[dkv_q_spec, dkv_kv_spec, dkv_kv_spec, dkv_q_spec,
                   dkv_row_spec, dkv_row_spec],
         out_specs=[dkv_kv_spec, dkv_kv_spec],
@@ -419,15 +534,16 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=False, scale=None,
-                    block_q=128, block_k=128):
+                    block_q=None, block_k=None):
     """Blocked online-softmax attention.  q: (B, H, S, D); k/v:
     (B, Hk, S, D) with Hk dividing H — Hk < H is grouped-query /
     multi-query attention with the shared KV never materialized.
 
-    block_q/block_k tile the kernel's VMEM working set; 128/128 suits
-    v5e's 128x128 MXU, but long-S or small-D configs can profit from
-    256-wide K blocks — benchmark/attention_bench.py sweeps them via
-    ATTN_BLOCKS."""
+    The rows of a score tile are derived from the shapes, by the forward
+    and by the backward each for itself (_geometry).  block_q/block_k
+    force them instead, for both (multiples of 128 that fit the VMEM
+    count, else ValueError): for tests that want several tiles at a small
+    S and for benchmark/attention_bench.py's ATTN_BLOCKS sweep."""
     return _flash_fwd(q, k, v, causal=causal, scale=scale,
                       block_q=block_q, block_k=block_k)
 
@@ -491,37 +607,21 @@ def _load_dispatch_table():
     return _dispatch_cache[1]
 
 
-def pick_attention_config(seq_len, gqa):
-    """(impl, block_q, block_k) for this shape — impl is 'flash'
-    (Pallas kernel) or 'xla' (fused jnp reference), blocks are the tile
-    config that WON the measurement (dispatch must run what was
-    measured, not default tiles).  MXNET_ATTENTION_IMPL=flash|xla|auto
-    overrides impl; in auto the MEASURED winner table decides (VERDICT
-    r3 item 5: an unmeasured Pallas kernel must not be assumed faster —
-    where the chip sweep shows XLA winning, dispatch follows the
-    data)."""
+def pick_attention_impl(seq_len, gqa):
+    """'flash' (Pallas kernels) or 'xla' (fused jnp reference) for this
+    shape.  MXNET_ATTENTION_IMPL=flash|xla|auto overrides; in auto the
+    MEASURED winner table decides (VERDICT r3 item 5: an unmeasured
+    Pallas kernel must not be assumed faster — where the chip sweep shows
+    XLA winning, dispatch follows the data).  The table says nothing
+    about tiles: those come from the shapes (_geometry)."""
     mode = os.environ.get("MXNET_ATTENTION_IMPL", "auto").lower()
-    impl, bq, bk = "flash", 128, 128
+    if mode in ("flash", "xla"):
+        return mode
     for row in _load_dispatch_table():
         if (row.get("min_seq", 0) <= seq_len <= row.get("max_seq", 1 << 62)
                 and bool(row.get("gqa", False)) == bool(gqa)):
-            try:
-                bq, bk = (int(x) for x in
-                          str(row.get("blocks", "128x128")).split("x"))
-            except ValueError:
-                pass
-            impl = row.get("winner", "flash")
-            break
-    # a forced mode overrides the impl choice only — the shape's measured
-    # tile config still applies (dispatch must run what was measured)
-    if mode in ("flash", "xla"):
-        return mode, bq, bk
-    return impl, bq, bk
-
-
-def pick_attention_impl(seq_len, gqa):
-    """Impl only (see pick_attention_config)."""
-    return pick_attention_config(seq_len, gqa)[0]
+            return row.get("winner", "flash")
+    return "flash"
 
 
 @register("_contrib_FlashAttention",
@@ -530,14 +630,13 @@ def pick_attention_impl(seq_len, gqa):
           aliases=("flash_attention", "_contrib_flash_attention"))
 def _flash_attention_op(query, key, value, causal=False, scale=None, **kw):
     """Registry entry point: usable from mx.nd / mx.sym / gluon.
-    Per-shape dispatch: the Pallas flash kernel (at its MEASURED winning
-    tile config) or the fused-XLA reference, per the winner table."""
-    impl, bq, bk = pick_attention_config(
+    Per-shape dispatch: the Pallas flash kernels (their tiles derived
+    from the shapes) or the fused-XLA reference, per the winner table."""
+    impl = pick_attention_impl(
         query.shape[2], key.shape[1] != query.shape[1])
     if impl == "xla":
         return _attn_reference(query, key, value, bool(causal), scale)
-    return flash_attention(query, key, value, bool(causal), scale,
-                           block_q=bq, block_k=bk)
+    return flash_attention(query, key, value, bool(causal), scale)
 
 
 def gqa_repeat_kv(q, k, v):

@@ -1,10 +1,13 @@
 """Flash/ring attention tests (new TPU-native capability, SURVEY.md §5.7).
 
 The Pallas kernels run in interpret mode on the CPU mesh: the same traced
-program the chip compiles (same padding, blocks and index maps), executed
-by the Pallas interpreter instead of Mosaic.  What the interpreter cannot
-show — that Mosaic accepts the program — is covered by the cross-lowering
-tests at the bottom and by chip_smoke.py on the chip."""
+program the chip compiles (same padding, tiles and index maps), executed
+by the Pallas interpreter instead of Mosaic.  The tiles are derived from
+the shapes, so a small S is one tile: the tests that want several tiles,
+block skipping and clamped index maps force 128-row tiles on purpose.
+What the interpreter cannot show — that Mosaic accepts the program — is
+covered by the cross-lowering tests at the bottom and by chip_smoke.py on
+the chip."""
 import os
 import time
 
@@ -160,8 +163,76 @@ def test_flash_backward_multi_block():
     got = A._flash_bwd(q, k, v,
                        *A._flash_fwd(q, k, v, causal=True,
                                      return_lse=True),
-                       g, causal=True)
+                       g, causal=True, block_q=128, block_k=128)
     for x, y in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=2e-3, atol=2e-3)
+
+
+# (Sq, Sk, H, Hk, causal, block_q, block_k): several tiles each way at
+# forced 128-row and rectangular tiles — GQA groups of 2 and 1, Sq != Sk,
+# K padding (Sk 300 in tiles of 128 / 256), causal skipping with
+# block_q != block_k (the clamped K/V and q index maps)
+_FORCED_TILES = [
+    (300, 300, 2, 2, True, 128, 128),
+    (300, 300, 2, 1, False, 128, 128),
+    (384, 384, 2, 1, True, 256, 128),
+    (384, 384, 2, 2, False, 256, 128),
+    (300, 300, 2, 1, True, 128, 256),
+    (384, 384, 4, 2, True, 128, 256),
+    (300, 384, 2, 1, False, 128, 128),
+    (384, 300, 2, 2, False, 256, 128),
+    (200, 300, 2, 1, False, 128, 256),
+    (384, 300, 2, 1, True, 128, 128),
+]
+
+
+def _forced_inputs(Sq, Sk, H, Hk):
+    rng = np.random.RandomState(Sq + Sk + H + Hk)
+    mk = lambda h, s: jnp.asarray(rng.randn(1, h, s, 8).astype('f'))
+    return mk(H, Sq), mk(Hk, Sk), mk(Hk, Sk), mk(H, Sq)
+
+
+def _ref_lse(q, k, causal):
+    g = q.shape[1] // k.shape[1]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, g, axis=1)) \
+        / (q.shape[-1] ** 0.5)
+    if causal:
+        Sq, Sk = s.shape[-2:]
+        s = jnp.where(jnp.arange(Sk)[None, :] <= jnp.arange(Sq)[:, None],
+                      s, -jnp.inf)
+    return jax.scipy.special.logsumexp(s, axis=-1)
+
+
+@pytest.mark.parametrize("Sq,Sk,H,Hk,causal,bq,bk", _FORCED_TILES)
+def test_flash_forward_lse_forced_tiles(Sq, Sk, H, Hk, causal, bq, bk):
+    from mxnet_tpu.ops import attention as A
+    q, k, v, _ = _forced_inputs(Sq, Sk, H, Hk)
+    geo = A._geometry(q, k, bq, bk, forward=True)
+    assert (geo.block_q, geo.block_k, geo.derived) == (bq, bk, False)
+    assert geo.nq > 1 and geo.nk > 1
+    out, lse = A._flash_fwd(q, k, v, causal=causal, return_lse=True,
+                            block_q=bq, block_k=bk)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_attn_reference(q, k, v, causal, None)),
+        rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(lse),
+                               np.asarray(_ref_lse(q, k, causal)),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("Sq,Sk,H,Hk,causal,bq,bk", _FORCED_TILES)
+def test_flash_backward_forced_tiles(Sq, Sk, H, Hk, causal, bq, bk):
+    """dq from ``flash_bwd_dq``, dk/dv from ``flash_bwd_dkv`` (the group
+    reduced in its accumulators), through the public function so the
+    explicit blocks reach the backward too."""
+    q, k, v, g = _forced_inputs(Sq, Sk, H, Hk)
+    ref = jax.vjp(lambda a, b, c: _attn_reference(a, b, c, causal, None),
+                  q, k, v)[1](g)
+    got = jax.vjp(lambda a, b, c: flash_attention(a, b, c, causal, None,
+                                                  bq, bk), q, k, v)[1](g)
+    for x, y in zip(got, ref):
+        assert x.shape == y.shape
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    rtol=2e-3, atol=2e-3)
 
@@ -170,6 +241,147 @@ def test_flash_blocks_must_be_lane_multiples():
     q, k, v = _rand_qkv(S=64)
     with pytest.raises(ValueError, match="multiples of 128"):
         flash_attention(q, k, v, True, None, 32, 32)
+
+
+# --- the sizing function alone (ops/attention.py _geometry) -----------------
+
+def _sized(S, D, dtype, forward, block_q=None, block_k=None, Sk=None):
+    from mxnet_tpu.ops import attention as A
+    q = jax.ShapeDtypeStruct((1, 4, S, D), dtype)
+    k = jax.ShapeDtypeStruct((1, 2, Sk or S, D), dtype)
+    return A._geometry(q, k, block_q, block_k, forward)
+
+
+@pytest.mark.parametrize("S,D,dtype,forward,blocks,tiles", [
+    # the ladder's shape: the two preferred tiles
+    (1024, 64, jnp.bfloat16, True, (1024, 1024), (1, 1)),
+    (1024, 64, jnp.bfloat16, False, (512, 512), (2, 2)),
+    # the fewest EQUAL lane-multiple tiles, not 3 x 512 / 2 x 1024
+    (1100, 64, jnp.bfloat16, False, (384, 384), (3, 3)),
+    (1100, 64, jnp.bfloat16, True, (640, 640), (2, 2)),
+    # a short sequence is one tile of its own padded length
+    (100, 64, jnp.bfloat16, True, (128, 128), (1, 1)),
+    (100, 64, jnp.bfloat16, False, (128, 128), (1, 1)),
+    (577, 64, jnp.bfloat16, True, (640, 640), (1, 1)),
+    (577, 64, jnp.bfloat16, False, (384, 384), (2, 2)),
+    # long sequences keep the preferred tiles
+    (4096, 64, jnp.bfloat16, True, (1024, 1024), (4, 4)),
+    (8192, 128, jnp.bfloat16, False, (512, 512), (16, 16)),
+    # float32, head 128: the forward's 1024 x 1024 counts 14.5 MiB, so
+    # its wider side (k on a tie) is halved; the backward's tile fits
+    (4096, 128, jnp.float32, True, (1024, 512), (4, 8)),
+    (4096, 128, jnp.float32, False, (512, 512), (8, 8)),
+])
+def test_geometry_derives_tiles_from_the_shape(S, D, dtype, forward, blocks,
+                                               tiles):
+    from mxnet_tpu.ops import attention as A
+    geo = _sized(S, D, dtype, forward)
+    assert (geo.block_q, geo.block_k) == blocks
+    assert (geo.nq, geo.nk) == tiles
+    assert (geo.Sqp, geo.Skp) == (blocks[0] * tiles[0], blocks[1] * tiles[1])
+    assert geo.Sqp - S < A._LANES * geo.nq     # under a lane tile a block
+    assert geo.derived and geo.G == 2
+    assert geo.vmem_bytes == A._vmem_bytes(*blocks, D, jnp.dtype(dtype)
+                                           .itemsize, forward)
+    assert geo.vmem_bytes <= A._VMEM_BUDGET
+
+
+def test_geometry_sides_are_sized_apart():
+    """Sq != Sk: each side from its own length."""
+    geo = _sized(200, 64, jnp.bfloat16, True, Sk=3000)
+    assert (geo.block_q, geo.block_k, geo.nq, geo.nk) == (256, 1024, 1, 3)
+    geo = _sized(200, 64, jnp.bfloat16, False, Sk=3000)
+    assert (geo.block_q, geo.block_k, geo.nq, geo.nk) == (256, 512, 1, 6)
+
+
+@pytest.mark.parametrize("forward", [True, False])
+def test_geometry_explicit_blocks_are_taken_as_given(forward):
+    geo = _sized(1024, 64, jnp.bfloat16, forward, 256, 128)
+    assert (geo.block_q, geo.block_k, geo.nq, geo.nk) == (256, 128, 4, 8)
+    assert not geo.derived
+    # one side explicit: the other is derived, and nothing is narrowed
+    geo = _sized(1024, 64, jnp.bfloat16, forward, None, 128)
+    assert geo.block_q == (1024 if forward else 512) and geo.block_k == 128
+    assert not geo.derived
+    # larger than the sequence: one tile of the padded length
+    geo = _sized(300, 64, jnp.bfloat16, forward, 512, 512)
+    assert (geo.block_q, geo.block_k, geo.Sqp) == (384, 384, 384)
+
+
+@pytest.mark.parametrize("forward,dtype,D,blocks", [
+    (True, jnp.bfloat16, 64, (1024, 2048)),    # PR 26's ladder: no result
+    (False, jnp.bfloat16, 64, (1024, 2048)),
+    (True, jnp.float32, 128, (1024, 1024)),
+    (False, jnp.float32, 256, (2048, 1024)),
+])
+def test_geometry_refuses_an_explicit_tile_over_the_vmem_count(
+        forward, dtype, D, blocks):
+    from mxnet_tpu.ops import attention as A
+    with pytest.raises(ValueError, match="bytes of VMEM") as e:
+        _sized(4096, D, dtype, forward, *blocks)
+    count = A._vmem_bytes(*blocks, D, jnp.dtype(dtype).itemsize, forward)
+    assert count > A._VMEM_BUDGET
+    assert str(count) in str(e.value) and str(A._VMEM_BUDGET) in str(e.value)
+
+
+def test_explicit_tile_over_the_count_raises_from_the_public_call():
+    q = jnp.zeros((1, 1, 4096, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="bytes of VMEM"):
+        flash_attention(q, q, q, True, None, 1024, 2048)
+
+
+def test_geometry_instant_once_a_compile_never_per_call(monkeypatch):
+    """Each kernel says what engaged in one ``mx.attention.geometry``
+    instant while its caller is traced: the forward and the backward their
+    own tiles, nothing on a call that hits jit's cache, nothing when
+    tracing is off."""
+    from mxnet_tpu import tracing
+
+    def said():
+        return [r["args"] for r in tracing.ring_records()
+                if r["name"] == "mx.attention.geometry"]
+
+    # a shape no other test of this file uses: jit's cache is the process's
+    q = jnp.ones((1, 4, 200, 24), jnp.float32)
+    k = v = jnp.ones((1, 2, 136, 24), jnp.float32)
+    monkeypatch.setenv("MXNET_TRACE", "1")
+    tracing.reconfigure()
+    try:
+        tracing.reset()
+        flash_attention(q, k, v, False, None)
+        (fwd,) = said()
+        assert fwd == {
+            "kernel": "flash_fwd", "Sq": 200, "Sk": 136, "D": 24, "G": 2,
+            "causal": False, "block_q": 256, "block_k": 256,
+            "grid": [4, 1, 1], "grid_steps": 4, "derived": True,
+            "vmem_bytes": fwd["vmem_bytes"]}
+        assert 0 < fwd["vmem_bytes"] < 14 << 20
+        for _ in range(3):
+            flash_attention(q, k, v, False, None)
+        assert len(said()) == 1
+        # the training path: the forward that keeps lse is another
+        # program, and the backward's two kernels speak for themselves
+        f = jax.jit(jax.grad(
+            lambda a, b, c: jnp.sum(flash_attention(a, b, c, False, None,
+                                                    128, 128)),
+            argnums=(0, 1, 2)))
+        f(q, k, v)
+        got = said()[1:]
+        assert [a["kernel"] for a in got] \
+            == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+        assert all((a["block_q"], a["block_k"], a["derived"])
+                   == (128, 128, False) for a in got)
+        assert [a["grid"] for a in got] == [[4, 2, 2], [4, 2, 2], [2, 2, 4]]
+        for _ in range(3):
+            f(q, k, v)
+        assert len(said()) == 4
+    finally:
+        monkeypatch.delenv("MXNET_TRACE")
+        tracing.reconfigure()
+        tracing.reset()
+    # off: a fresh compile says nothing
+    flash_attention(q, k, v, True, None)
+    assert said() == []
 
 
 # --- Ulysses all-to-all sequence parallelism (parallel/ulysses.py) ---------
@@ -380,10 +592,19 @@ def test_attention_impl_dispatch(monkeypatch, tmp_path):
     path.write_text(json.dumps(table))
     os.utime(path, (time.time() + 5, time.time() + 5))
     monkeypatch.setattr(att, "_dispatch_stat_t", 0.0)
-    assert att.pick_attention_config(256, False) == ("flash", 256, 128)
-    # a forced impl still runs the shape's MEASURED tile config
+    assert att.pick_attention_impl(256, False) == "flash"
+    # a `blocks` column in the table changes nothing: the tiles come from
+    # the shapes, and the op passes none
+    seen = []
+    monkeypatch.setattr(
+        att, "flash_attention",
+        lambda *a, **kw: seen.append((a[3:], kw)) or out_flash)
+    mx.nd.flash_attention(mx.nd.NDArray(q), mx.nd.NDArray(k),
+                          mx.nd.NDArray(v), causal=True)
+    assert seen == [((True, None), {})]
+    assert not hasattr(att, "pick_attention_config")
     monkeypatch.setenv("MXNET_ATTENTION_IMPL", "xla")
-    assert att.pick_attention_config(256, False) == ("xla", 256, 128)
+    assert att.pick_attention_impl(256, False) == "xla"
     monkeypatch.setenv("MXNET_ATTENTION_IMPL", "auto")
     monkeypatch.setattr(att, "_dispatch_cache", None)
 
@@ -403,10 +624,13 @@ def _tpu_lowered(fn, *avals):
 
 @pytest.mark.parametrize("kernel", ["fwd", "fwd_lse", "bwd"])
 @pytest.mark.parametrize("D,Hk", [(64, 4), (64, 1), (128, 4), (128, 2)])
-def test_flash_kernels_cross_lower_for_tpu(kernel, D, Hk):
+@pytest.mark.parametrize("S", [512, 1024, 4096])
+def test_flash_kernels_cross_lower_for_tpu(kernel, D, Hk, S):
+    """S 512 is one derived tile; S 1024 the forward's 1024-row tile and
+    2 x 2 of the backward's 512; S 4096 several of both."""
     from mxnet_tpu.ops import attention as A
     assert jax.config.jax_enable_x64       # the package's setting, not ours
-    B, H, S = 2, 4, 512
+    B, H = 2, 4
     q = jax.ShapeDtypeStruct((B, H, S, D), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((B, Hk, S, D), jnp.bfloat16)
     lse = jax.ShapeDtypeStruct((B, H, S), jnp.float32)

@@ -1095,6 +1095,7 @@ _COVERED_ELSEWHERE = {
     'Custom': 'tests/test_aux.py',
     '_contrib_MoE': 'tests/test_moe_pipeline.py',
     'moe_ffn': 'tests/test_moe_pipeline.py',
+    '_contrib_ChunkedLMLoss': 'tests/test_chunked_loss.py',
     'Embedding': 'tests/test_gluon.py',
     'Dropout': 'tests/test_autograd.py',
     'SequenceMask': 'tests/test_rnn.py',
