@@ -7,11 +7,13 @@ blocked attention whose QK^T and PV matmuls tile onto the MXU and whose
 working set stays in VMEM — O(S) memory instead of the O(S²) a naive
 softmax(QK^T)V materializes.
 
-The backward pass is a dual Pallas kernel in the FA2 style (_flash_bwd
-below): one kernel for dQ, one for dK/dV, both recomputing the attention
-probabilities blockwise from the forward's saved logsumexp — O(S) memory
-end-to-end, with GQA/MQA handled at the block-spec level so repeated KV
-heads are never materialized.
+The backward pass (_flash_bwd below) is one Pallas kernel in the FA2
+style that recomputes the attention probabilities blockwise from the
+forward's saved logsumexp and feeds dK, dV and dQ from the same tile —
+O(S) memory end-to-end, with GQA/MQA handled at the block-spec level so
+repeated KV heads are never materialized.  Where its whole-sequence dQ
+accumulator does not fit VMEM (_geometry decides from the shapes), dQ
+gets a kernel of its own.
 
 Where the kernels run.  A process pinned to the CPU platform
 (``JAX_PLATFORMS=cpu`` — the unit tests) runs them in Pallas interpret
@@ -70,13 +72,21 @@ def _round_up(x, m):
     return -(-x // m) * m
 
 
-# Preferred rows (q, k) of a score tile, from the ladder on the v5e
+# Preferred rows (q, k) of a score tile, from the ladders on the v5e
 # (B 4, H 16, S 1024, D 64, causal, bf16, device ms a call; PERF.md §6,
-# PR 28).  A tile's time is its serial chain (matmul, reduce, exp, matmul,
-# rescale), which wide tiles amortise:
-#   flash_fwd      128x128 1.247, 512x512 0.366, 1024x1024 0.213
-#   flash_bwd_dq   128x128 1.121, 512x512 0.268, 1024x1024 0.289
-#   flash_bwd_dkv  128x128 0.965, 512x512 0.325, 1024x1024 0.413
+# PR 28 and PR 31).  A tile's time is its serial chain (matmul, reduce,
+# exp, matmul, rescale), which wide tiles amortise; past 512 rows the
+# backward's causal tile pairs stop being skipped and it loses again:
+#   flash_fwd         128x128 1.247, 512x512 0.366, 1024x1024 0.213
+#   flash_bwd_dkv_dq  128x128 1.482, 256x256 0.579, 256x512 0.467,
+#                     512x256 0.468, 512x512 0.418, 512x1024 0.503,
+#                     1024x512 0.505, 1024x1024 0.510
+#   flash_bwd_dq + flash_bwd_dkv (where dQ's accumulator does not fit)
+#                     128x128 1.121 + 0.965, 512x512 0.268 + 0.325,
+#                     1024x1024 0.289 + 0.413
+# 512x512 is also the merged kernel's best at S 4096 (1.215; 1024x512
+# 1.251, 512x1024 1.241) and at D 128 with 4 query heads a KV head (0.103;
+# 0.125, 0.123); not causal, 512x1024 reads 0.491 for 0.526.
 _TILE_FWD = (1024, 1024)
 _TILE_BWD = (512, 512)
 # What a grid step may take of VMEM by _vmem_bytes' count.  Mosaic grants
@@ -101,16 +111,20 @@ class _Geometry(NamedTuple):
     nk: int
     vmem_bytes: int   # _vmem_bytes of these tiles
     derived: bool     # no explicit block was given
+    merged: bool      # backward: one kernel, dQ accumulated in VMEM
 
 
-def _vmem_bytes(block_q, block_k, D, itemsize, forward):
+def _vmem_bytes(block_q, block_k, D, itemsize, forward, dq_rows=0):
     """VMEM one grid step takes, by count — of ``flash_fwd``, or of the
-    hungrier of the two backward kernels: every in and out block twice
-    (the pipeline's two buffers), the f32 scratch, and two f32 score
-    tiles for s, p, dp, ds and their masks (compiled for a v5e with
-    Mosaic's limit lowered until it refused, the kernels needed blocks +
-    scratch + at most 1.2 tiles; PERF.md §6, PR 26).  A block's minor dim
-    pads to 128 lanes."""
+    backward: every in and out block twice (the pipeline's two buffers),
+    the f32 scratch, and two f32 score tiles for s, p, dp, ds and their
+    masks (compiled for a v5e with Mosaic's limit lowered until it
+    refused, the kernels needed blocks + scratch + at most 1.2 tiles;
+    PERF.md §6, PR 26).  ``dq_rows`` > 0 counts the merged backward
+    kernel, which keeps dQ of one KV head's whole group (G * Sqp rows)
+    resident as an out block and an f32 accumulator; 0 the hungrier of
+    the two kernels it falls back to.  A block's minor dim pads to 128
+    lanes."""
     Dp = _round_up(D, _LANES)
     row = Dp * itemsize                  # one row of q/k/v/o/do
     tile = block_q * block_k * 4
@@ -120,8 +134,12 @@ def _vmem_bytes(block_q, block_k, D, itemsize, forward):
         scratch = block_q * (2 * _LANES + Dp) * 4          # m l, acc
         return 2 * blocks + scratch + 2 * tile
     stats = 2 * _SUBLANES * block_q * 4                    # lse, delta rows
-    dq = (3 * block_q + 2 * block_k) * row + stats         # q do dq, k v
     dkv = (2 * block_q + 4 * block_k) * row + stats        # q do, k v dk dv
+    if dq_rows:
+        blocks = dkv + dq_rows * row                       # ... and all dq
+        scratch = (2 * block_k + dq_rows) * Dp * 4         # dk dv, dq acc
+        return 2 * blocks + scratch + 2 * tile
+    dq = (3 * block_q + 2 * block_k) * row + stats         # q do dq, k v
     scratch = max(block_q, 2 * block_k) * Dp * 4           # dq | dk dv acc
     return 2 * max(dq, dkv) + scratch + 2 * tile
 
@@ -144,26 +162,37 @@ def _tile_rows(S, block, preferred):
 
 def _geometry(q, k, block_q, block_k, forward):
     """The one place that sizes a call: the tiles from what it can see —
-    the two sequence lengths, the head dim, the operand dtype — for
-    ``flash_fwd`` (``forward``) or for the two backward kernels.  Derived
-    tiles start from _TILE_FWD / _TILE_BWD and the wider side is halved
-    until _vmem_bytes fits _VMEM_BUDGET (float32 operands, a wide head);
-    explicit blocks (tests, benchmark/attention_bench.py) must be lane
-    multiples and must fit as given.  Sequences are padded to whole
-    tiles; the head dim is a whole block dim and travels unpadded.  All
-    of it on every backend, so the interpreted CPU tests trace the
-    program the chip compiles."""
+    the two sequence lengths, the head dim, the operand dtype, the query
+    heads a KV head — for ``flash_fwd`` (``forward``) or for the backward.
+    Derived tiles start from _TILE_FWD / _TILE_BWD and the wider side is
+    halved until _vmem_bytes fits _VMEM_BUDGET (float32 operands, a wide
+    head); explicit blocks (tests, benchmark/attention_bench.py) must be
+    lane multiples and must fit as given.  The backward is the merged
+    kernel where its count fits, dQ's whole-sequence accumulator
+    included; where it does not (S 8192, D 128, four query heads a KV
+    head: 33 MB), the same tiles are counted for the dQ and dK/dV
+    kernels apart.  Sequences are padded to whole tiles; the head dim is
+    a whole block dim and travels unpadded.  All of it on every backend,
+    so the interpreted CPU tests trace the program the chip compiles."""
     B, H, Sq, D = q.shape
     Hk, Sk = k.shape[1], k.shape[2]
     if H % Hk:
         raise ValueError(f"q heads {H} not divisible by kv heads {Hk}")
+    G = H // Hk
     itemsize = jnp.dtype(q.dtype).itemsize
     derived = block_q is None and block_k is None
     pref_q, pref_k = _TILE_FWD if forward else _TILE_BWD
     while True:
         bq = _tile_rows(Sq, block_q, pref_q)
         bk = _tile_rows(Sk, block_k, pref_k)
+        Sqp = _round_up(Sq, bq)
         vmem = _vmem_bytes(bq, bk, D, itemsize, forward)
+        merged = False
+        if not forward:
+            with_dq = _vmem_bytes(bq, bk, D, itemsize, forward, G * Sqp)
+            merged = with_dq <= _VMEM_BUDGET
+            if merged:
+                vmem = with_dq
         if vmem <= _VMEM_BUDGET:
             break
         if not derived:
@@ -177,12 +206,12 @@ def _geometry(q, k, block_q, block_k, forward):
             pref_k = bk // 2
         else:
             pref_q = bq // 2
-    Sqp, Skp = _round_up(Sq, bq), _round_up(Sk, bk)
-    return _Geometry(B, H, Hk, H // Hk, Sq, Sk, D, bq, bk, Sqp, Skp,
-                     Sqp // bq, Skp // bk, vmem, derived)
+    Skp = _round_up(Sk, bk)
+    return _Geometry(B, H, Hk, G, Sq, Sk, D, bq, bk, Sqp, Skp,
+                     Sqp // bq, Skp // bk, vmem, derived, merged)
 
 
-def _say_geometry(kernel, geo, causal, grid):
+def _say_geometry(kernel, geo, causal, grid, **more):
     """One trace instant a compile (this runs while jit traces the
     kernel's caller, never per call): what engaged, for whoever reads the
     kernel's time beside it."""
@@ -191,7 +220,7 @@ def _say_geometry(kernel, geo, causal, grid):
         "G": geo.G, "causal": bool(causal), "block_q": geo.block_q,
         "block_k": geo.block_k, "grid": list(grid),
         "grid_steps": math.prod(grid), "vmem_bytes": geo.vmem_bytes,
-        "derived": geo.derived})
+        "derived": geo.derived, **more})
 
 
 def _pad_heads(x, Sp):
@@ -201,12 +230,13 @@ def _pad_heads(x, Sp):
         .reshape(B * h, Sp, D)
 
 
-def _compiler_params():
+def _compiler_params(second="parallel"):
     from jax.experimental.pallas import tpu as pltpu
     # the last grid axis walks the reduction (scratch accumulators carry
-    # across it); the first two are independent programs
+    # across it); the first is independent programs, and so is the second
+    # unless the caller carries state across it too
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", second, "arbitrary"))
 
 
 def _tile_mask(q_lo, k_lo, shape, Sk, Skp, causal, q_axis):
@@ -371,22 +401,31 @@ def _attn_reference(q, k, v, causal, scale):
 @_x32
 def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
                block_q=None, block_k=None, interpret=None):
-    """FlashAttention-2 backward: two Pallas kernels (dq; dk+dv), each
-    recomputing p = exp(s - lse) blockwise from the saved logsumexp — the
-    O(S) memory story of the forward carries to the backward (the
-    time-dominant path for long-context training).  Same one-tile-per-
-    program grids as the forward, accumulators in VMEM scratch; the tiles
-    are the backward's own (_geometry, ``forward=False``), not the
-    forward's."""
+    """FlashAttention-2 backward, recomputing p = exp(s - lse) blockwise
+    from the saved logsumexp — the O(S) memory story of the forward
+    carries to the backward (the time-dominant path for long-context
+    training).  One kernel, ``flash_bwd_dkv_dq``: a program owns one K/V
+    tile, walks the q tiles of its KV head's group, and from each score
+    tile feeds all three accumulators — dK and dV its own, dQ a slot of a
+    whole-sequence accumulator that stays in VMEM across the k tiles.
+    Where that accumulator is over the VMEM count (_geometry), dQ comes
+    from ``flash_bwd_dq`` and dK/dV from ``flash_bwd_dkv``: the same
+    tiles, the same ``p_and_ds_t``, each rebuilding it.  One tile a
+    program as in the forward; the tiles are the backward's own
+    (_geometry, ``forward=False``), not the forward's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     geo = _geometry(q, k, block_q, block_k, forward=False)
     (B, H, Hk, G, Sq, Sk, D, block_q, block_k, Sqp, Skp, nq,
      nk, *_) = geo
+    merged = geo.merged
     dq_grid, dkv_grid = (B * H, nq, nk), (B * Hk, nk, G * nq)
-    _say_geometry("flash_bwd_dq", geo, causal, dq_grid)
-    _say_geometry("flash_bwd_dkv", geo, causal, dkv_grid)
+    if merged:
+        _say_geometry("flash_bwd_dkv_dq", geo, causal, dkv_grid, merged=True)
+    else:
+        _say_geometry("flash_bwd_dq", geo, causal, dq_grid, merged=False)
+        _say_geometry("flash_bwd_dkv", geo, causal, dkv_grid, merged=False)
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
@@ -429,50 +468,64 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
                                preferred_element_type=f32)
         return p_t, p_t * (dp_t - dlt_ref[0, :1, :]) * scale
 
-    def dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref,
-                  acc_ref):
-        i, j = pl.program_id(1), pl.program_id(2)
+    def dq_of(ds_t, k_ref):
+        return lax.dot_general(
+            ds_t.astype(k_ref.dtype), k_ref[0],
+            (((0,), (0,)), ((), ())), preferred_element_type=f32)
 
-        @pl.when(j == 0)
-        def _():
-            acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+    def dq_alone():
+        """dQ from a kernel of its own, on the forward's grid: program
+        (b, i) owns one q tile of head b and walks the k tiles."""
+        def dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, dq_ref,
+                      acc_ref):
+            i, j = pl.program_id(1), pl.program_id(2)
 
-        def step():
-            _, ds_t = p_and_ds_t(q_ref, k_ref, v_ref, g_ref, lse_ref,
-                                 dlt_ref, i, j)
-            acc_ref[...] += lax.dot_general(
-                ds_t.astype(k_ref.dtype), k_ref[0],
-                (((0,), (0,)), ((), ())), preferred_element_type=f32)
+            @pl.when(j == 0)
+            def _():
+                acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
 
-        if causal:
-            pl.when(j <= last_k(i))(step)
-        else:
-            step()
+            def step():
+                _, ds_t = p_and_ds_t(q_ref, k_ref, v_ref, g_ref, lse_ref,
+                                     dlt_ref, i, j)
+                acc_ref[...] += dq_of(ds_t, k_ref)
 
-        @pl.when(j == nk - 1)
-        def _():
-            dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+            if causal:
+                pl.when(j <= last_k(i))(step)
+            else:
+                step()
 
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
-    row_spec = pl.BlockSpec((1, _SUBLANES, block_q),
-                            lambda b, i, j: (b, 0, i))
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=dq_grid,
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B * H, Sqp, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), f32)],
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-        name="flash_bwd_dq",
-    )(qr, kr, vr, gr, lser, deltar)
+            @pl.when(j == nk - 1)
+            def _():
+                dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+
+        q_spec = pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0))
+        row_spec = pl.BlockSpec((1, _SUBLANES, block_q),
+                                lambda b, i, j: (b, 0, i))
+        return pl.pallas_call(
+            dq_kernel,
+            grid=dq_grid,
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((B * H, Sqp, D), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, D), f32)],
+            compiler_params=_compiler_params(),
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(qr, kr, vr, gr, lser, deltar)
 
     # dk/dv: program (b, j) owns one K/V tile of KV head b and walks the
     # G query heads of its group times the q blocks on the last axis, so
-    # the GQA reduction over the group happens in the accumulators
+    # the GQA reduction over the group happens in the accumulators.
+    # Merged, step t of that walk also adds its dQ into slot t of an
+    # accumulator that outlives the k tiles (the second axis), and the
+    # last k tile casts each slot into the resident dq block, which goes
+    # to HBM once a KV head.
     def dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref,
-                   dk_ref, dv_ref, dk_acc, dv_acc):
+                   dk_ref, dv_ref, *rest):
+        if merged:
+            dq_ref, dk_acc, dv_acc, dq_acc = rest
+        else:
+            dk_acc, dv_acc = rest
         j, t = pl.program_id(1), pl.program_id(2)
         i = t % nq
 
@@ -480,6 +533,11 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
         def _():
             dk_acc[...] = jnp.zeros(dk_acc.shape, f32)
             dv_acc[...] = jnp.zeros(dv_acc.shape, f32)
+
+        if merged:
+            @pl.when(j == 0)
+            def _():
+                dq_acc[t] = jnp.zeros(dq_acc.shape[1:], f32)
 
         def step():
             p_t, ds_t = p_and_ds_t(q_ref, k_ref, v_ref, g_ref, lse_ref,
@@ -490,6 +548,8 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
             dk_acc[...] += lax.dot_general(
                 ds_t.astype(q_ref.dtype), q_ref[0],
                 (((1,), (0,)), ((), ())), preferred_element_type=f32)
+            if merged:
+                dq_acc[t] += dq_of(ds_t, k_ref)
 
         if causal:
             pl.when(i >= first_q(j))(step)
@@ -501,6 +561,11 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
             dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
+        if merged:
+            @pl.when(j == nk - 1)
+            def _():
+                dq_ref[0, t] = dq_acc[t].astype(dq_ref.dtype)
+
     def q_block(j, t):
         i = t % nq
         return jnp.maximum(i, first_q(j)) if causal else i
@@ -511,20 +576,33 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
         (1, _SUBLANES, block_q),
         lambda b, j, t: (b * G + t // nq, 0, q_block(j, t)))
     dkv_kv_spec = pl.BlockSpec((1, block_k, D), lambda b, j, t: (b, j, 0))
-    dk, dv = pl.pallas_call(
+    out_specs = [dkv_kv_spec, dkv_kv_spec]
+    out_shape = [jax.ShapeDtypeStruct((B * Hk, Skp, D), k.dtype),
+                 jax.ShapeDtypeStruct((B * Hk, Skp, D), v.dtype)]
+    scratch_shapes = [pltpu.VMEM((block_k, D), f32),
+                      pltpu.VMEM((block_k, D), f32)]
+    if merged:
+        # dq of KV head b's group, tile by tile in the walk's order: the
+        # (B*H, Sqp, D) array seen as (B*Hk, G*nq, block_q, D)
+        out_specs.append(pl.BlockSpec((1, G * nq, block_q, D),
+                                      lambda b, j, t: (b, 0, 0, 0)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((B * Hk, G * nq, block_q, D), q.dtype))
+        scratch_shapes.append(pltpu.VMEM((G * nq, block_q, D), f32))
+    dk, dv, *dq = pl.pallas_call(
         dkv_kernel,
         grid=dkv_grid,
         in_specs=[dkv_q_spec, dkv_kv_spec, dkv_kv_spec, dkv_q_spec,
                   dkv_row_spec, dkv_row_spec],
-        out_specs=[dkv_kv_spec, dkv_kv_spec],
-        out_shape=[jax.ShapeDtypeStruct((B * Hk, Skp, D), k.dtype),
-                   jax.ShapeDtypeStruct((B * Hk, Skp, D), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, D), f32),
-                        pltpu.VMEM((block_k, D), f32)],
-        compiler_params=_compiler_params(),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch_shapes,
+        compiler_params=_compiler_params(
+            "arbitrary" if merged else "parallel"),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv_dq" if merged else "flash_bwd_dkv",
     )(qr, kr, vr, gr, lser, deltar)
+    dq = dq[0] if merged else dq_alone()
 
     dq = dq.reshape(B, H, Sqp, D)[:, :, :Sq]
     dk = dk.reshape(B, Hk, Skp, D)[:, :, :Sk]

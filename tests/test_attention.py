@@ -223,9 +223,9 @@ def test_flash_forward_lse_forced_tiles(Sq, Sk, H, Hk, causal, bq, bk):
 
 @pytest.mark.parametrize("Sq,Sk,H,Hk,causal,bq,bk", _FORCED_TILES)
 def test_flash_backward_forced_tiles(Sq, Sk, H, Hk, causal, bq, bk):
-    """dq from ``flash_bwd_dq``, dk/dv from ``flash_bwd_dkv`` (the group
-    reduced in its accumulators), through the public function so the
-    explicit blocks reach the backward too."""
+    """dq, dk, dv from ``flash_bwd_dkv_dq`` (the group reduced in its
+    dk/dv accumulators, dq carried across the k tiles), through the
+    public function so the explicit blocks reach the backward too."""
     q, k, v, g = _forced_inputs(Sq, Sk, H, Hk)
     ref = jax.vjp(lambda a, b, c: _attn_reference(a, b, c, causal, None),
                   q, k, v)[1](g)
@@ -235,6 +235,62 @@ def test_flash_backward_forced_tiles(Sq, Sk, H, Hk, causal, bq, bk):
         assert x.shape == y.shape
         np.testing.assert_allclose(np.asarray(x), np.asarray(y),
                                    rtol=2e-3, atol=2e-3)
+
+
+def _bwd_uncached(monkeypatch, merged, q, k, v, g, causal, bq, bk):
+    """``_flash_bwd`` outside jit's cache, as the merged kernel or (the
+    budget lowered to just under the merged count, which is what a long
+    sequence does) as the dQ and dK/dV kernels apart."""
+    from mxnet_tpu.ops import attention as A
+    out, lse = A._flash_fwd(q, k, v, causal=causal, return_lse=True,
+                            block_q=bq, block_k=bk)
+    geo = A._geometry(q, k, bq, bk, forward=False)
+    assert geo.merged
+    if not merged:
+        monkeypatch.setattr(A, "_VMEM_BUDGET", geo.vmem_bytes - 1)
+        two = A._geometry(q, k, bq, bk, forward=False)
+        assert not two.merged and two.vmem_bytes < geo.vmem_bytes
+        assert (two.block_q, two.block_k) == (geo.block_q, geo.block_k)
+    return A._flash_bwd.__wrapped__(q, k, v, out, lse, g, causal=causal,
+                                    block_q=bq, block_k=bk)
+
+
+@pytest.mark.parametrize("Sq,Sk,H,Hk,causal,bq,bk", _FORCED_TILES + [
+    (100, 100, 4, 2, True, None, None),      # one derived tile
+    (1100, 600, 2, 1, False, None, None),    # 3 x 384 by 2 x 384 derived
+])
+def test_flash_backward_merged_equals_two_kernels(monkeypatch, Sq, Sk, H,
+                                                  Hk, causal, bq, bk):
+    """One pass over the score tiles gives what two gave: the same p and
+    ds feed the same sums in the same order, so dk and dv are the dK/dV
+    kernel's bit for bit and dq the dQ kernel's to rounding."""
+    q, k, v, g = _forced_inputs(Sq, Sk, H, Hk)
+    one = _bwd_uncached(monkeypatch, True, q, k, v, g, causal, bq, bk)
+    two = _bwd_uncached(monkeypatch, False, q, k, v, g, causal, bq, bk)
+    ref = jax.vjp(lambda a, b, c: _attn_reference(a, b, c, causal, None),
+                  q, k, v)[1](g)
+    for x, y, r in zip(one, two, ref):
+        assert x.shape == y.shape == r.shape
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(x), np.asarray(r),
+                                   rtol=2e-3, atol=2e-3)
+    np.testing.assert_array_equal(np.asarray(one[1]), np.asarray(two[1]))
+    np.testing.assert_array_equal(np.asarray(one[2]), np.asarray(two[2]))
+
+
+def test_flash_backward_merged_equals_two_kernels_bf16(monkeypatch):
+    """bf16 operands, head 64, a group of 2, 3 x 3 causal tiles: each
+    kernel rounds ds to bf16 once, before the same matmuls."""
+    rng = np.random.RandomState(5)
+    mk = lambda h: jnp.asarray(rng.randn(1, h, 384, 64), jnp.bfloat16)
+    q, k, v, g = mk(4), mk(2), mk(2), mk(4)
+    one = _bwd_uncached(monkeypatch, True, q, k, v, g, True, 128, 128)
+    two = _bwd_uncached(monkeypatch, False, q, k, v, g, True, 128, 128)
+    for x, y in zip(one, two):
+        assert x.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                      np.asarray(y, np.float32))
 
 
 def test_flash_blocks_must_be_lane_multiples():
@@ -281,9 +337,50 @@ def test_geometry_derives_tiles_from_the_shape(S, D, dtype, forward, blocks,
     assert (geo.Sqp, geo.Skp) == (blocks[0] * tiles[0], blocks[1] * tiles[1])
     assert geo.Sqp - S < A._LANES * geo.nq     # under a lane tile a block
     assert geo.derived and geo.G == 2
-    assert geo.vmem_bytes == A._vmem_bytes(*blocks, D, jnp.dtype(dtype)
-                                           .itemsize, forward)
+    # a merged backward counts its resident dQ (here G 2: all but the two
+    # whose dQ rows are 4 MB — S 8192 at D 128, S 4096 at D 128 float32)
+    assert geo.merged == (not forward and S * D < 4096 * 128)
+    assert geo.vmem_bytes == A._vmem_bytes(
+        *blocks, D, jnp.dtype(dtype).itemsize, forward,
+        geo.G * geo.Sqp if geo.merged else 0)
     assert geo.vmem_bytes <= A._VMEM_BUDGET
+
+
+@pytest.mark.parametrize("S,D,dtype,H,Hk,blocks,tiles,merged", [
+    # the cell's shape and the ladder's: dQ of a group stays in VMEM
+    (1024, 64, jnp.bfloat16, 16, 16, (None, None), (512, 512), True),
+    (1024, 128, jnp.bfloat16, 8, 2, (None, None), (512, 512), True),
+    (4096, 64, jnp.bfloat16, 16, 16, (None, None), (512, 512), True),
+    (8192, 64, jnp.bfloat16, 16, 16, (None, None), (512, 512), True),
+    (4096, 128, jnp.float32, 8, 8, (None, None), (512, 512), True),
+    (577, 64, jnp.bfloat16, 16, 16, (None, None), (384, 384), True),
+    (300, 8, jnp.float32, 2, 1, (128, 128), (128, 128), True),
+    # the accumulator is over the count: 4 x 8192 rows of 128 (33 MB), a
+    # group of 4 at S 4096 (16 MB), 16384 rows alone (19 MB)
+    (8192, 128, jnp.bfloat16, 4, 1, (None, None), (512, 512), False),
+    (4096, 64, jnp.bfloat16, 4, 1, (None, None), (512, 512), False),
+    (16384, 64, jnp.bfloat16, 8, 8, (None, None), (512, 512), False),
+    (8192, 128, jnp.bfloat16, 4, 1, (256, 512), (256, 512), False),
+])
+def test_geometry_merges_the_backward_where_dq_fits(S, D, dtype, H, Hk,
+                                                    blocks, tiles, merged):
+    """One algorithm, a parameter read off the shape: merged where the
+    whole-sequence dQ accumulator fits the count with the tiles, else the
+    SAME tiles for the two kernels — falling back never narrows a tile
+    and never raises."""
+    from mxnet_tpu.ops import attention as A
+    q = jax.ShapeDtypeStruct((1, H, S, D), dtype)
+    k = jax.ShapeDtypeStruct((1, Hk, S, D), dtype)
+    geo = A._geometry(q, k, *blocks, forward=False)
+    assert (geo.block_q, geo.block_k) == tiles
+    item = jnp.dtype(dtype).itemsize
+    with_dq = A._vmem_bytes(*tiles, D, item, False, geo.G * geo.Sqp)
+    apart = A._vmem_bytes(*tiles, D, item, False)
+    assert with_dq > apart
+    assert geo.merged == merged == (with_dq <= A._VMEM_BUDGET)
+    assert geo.vmem_bytes == (with_dq if merged else apart)
+    assert geo.vmem_bytes <= A._VMEM_BUDGET
+    assert not A._geometry(q, k, *blocks, forward=True).merged
 
 
 def test_geometry_sides_are_sized_apart():
@@ -336,6 +433,7 @@ def test_geometry_instant_once_a_compile_never_per_call(monkeypatch):
     own tiles, nothing on a call that hits jit's cache, nothing when
     tracing is off."""
     from mxnet_tpu import tracing
+    from mxnet_tpu.ops import attention as A
 
     def said():
         return [r["args"] for r in tracing.ring_records()
@@ -360,21 +458,38 @@ def test_geometry_instant_once_a_compile_never_per_call(monkeypatch):
             flash_attention(q, k, v, False, None)
         assert len(said()) == 1
         # the training path: the forward that keeps lse is another
-        # program, and the backward's two kernels speak for themselves
+        # program, and the backward's one kernel speaks for itself
         f = jax.jit(jax.grad(
             lambda a, b, c: jnp.sum(flash_attention(a, b, c, False, None,
                                                     128, 128)),
             argnums=(0, 1, 2)))
         f(q, k, v)
         got = said()[1:]
-        assert [a["kernel"] for a in got] \
-            == ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+        assert [a["kernel"] for a in got] == ["flash_fwd", "flash_bwd_dkv_dq"]
         assert all((a["block_q"], a["block_k"], a["derived"])
                    == (128, 128, False) for a in got)
-        assert [a["grid"] for a in got] == [[4, 2, 2], [4, 2, 2], [2, 2, 4]]
+        assert [a["grid"] for a in got] == [[4, 2, 2], [2, 2, 4]]
+        # only the backward says whether it merged
+        assert [a.get("merged") for a in got] == [None, True]
+        assert got[1]["vmem_bytes"] == A._vmem_bytes(128, 128, 24, 4, False,
+                                                     2 * 256)
         for _ in range(3):
             f(q, k, v)
-        assert len(said()) == 4
+        assert len(said()) == 3
+        # dQ's accumulator over the count (here: the budget under it):
+        # the two kernels, each with its own grid, once a compile too
+        monkeypatch.setattr(A, "_VMEM_BUDGET", got[1]["vmem_bytes"] - 1)
+        f2 = jax.jit(jax.grad(
+            lambda a, b, c: jnp.sum(flash_attention(a, b, c, True, None,
+                                                    128, 128)),
+            argnums=(0, 1, 2)))
+        for _ in range(2):
+            f2(q, k, v)
+        two = said()[4:]
+        assert [(a["kernel"], a["merged"], a["grid"]) for a in two] == [
+            ("flash_bwd_dq", False, [4, 2, 2]),
+            ("flash_bwd_dkv", False, [2, 2, 4])]
+        assert len(said()) == 6
     finally:
         monkeypatch.delenv("MXNET_TRACE")
         tracing.reconfigure()
@@ -627,7 +742,8 @@ def _tpu_lowered(fn, *avals):
 @pytest.mark.parametrize("S", [512, 1024, 4096])
 def test_flash_kernels_cross_lower_for_tpu(kernel, D, Hk, S):
     """S 512 is one derived tile; S 1024 the forward's 1024-row tile and
-    2 x 2 of the backward's 512; S 4096 several of both."""
+    2 x 2 of the backward's 512; S 4096 several of both.  The backward is
+    the merged kernel wherever dQ's accumulator fits."""
     from mxnet_tpu.ops import attention as A
     assert jax.config.jax_enable_x64       # the package's setting, not ours
     B, H = 2, 4
@@ -639,7 +755,12 @@ def test_flash_kernels_cross_lower_for_tpu(kernel, D, Hk, S):
             lambda q, k, v, o, l, g: A._flash_bwd(
                 q, k, v, o, l, g, causal=True, interpret=False),
             q, kv, kv, q, lse, q)
-        names = ["flash_bwd_dq", "flash_bwd_dkv"]
+        # H 4 / Hk 1 at S 4096 is 16384 rows of dQ a KV head: over the
+        # count, so that one lowers the two kernels
+        merged = A._geometry(q, kv, None, None, forward=False).merged
+        assert merged == ((S, Hk) != (4096, 1))
+        names = ["flash_bwd_dkv_dq"] if merged \
+            else ["flash_bwd_dq", "flash_bwd_dkv"]
     else:
         txt = _tpu_lowered(
             lambda q, k, v: A._flash_fwd(
