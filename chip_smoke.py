@@ -37,6 +37,12 @@ import time
 
 T0 = time.perf_counter()
 
+from chipbench.common import place_compile_cache  # noqa: E402
+
+# before the first ``import jax``: jax reads the cache's variable once, as
+# it is imported
+CACHE_DIR = place_compile_cache()
+
 import jax  # noqa: E402
 
 DEVICES = jax.devices()
@@ -47,10 +53,6 @@ if DEV.platform != "tpu":
 
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-
-from benchmark._bench_common import place_compile_cache  # noqa: E402
-
-CACHE_DIR = place_compile_cache()
 
 import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu import models  # noqa: E402
@@ -180,7 +182,7 @@ def phase_a():
         % (time.perf_counter() - t))
     del it
 
-    # (b) forward + update on device-resident batches (bench.py's loop)
+    # (b) forward + update on device-resident batches
     t = time.perf_counter()
     for seed in (1, 2, 3):
         x, y = device_batch(seed)

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # CI entry point (reference: Jenkinsfile:52-99 build+test matrix).
 # Runs the full suite on the virtual 8-device CPU mesh, the multichip
-# dryrun, a CPU bench smoke, and the multi-process dist tests.
+# dryrun and the multi-process dist tests.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -321,11 +321,10 @@ echo "== hierarchical kvstore smoke (in-mesh reduce + per-host wire shipping)"
 # own byte counters must show the hierarchy phase's wire at <= 60% of
 # the flat phase (the >= 40% acceptance drop), and the follower's
 # gradients must show up in the new "ici_*" counter family instead of
-# "sent" (the numbers behind bench.py's ici_bytes_per_step).  Runs
-# traced: the merged timeline must show the new tier — kv.mesh_reduce
-# and kv.leader_ship spans descending from a fused.chunk.  Time-boxed:
-# a fan-in regression presents as a hang, a byte regression as a
-# failed inequality.
+# "sent".  Runs traced: the merged timeline must show the new tier —
+# kv.mesh_reduce and kv.leader_ship spans descending from a
+# fused.chunk.  Time-boxed: a fan-in regression presents as a hang, a
+# byte regression as a failed inequality.
 # MXNET_KVSTORE_SHM=0 pins this run to loopback TCP: it is the byte
 # and send_syscalls baseline the shm gates below compare against
 rm -rf /tmp/_trace_hier && mkdir -p /tmp/_trace_hier
@@ -505,41 +504,6 @@ JAX_PLATFORMS=cpu timeout -k 10 240 \
     --env MXNET_HEALTH_RECOVERY_S=1.0 \
     python tests/dist/dist_health_smoke.py
 
-echo "== autotune smoke (stub-backend sweep: propose/measure/journal/promote)"
-# The measurement harness itself is CI-gated end to end on CPU
-# (docs/AUTOTUNE.md): a 6-trial sweep over a 2-knob toy space (the stub
-# axes restricted to 3x2 declared choices) against the deterministic
-# stub backend must CONVERGE to the analytic optimum (window=8,
-# chunk=4) and promote it into a throwaway PER-TOPOLOGY defaults file —
-# the exact loop a chip session runs (--target bench) proven without a
-# chip.  Time-boxed: a searcher/executor regression presents as a
-# missed optimum or a hang.
-rm -f /tmp/_autotune_smoke.jsonl /tmp/_autotune_smoke_defaults.json
-JAX_PLATFORMS=cpu timeout -k 10 240 \
-    python -m mxnet_tpu.autotune --target stub --trials 6 \
-    --restrict MXNET_KVSTORE_WINDOW=4,8,16 \
-    --restrict MXNET_KVSTORE_FUSED_CHUNK=2,4 \
-    --journal /tmp/_autotune_smoke.jsonl \
-    --defaults /tmp/_autotune_smoke_defaults.json \
-    | tee /tmp/_autotune_smoke.out
-JAX_PLATFORMS=cpu python - <<'PY'
-import json
-lines = [json.loads(l) for l in open("/tmp/_autotune_smoke.out")
-         if l.startswith("{")]
-assert len(lines) == 1, "one-JSON-line contract violated"
-out = lines[0]
-best = {"MXNET_KVSTORE_WINDOW": 8, "MXNET_KVSTORE_FUSED_CHUNK": 4}
-assert out["best_config"] == best, out
-assert out["promoted"] is True, out
-from mxnet_tpu.autotune import lookup_defaults, topology_key
-path = "/tmp/_autotune_smoke_defaults.json"
-entry = lookup_defaults(path, topology_key("cpu-stub"))
-assert entry["env"] == best, entry
-# and ONLY that topology: nothing leaks to a different device kind
-assert lookup_defaults(path, topology_key("cpu")) == {}
-print("autotune smoke OK: converged to", out["best_config"])
-PY
-
 echo "== multichip dryrun (8 virtual devices)"
 JAX_PLATFORMS=cpu python - <<'PY'
 import cpu_pin
@@ -548,27 +512,5 @@ import __graft_entry__ as ge
 ge.dryrun_multichip(8)
 print("dryrun_multichip(8) OK")
 PY
-
-echo "== bench smoke (CPU, tiny config; real numbers come from TPU runs)"
-# The bench OUTPUT CONTRACT is part of the gate: exactly ONE JSON line on
-# stdout (sweep tooling and BENCH_LOG banking parse it) — a stray print
-# or a config that emits twice breaks every downstream consumer
-# (VERDICT r5 item b).  The K-step scanned dispatch mode
-# (BENCH_STEPS_PER_CALL) is gated separately by tests/test_run_steps.py:
-# compiling the SCANNED ResNet-50@224 program on the CI CPU takes tens
-# of minutes, so the bench smoke stays per-step here and the scan runs
-# on real chips.
-BENCH_BATCH=8 BENCH_ITERS=2 BENCH_WARMUP=1 python - <<'PY' | tee /tmp/_bench_smoke.out
-import cpu_pin
-cpu_pin.pin_cpu(8)
-import bench, sys
-sys.exit(bench.main())
-PY
-json_lines=$(grep -c '^{' /tmp/_bench_smoke.out || true)
-if [ "$json_lines" != "1" ]; then
-  echo "BENCH CONTRACT VIOLATION: expected exactly 1 JSON line on" \
-       "stdout, got $json_lines" >&2
-  exit 1
-fi
 
 echo "== CI green"

@@ -1,8 +1,7 @@
 """Pin JAX to a virtual multi-device CPU backend.
 
 One shared implementation of the backend pin every CPU-side entry point
-uses (tests, CI, dist worker scripts, the multichip dryrun, the bench
-scripts' smoke modes).  Why it exists:
+uses (tests, CI, dist worker scripts, the multichip dryrun).  Why it exists:
 
 * A chip belongs to one process at a time; a unit-test or dryrun process
   must not claim it for work designed for virtual devices.

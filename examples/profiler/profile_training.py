@@ -8,8 +8,7 @@ Same workflow here, two capture layers:
    (load it at chrome://tracing or perfetto.dev);
  * on real hardware pass ``--xplane-dir DIR`` (or set
    ``MXNET_PROFILER_XLA_LOGDIR``) to also capture the XLA xplane trace
-   (summarize without TensorBoard via
-   ``python tools/xplane_summary.py DIR``).
+   (put both on one timeline with ``python tools/trace_merge.py``).
 
 Run:  python examples/profiler/profile_training.py
 """

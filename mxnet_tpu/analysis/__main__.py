@@ -15,8 +15,7 @@ mxnet_tpu/wirecodec.py folds in; ``--check`` fails (exit 2) when any
 generated copy is STALE instead of silently regenerating — the drift
 gate ci/run_ci.sh runs next to ``--strict``.
 ``--json`` emits one finding per line (the Finding dataclass fields
-verbatim) so CI and the autotune journal consume findings without
-scraping text.
+verbatim) so CI consumes findings without scraping text.
 """
 from __future__ import annotations
 

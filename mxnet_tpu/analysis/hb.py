@@ -374,6 +374,11 @@ class HBLock:
             return False
         return True
 
+    def _recursion_count(self):
+        # RLock only: multiprocessing.resource_tracker (Python 3.12) asks
+        # its own lock, which is one of these when created under the shim
+        return self._inner._recursion_count()
+
     def __repr__(self):
         if self.name:
             return "<HBLock %s %#x>" % (self.name, id(self))

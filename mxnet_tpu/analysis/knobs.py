@@ -25,32 +25,16 @@ class Knob:
     type: str
     default: object
     doc: str
-    # search-space metadata from declare_env(tune=...) — None when the
-    # knob declared no tune axis (mxnet_tpu.autotune derives its search
-    # spaces exclusively from this field's presence)
-    tune: Optional[dict] = None
 
 
 def registry() -> Dict[str, Knob]:
     """Every declared knob, keyed by name (from base._ENV_FLAGS)."""
-    from ..base import list_env_flags, list_env_tunables
-    tunables = list_env_tunables()
+    from ..base import list_env_flags
     out = {}
     for name, (typ, default, doc) in sorted(list_env_flags().items()):
         out[name] = Knob(name=name, type=typ.__name__, default=default,
-                         doc=" ".join(doc.split()),
-                         tune=tunables.get(name))
+                         doc=" ".join(doc.split()))
     return out
-
-
-def tune_summary(tune: Optional[dict]) -> str:
-    """One-cell rendering of a knob's tune axis for the doc table."""
-    if not tune:
-        return "—"
-    if tune.get("kind") == "choice":
-        return "{%s}" % ", ".join("%r" % c for c in tune["choices"])
-    return "[%r, %r]%s" % (tune["min"], tune["max"],
-                           " log" if tune.get("log") else "")
 
 
 def markdown_table() -> str:
@@ -58,13 +42,12 @@ def markdown_table() -> str:
     ``python -m mxnet_tpu.analysis --knob-table``)."""
     lines = [
         DOCS_BEGIN + " python -m mxnet_tpu.analysis --knob-table) -->",
-        "| knob | type | default | tunable | what it does |",
-        "|------|------|---------|---------|--------------|",
+        "| knob | type | default | what it does |",
+        "|------|------|---------|--------------|",
     ]
     for knob in registry().values():
-        lines.append("| `%s` | %s | `%r` | %s | %s |" % (
-            knob.name, knob.type, knob.default,
-            tune_summary(knob.tune), knob.doc or "—"))
+        lines.append("| `%s` | %s | `%r` | %s |" % (
+            knob.name, knob.type, knob.default, knob.doc or "—"))
     lines.append(DOCS_END)
     return "\n".join(lines)
 

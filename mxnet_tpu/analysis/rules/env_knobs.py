@@ -34,11 +34,8 @@ def _is_env_func(name: str) -> bool:
 
 
 def _mxnet_literal(node):
-    # BENCH_* counts too: the bench-script knobs are registered (that is
-    # what makes them autotune-able), so a package-internal read of an
-    # undeclared BENCH_ name is the same rot as an undeclared MXNET_ one
     if isinstance(node, ast.Constant) and isinstance(node.value, str) \
-            and node.value.startswith(("MXNET_", "BENCH_")):
+            and node.value.startswith("MXNET_"):
         return node.value
     return None
 
@@ -120,32 +117,11 @@ class _EnvKnobRule:
 
         reg = registry()
         for knob in sorted(set(reg) - reads):
-            if not knob.startswith("MXNET_"):
-                # BENCH_* rows are the bench-script surface: read by
-                # bench.py / benchmark/* at the repo root, OUTSIDE the
-                # linted package — registered so autotune can derive
-                # their axes, not because package code consults them
-                continue
             yield Finding(
                 rule=self.name, path="base.py", line=_decl_line(knob),
                 message="env knob %s is declared in the registry but "
                 "no code reads it — stale documentation; wire it up "
                 "or delete the declaration" % knob)
-        # tunable-but-undeclared: every axis a built-in autotune target
-        # sweeps must resolve to a registered knob — the space builder
-        # raises at runtime, this catches the drift before any sweep
-        from ...autotune.targets import all_target_knobs
-        for target, names in sorted(all_target_knobs().items()):
-            for knob in names:
-                if knob not in reg:
-                    yield Finding(
-                        rule=self.name, path="autotune/targets.py",
-                        line=1,
-                        message="autotune target %r sweeps knob %s "
-                        "which is not declared via base.declare_env — "
-                        "undeclared knobs can never be tuned; declare "
-                        "it (with tune= metadata) or drop the axis"
-                        % (target, knob))
         for knob, entry in sorted(reg.items()):
             if not entry.doc:
                 yield Finding(

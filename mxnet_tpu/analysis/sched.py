@@ -129,9 +129,9 @@ class _LockModel:
 
 
 class _Journal:
-    """Append-only JSONL schedule journal (the autotune-journal
-    conventions: one object per line, fsync at the records that must
-    survive a crash, torn trailing lines tolerated by the reader)."""
+    """Append-only JSONL schedule journal (one object per line, fsync at
+    the records that must survive a crash, torn trailing lines tolerated
+    by the reader)."""
 
     def __init__(self, path: Optional[str]):
         self.path = path

@@ -34,53 +34,7 @@ class MXNetError(RuntimeError):
 # ---------------------------------------------------------------------------
 _ENV_FLAGS: Dict[str, tuple] = {}
 
-# Tune metadata sidecar (mxnet_tpu.autotune): knobs that additionally
-# carry a search-space description.  Kept out of the _ENV_FLAGS tuple so
-# every existing (typ, default, doc) unpacker stays valid.
-_ENV_TUNE: Dict[str, dict] = {}
-
-
-def _validate_tune(name: str, typ: type, tune: dict) -> dict:
-    """Normalize/validate declare_env tune metadata.  Two shapes:
-    ``{"choices": [...]}`` (ordered candidate values, any typ) or
-    ``{"min": lo, "max": hi[, "log": True]}`` (numeric range)."""
-    if not isinstance(tune, dict):
-        raise MXNetError("declare_env(%s): tune metadata must be a dict, "
-                         "got %r" % (name, type(tune).__name__))
-    unknown = set(tune) - {"choices", "min", "max", "log"}
-    if unknown:
-        raise MXNetError("declare_env(%s): unknown tune keys %s"
-                         % (name, sorted(unknown)))
-    if "choices" in tune:
-        choices = list(tune["choices"])
-        if not choices:
-            raise MXNetError("declare_env(%s): empty tune choices" % name)
-        if "min" in tune or "max" in tune:
-            raise MXNetError("declare_env(%s): tune metadata is choices "
-                             "OR a min/max range, not both" % name)
-        return {"kind": "choice", "choices": choices}
-    if "min" not in tune or "max" not in tune:
-        raise MXNetError("declare_env(%s): tune metadata needs either "
-                         "'choices' or both 'min' and 'max'" % name)
-    if typ not in (int, float):
-        raise MXNetError("declare_env(%s): min/max tune ranges require "
-                         "an int or float knob, got %s"
-                         % (name, typ.__name__))
-    lo, hi = typ(tune["min"]), typ(tune["max"])
-    if not lo < hi:
-        raise MXNetError("declare_env(%s): tune range needs min < max, "
-                         "got [%r, %r]" % (name, lo, hi))
-    log = bool(tune.get("log", False))
-    if log and lo <= 0:
-        raise MXNetError("declare_env(%s): log-scale tune range needs "
-                         "min > 0" % name)
-    return {"kind": typ.__name__, "min": lo, "max": hi, "log": log}
-
-
-def declare_env(name: str, typ: type, default, doc: str = "",
-                tune: Optional[dict] = None) -> None:
-    if tune is not None:
-        _ENV_TUNE[name] = _validate_tune(name, typ, tune)
+def declare_env(name: str, typ: type, default, doc: str = "") -> None:
     _ENV_FLAGS[name] = (typ, default, doc)
 
 
@@ -105,13 +59,6 @@ def env(name: str, default=None):
 
 def list_env_flags() -> Dict[str, tuple]:
     return dict(_ENV_FLAGS)
-
-
-def list_env_tunables() -> Dict[str, dict]:
-    """Knobs that declared a search space (``declare_env(..., tune=)``).
-    The ONLY source mxnet_tpu.autotune derives axes from — an undeclared
-    knob can never be tuned."""
-    return {name: dict(meta) for name, meta in _ENV_TUNE.items()}
 
 
 # The runtime flags carried over from the reference that still make sense on
@@ -242,33 +189,27 @@ declare_env("MXNET_KVSTORE_BIGARRAY_BOUND", int, 1 << 19,
 declare_env("MXNET_KVSTORE_WINDOW", int, 8,
             "dist_async channel: max envelopes in flight per server "
             "connection (sliding-window pipeline; 1 = the old "
-            "stop-and-wait loop bit for bit)",
-            tune={"choices": [1, 2, 4, 8, 16, 32]})
+            "stop-and-wait loop bit for bit)")
 declare_env("MXNET_KVSTORE_COMPRESSION", str, "",
             "gradient compression for dist pushes: ''/none, 2bit or "
-            "fp16 (job-wide form of set_gradient_compression)",
-            tune={"choices": ["", "fp16", "2bit"]})
+            "fp16 (job-wide form of set_gradient_compression)")
 declare_env("MXNET_KVSTORE_COMPRESSION_THRESHOLD", float, 0.5,
             "2bit quantization threshold t: gradient values quantize "
-            "to {-t, 0, +t} with worker-side error feedback",
-            tune={"min": 0.05, "max": 2.0, "log": True})
+            "to {-t, 0, +t} with worker-side error feedback")
 declare_env("MXNET_KVSTORE_COALESCE_BYTES", int, 16384,
             "LIST pushes coalesce same-server keys at or below this "
-            "many payload bytes into one multi-key envelope",
-            tune={"choices": [0, 4096, 16384, 65536, 262144]})
+            "many payload bytes into one multi-key envelope")
 declare_env("MXNET_KVSTORE_CODEC", str, "auto",
             "dist kvstore wire codec: 'auto'/'binary' negotiate the "
             "registry-generated binary frame codec per connection at "
             "hello time (hot push/pull/predict envelopes serialize "
             "zero pickled bytes; old peers keep pickle), 'pickle' "
             "pins the legacy pickle framing — the mixed-version "
-            "escape hatch",
-            tune={"choices": ["auto", "binary", "pickle"]})
+            "escape hatch")
 declare_env("MXNET_KVSTORE_SENDMSG", int, 1,
             "dist kvstore transport: 1 sends each frame with vectored "
             "socket.sendmsg scatter-gather (one syscall per frame, "
-            "chunked at IOV_MAX); 0 falls back to per-buffer sendall",
-            tune={"choices": [0, 1]})
+            "chunked at IOV_MAX); 0 falls back to per-buffer sendall")
 declare_env("MXNET_KVSTORE_PICKLE_ALLOWLIST", str, "",
             "extra 'module' or 'module:name' entries (comma-separated) "
             "the wire unpickler admits — the custom-optimizer escape "
@@ -305,8 +246,7 @@ declare_env("MXNET_KVSTORE_SNAPSHOT_S", float, 0.0,
             "beats, fanned out to EVERY peer so the bank outlives any "
             "single server incl. the coordinator (the killed-server "
             "optimizer-state recovery source; 0 disables snapshots — "
-            "weights still recover from the workers' quorum re-push)",
-            tune={"choices": [0.0, 0.25, 1.0, 5.0]})
+            "weights still recover from the workers' quorum re-push)")
 declare_env("MXNET_KVSTORE_ELASTIC_PUSH_LOG", int, 256,
             "elastic: per-worker cap on pushes remembered since each "
             "key's last pull, re-applied under the new layout when a "
@@ -328,8 +268,7 @@ declare_env("MXNET_KVSTORE_FUSED_CHUNK", int, 8,
             "local (worker-replica) weight evolution between server "
             "sync points.  A K not divisible by the chunk compiles the "
             "tail chunk as its own XLA program — size K in multiples "
-            "to pay exactly one compile",
-            tune={"choices": [1, 2, 4, 8, 16, 32]})
+            "to pay exactly one compile")
 declare_env("MXNET_KVSTORE_FUSED_STALENESS", int, 1,
             "fused-dist driver: exactly how many chunk boundaries the "
             "adopted server weights lag — chunk j always starts from "
@@ -338,8 +277,7 @@ declare_env("MXNET_KVSTORE_FUSED_STALENESS", int, 1,
             "chunk boundary (no overlap) that single-worker matches the "
             "eager dist loop bit-for-bit; 1 (default) hides the wire "
             "behind one chunk of compute — async-SGD-grade staleness, "
-            "same class as the elastic handoff contract",
-            tune={"choices": [0, 1, 2]})
+            "same class as the elastic handoff contract")
 declare_env("MXNET_KVSTORE_HIERARCHY", bool, False,
             "dist_async: hierarchical reduction tier — workers sharing "
             "a host (membership.host_groups over the launch topology) "
@@ -351,8 +289,7 @@ declare_env("MXNET_KVSTORE_HIERARCHY", bool, False,
             "host factor.  Needs "
             "MXNET_KVSTORE_WORKERS_PER_HOST and MXT_MESH_URIS (both "
             "set by tools/launch.py --workers-per-host); static "
-            "rosters only",
-            tune={"choices": [0, 1]})
+            "rosters only")
 declare_env("MXNET_KVSTORE_WORKERS_PER_HOST", int, 0,
             "hierarchical kvstore tier: worker ranks per host — "
             "consecutive ranks group (launchers fill host slots in "
@@ -375,8 +312,7 @@ declare_env("MXNET_KVSTORE_MESH_ACCEPTORS", int, 8,
             "instead of serializing through one recv loop (reduction "
             "itself stays single-threaded at the local_allreduce_sum "
             "barrier); 1 restores the serialized single-acceptor "
-            "drain, values past the follower count change nothing",
-            tune={"choices": [1, 2, 4, 8, 16]})
+            "drain, values past the follower count change nothing")
 declare_env("MXNET_KVSTORE_SHM", str, "auto",
             "hierarchical kvstore tier: same-host shared-memory lane "
             "for follower<->leader mesh frames (mxnet_tpu/shmlane.py; "
@@ -387,14 +323,12 @@ declare_env("MXNET_KVSTORE_SHM", str, "auto",
             "TCP loopback path per connection.  Lane bytes land in "
             "the shm_* counter family (profiler.shm_bytes_total) with "
             "ZERO socket syscalls behind them; the socket's ici_* "
-            "drops to control traffic",
-            tune={"choices": ["auto", "on", "off"]})
+            "drops to control traffic")
 declare_env("MXNET_KVSTORE_SHM_RING_KB", int, 4096,
             "shm lane: ring capacity per direction in KiB — a frame "
             "larger than the ring rides the TCP path for that round "
             "(safe: mesh channels run a one-envelope window, so no "
-            "reordering is possible)",
-            tune={"choices": [256, 1024, 4096, 16384]})
+            "reordering is possible)")
 declare_env("MXNET_KVSTORE_SHM_STALL_S", float, 5.0,
             "shm lane: seconds a pushed request may sit unconsumed in "
             "the ring before the follower declares the lane wedged, "
@@ -408,33 +342,27 @@ declare_env("MXNET_KVSTORE_SPARSE", bool, True,
             "8 bytes per row id travel, cutting push bytes by roughly "
             "the touch density; 0 "
             "densifies at the push boundary (the pre-PR-19 wire "
-            "format, every byte dense)",
-            tune={"choices": [0, 1]})
+            "format, every byte dense)")
 declare_env("MXNET_KVSTORE_SPARSE_DENSITY_CUTOVER", float, 0.5,
             "dist_async sparse wire: touch-density threshold above "
             "which a row-sparse push goes DENSE instead — past ~50% "
             "touched rows the 8-bytes-per-id index overhead plus the "
             "gather outweighs the skipped rows, and the dense path's "
             "2-bit quantization packs tighter per element; 1.0 keeps "
-            "every sparse push sparse, 0.0 densifies all",
-            tune={"min": 0.05, "max": 1.0, "log": True})
+            "every sparse push sparse, 0.0 densifies all")
 # -- serving tier (mxnet_tpu.serving) ---------------------------------------
 declare_env("MXNET_SERVING_BUCKETS", str, "1,2,4,8,16,32",
             "serving: comma-separated batch-size buckets the replica "
             "pre-compiles predict executables for (requests pad to the "
-            "smallest covering bucket — N requests never mean N compiles)",
-            tune={"choices": ["1,2,4,8,16,32", "1,4,16,64",
-                              "8,16,32,64", "1,8,64"]})
+            "smallest covering bucket — N requests never mean N compiles)")
 declare_env("MXNET_SERVING_MAX_WAIT_MS", float, 2.0,
             "serving: dynamic batcher max wait for more requests before "
             "dispatching a partially-filled bucket (the latency half of "
-            "the batching SLO dial; 0 dispatches immediately)",
-            tune={"choices": [0.0, 0.5, 2.0, 5.0]})
+            "the batching SLO dial; 0 dispatches immediately)")
 declare_env("MXNET_SERVING_QUEUE_DEPTH", int, 256,
             "serving: admission control — requests queued past this "
             "depth are shed with a typed BUSY reply instead of growing "
-            "an unbounded queue",
-            tune={"choices": [64, 256, 1024]})
+            "an unbounded queue")
 declare_env("MXNET_SERVING_REFRESH_S", float, 0.0,
             "serving: seconds between weight-version polls against the "
             "live dist_async parameter servers (0 disables polling; the "
@@ -442,8 +370,7 @@ declare_env("MXNET_SERVING_REFRESH_S", float, 0.0,
 declare_env("MXNET_SERVING_CLIENT_WINDOW", int, 64,
             "serving: max in-flight predict envelopes per client "
             "connection (the serving override of MXNET_KVSTORE_WINDOW — "
-            "the replica's pipelined loop batches across the window)",
-            tune={"choices": [16, 64, 256]})
+            "the replica's pipelined loop batches across the window)")
 declare_env("MXNET_SERVING_LATENCY_WINDOW", int, 2048,
             "serving: ring size of the profiler's per-kind latency "
             "sample window (p50/p99/QPS are computed over this window; "
@@ -453,8 +380,7 @@ declare_env("MXNET_SERVING_FLEET_RETRIES", int, 3,
             "serving fleet: per-request retry budget — after the first "
             "attempt, at most this many more replicas are tried on "
             "BusyError / connection failure / reply timeout (predict is "
-            "pure, so a cross-replica retry can never double-apply)",
-            tune={"choices": [1, 3, 6]})
+            "pure, so a cross-replica retry can never double-apply)")
 declare_env("MXNET_SERVING_FLEET_DEADLINE_S", float, 30.0,
             "serving fleet: per-request wall deadline — routing, "
             "backoff sleeps and retries all stop here and the LAST "
@@ -483,8 +409,7 @@ declare_env("MXNET_SERVING_FLEET_DEGRADED_PENALTY", float, 4.0,
             "serving fleet: load multiplier applied to a DEGRADED "
             "replica in weighted-least-loaded routing (it still "
             "serves, just proportionally less; CRITICAL/dead/draining "
-            "replicas are excluded outright)",
-            tune={"choices": [2.0, 4.0, 8.0]})
+            "replicas are excluded outright)")
 declare_env("MXNET_SERVING_FLEET_CANARY_FRACTION", float, 0.1,
             "serving fleet: fraction of requests routed to the canary "
             "cohort while a canary is active")
@@ -521,10 +446,6 @@ declare_env("MXNET_PREDICT_READBACK_BATCHES", int, 64,
 declare_env("MXNET_FUSED_DONATE", bool, True,
             "donate param/aux/opt-state buffers to the fused training "
             "step so XLA updates them in place in HBM")
-declare_env("MXNET_ATTENTION_IMPL", str, "auto",
-            "attention kernel dispatch: flash (Pallas), xla (fused "
-            "jnp) or auto (the measured winner table decides); flash or "
-            "xla only, the kernels' tiles come from the shapes")
 # Deterministic fault injection (mxnet_tpu.faultinject) — the env forms
 # of configure(), for reaching into launcher-spawned worker processes.
 declare_env("MXNET_FI_KILL_POINT", str, "before_send",
@@ -588,68 +509,6 @@ declare_env("MXNET_FI_SHM_WEDGE_AFTER", int, None,
             "follower's MXNET_KVSTORE_SHM_STALL_S watchdog must turn "
             "into a clean TCP fallback with zero lost envelopes "
             "(composes with MXNET_FI_ONLY_RANK; unset = off)")
-# -- bench-script knobs (bench.py / benchmark/*) -----------------------------
-# Read by the repo-level bench scripts, which sit OUTSIDE the linted
-# package — declared here anyway because registration is what makes a
-# knob tunable: mxnet_tpu.autotune derives its search space exclusively
-# from this registry (docs/AUTOTUNE.md), so an undeclared bench axis
-# could never be swept.
-declare_env("BENCH_BATCH", int, 256,
-            "bench.py: training batch size (an out-of-memory batch "
-            "fails the run; per-topology BENCH_DEFAULTS.json overrides "
-            "the built-in default, env overrides both)",
-            tune={"choices": [64, 128, 256, 512, 1024]})
-declare_env("BENCH_DTYPE", str, "bfloat16",
-            "bench.py: compute dtype for the fused step (bfloat16 = "
-            "mixed precision with fp32 masters; float32 = full "
-            "precision)",
-            tune={"choices": ["bfloat16", "float32"]})
-declare_env("BENCH_OPT", str, "sgd",
-            "bench.py: optimizer driven through init_optimizer (lars "
-            "exercises the large-batch trust-ratio recipe)",
-            tune={"choices": ["sgd", "lars"]})
-declare_env("BENCH_STEPS_PER_CALL", int, 1,
-            "bench.py: training steps fused into ONE run_steps dispatch "
-            "(lax.scan); K>1 amortizes the host dispatch to 1/K per "
-            "step, 1 = classic per-step dispatch",
-            tune={"choices": [1, 2, 4, 8, 16]})
-declare_env("BENCH_STEM", str, "conv7",
-            "bench.py: ResNet stem variant — conv7 (reference 7x7) or "
-            "s2d (TPU-native space-to-depth, mathematically equivalent)",
-            tune={"choices": ["conv7", "s2d"]})
-declare_env("BENCH_LAYOUT", str, "nchw",
-            "bench.py: activation layout — nchw (MXNet default) or "
-            "nhwc (channels-last, the MLPerf-TPU ResNet convention)",
-            tune={"choices": ["nchw", "nhwc"]})
-declare_env("BENCH_REMAT", str, "0",
-            "bench.py: rematerialization — 0 off, 1/full whole-step "
-            "recompute, save_matmuls keeps conv/FC outputs and "
-            "recomputes elementwise chains",
-            tune={"choices": ["0", "1", "save_matmuls"]})
-# -- autotune harness (mxnet_tpu.autotune) -----------------------------------
-declare_env("MXNET_AUTOTUNE_TRIALS", int, 16,
-            "autotune: measured trials per sweep invocation (the CLI "
-            "--trials default; resume counts prior journaled trials "
-            "toward nothing — this is trials THIS run)")
-declare_env("MXNET_AUTOTUNE_SEED", int, 0,
-            "autotune: RNG seed for proposal sampling — same journal + "
-            "same seed reproduces the same proposal sequence exactly")
-declare_env("MXNET_AUTOTUNE_EPSILON", float, 0.25,
-            "autotune: epsilon-greedy exploration rate for the model "
-            "searcher — fraction of proposals drawn uniformly from the "
-            "space instead of argmax over the fitted cost model")
-declare_env("MXNET_AUTOTUNE_STRATEGY", str, "model",
-            "autotune: proposal strategy — model (fit-on-the-fly "
-            "regressor + epsilon-greedy), random, or grid")
-declare_env("MXNET_AUTOTUNE_TRIAL_TIMEOUT_S", float, 900.0,
-            "autotune: hard deadline per measured trial — the "
-            "subprocess executor SIGKILLs the config's whole process "
-            "group at the deadline and records status=timeout (a hung "
-            "trial can never serialize the sweep)")
-declare_env("MXNET_AUTOTUNE_CANDIDATES", int, 64,
-            "autotune: candidate pool size the model searcher scores "
-            "per proposal (random samples + neighbors of the measured "
-            "best)")
 # -- interleaving explorer (mxnet_tpu.analysis.sched) ------------------------
 declare_env("MXNET_SCHED_SCHEDULES", int, 20,
             "interleaving explorer: controlled schedules per "

@@ -214,7 +214,7 @@ def cluster_stats(compact: bool = False) -> dict:
     servers' last-known-counters banks, which still names members that
     have DIED (the bank outlives eviction, like the elastic state
     snapshots).  ``compact=True`` trims each entry to the transport
-    families (what bench.py banks into its one-line JSON row).
+    families.
 
     A server whose channel fails mid-sweep is skipped rather than
     failing the whole sweep: its last-known counters are usually still

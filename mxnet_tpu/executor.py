@@ -253,8 +253,8 @@ def drive_chunked_dist(num_steps, chunk_size, staleness, dispatch_chunk,
     The lag is EXACT, not just bounded: chunk ``j`` always adopts the
     round issued after chunk ``j-1-staleness``'s pushes, even when a
     fresher round happens to have resolved — determinism is what makes
-    the staleness-1 analytic golden (and any future autotuned setting)
-    simulable and therefore testable (tests/test_fused_dist.py).
+    the staleness-1 analytic golden simulable and therefore testable
+    (tests/test_fused_dist.py).
 
     Fault composition: ``handle.wait()`` owns its own recovery — under
     MXNET_KVSTORE_ELASTIC an in-flight round whose server died mid-pull

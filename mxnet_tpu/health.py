@@ -623,9 +623,8 @@ def _peek_snapshot():
 
 
 def summary() -> dict:
-    """The end-of-run digest bench.py banks next to wire_bytes_per_step:
-    current + worst status and the watchdog trip counters — an unhealthy
-    run is visible in BENCH_LOG.jsonl, not just slow."""
+    """The end-of-run digest: current + worst status and the watchdog
+    trip counters, so an unhealthy run is visible, not just slow."""
     st = status()
     with _lock:
         return {"status": st, "worst": _state.worst,
@@ -653,7 +652,7 @@ def reset() -> None:
 # ---------------------------------------------------------------------------
 # The flight-recorder bundle (black-box crash forensics)
 # ---------------------------------------------------------------------------
-_ENV_PREFIXES = ("MXNET_", "DMLC_", "MXT_", "BENCH_", "JAX_")
+_ENV_PREFIXES = ("MXNET_", "DMLC_", "MXT_", "JAX_")
 
 
 def _env_fingerprint() -> Dict[str, str]:

@@ -268,9 +268,9 @@ class ImageRecordIter(DataIter):
     def next_raw(self):
         """Next batch as HOST numpy arrays (data, label, pad) — no NDArray
         wrap, no device transfer.  For callers that manage placement
-        themselves (bench.py does ONE uint8 device_put per batch; wrapping
-        through next() would eagerly device_put and cost extra
-        host<->device crossings on a remote-attached chip)."""
+        themselves (ONE uint8 device_put per batch; wrapping through
+        next() would eagerly device_put and cost extra host<->device
+        crossings on a remote-attached chip)."""
         if self._done:
             raise StopIteration
         while True:

@@ -377,8 +377,8 @@ def _send_msg(sock, obj, fi_role=None, byte_kind="sent"):
     servers; the hierarchical tier's in-host mesh channels count under
     "ici_sent" (or "shm_sent" when the same-host lane carries them),
     and control-plane traffic (heartbeats, roster beats, hellos) under
-    "control" so bench.py reports gradients, mesh, and control
-    separately (profiler.wire_bytes_total / ici_bytes_total /
+    "control", so gradients, mesh and control are reported separately
+    (profiler.wire_bytes_total / ici_bytes_total /
     shm_bytes_total / control_bytes_total)."""
     if fi_role == "client":
         faultinject.client_send(sock)

@@ -4,7 +4,7 @@ gluon/model_zoo/vision/densenet.py architecture; Huang et al. 2017).
 Completes the symbolic model registry's coverage of the reference model
 zoo — the gluon DenseNet (gluon/model_zoo/vision/densenet.py here) is
 the block-based variant; this is the graph-API equivalent for
-Module-driven training and benchmark/score.py sweeps.
+Module-driven training.
 """
 from .. import symbol as sym
 

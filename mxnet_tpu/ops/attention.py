@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import NamedTuple
 
 import jax
@@ -166,10 +165,9 @@ def _geometry(q, k, block_q, block_k, forward):
     heads a KV head — for ``flash_fwd`` (``forward``) or for the backward.
     Derived tiles start from _TILE_FWD / _TILE_BWD and the wider side is
     halved until _vmem_bytes fits _VMEM_BUDGET (float32 operands, a wide
-    head); explicit blocks (tests, benchmark/attention_bench.py) must be
-    lane multiples and must fit as given.  The backward is the merged
-    kernel where its count fits, dQ's whole-sequence accumulator
-    included; where it does not (S 8192, D 128, four query heads a KV
+    head); explicit blocks (tests) must be lane multiples and must fit as
+    given.  The backward is the merged kernel where its count fits,
+    dQ's whole-sequence accumulator included; where it does not (S 8192, D 128, four query heads a KV
     head: 33 MB), the same tiles are counted for the dQ and dK/dV
     kernels apart.  Sequences are padded to whole tiles; the head dim is
     a whole block dim and travels unpadded.  All of it on every backend,
@@ -621,7 +619,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     and by the backward each for itself (_geometry).  block_q/block_k
     force them instead, for both (multiples of 128 that fit the VMEM
     count, else ValueError): for tests that want several tiles at a small
-    S and for benchmark/attention_bench.py's ATTN_BLOCKS sweep."""
+    S."""
     return _flash_fwd(q, k, v, causal=causal, scale=scale,
                       block_q=block_q, block_k=block_k)
 
@@ -642,78 +640,13 @@ def _fa_bwd(causal, scale, block_q, block_k, res, g):
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-_DISPATCH_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), "docs", "artifacts",
-    "attention_dispatch.json")
-_dispatch_cache = None  # (mtime_or_None, rows)
-_dispatch_stat_t = 0.0  # last time the file was stat'ed
-_DISPATCH_STAT_PERIOD_S = 2.0
-
-
-def _load_dispatch_table():
-    """Measured per-shape winner table written by
-    benchmark/attention_bench.py on real hardware: rows
-    ``{"min_seq": int, "max_seq": int, "gqa": bool, "winner":
-    "flash"|"xla"}``.  Absent file = empty table (flash wins by
-    default — it exists because it beats XLA at the long-seq shapes
-    the framework targets).  Keyed on file mtime so a table written
-    later in the same process (bench, then immediate use) is seen;
-    the stat is throttled so eager-mode op dispatch doesn't pay a
-    syscall per call."""
-    global _dispatch_cache, _dispatch_stat_t
-    import time as _time
-    now = _time.monotonic()
-    if (_dispatch_cache is not None
-            and now - _dispatch_stat_t < _DISPATCH_STAT_PERIOD_S):
-        return _dispatch_cache[1]
-    _dispatch_stat_t = now
-    try:
-        mtime = os.path.getmtime(_DISPATCH_PATH)
-    except OSError:
-        mtime = None
-    if _dispatch_cache is None or _dispatch_cache[0] != mtime:
-        rows = []
-        if mtime is not None:
-            try:
-                import json
-                with open(_DISPATCH_PATH) as f:
-                    rows = json.load(f)["rows"]
-            except Exception:  # noqa: BLE001 — invalid = default
-                rows = []
-        _dispatch_cache = (mtime, rows)
-    return _dispatch_cache[1]
-
-
-def pick_attention_impl(seq_len, gqa):
-    """'flash' (Pallas kernels) or 'xla' (fused jnp reference) for this
-    shape.  MXNET_ATTENTION_IMPL=flash|xla|auto overrides; in auto the
-    MEASURED winner table decides (VERDICT r3 item 5: an unmeasured
-    Pallas kernel must not be assumed faster — where the chip sweep shows
-    XLA winning, dispatch follows the data).  The table says nothing
-    about tiles: those come from the shapes (_geometry)."""
-    mode = os.environ.get("MXNET_ATTENTION_IMPL", "auto").lower()
-    if mode in ("flash", "xla"):
-        return mode
-    for row in _load_dispatch_table():
-        if (row.get("min_seq", 0) <= seq_len <= row.get("max_seq", 1 << 62)
-                and bool(row.get("gqa", False)) == bool(gqa)):
-            return row.get("winner", "flash")
-    return "flash"
-
-
 @register("_contrib_FlashAttention",
           arg_names=["query", "key", "value"],
           attr_defaults={"causal": False, "scale": None},
           aliases=("flash_attention", "_contrib_flash_attention"))
 def _flash_attention_op(query, key, value, causal=False, scale=None, **kw):
-    """Registry entry point: usable from mx.nd / mx.sym / gluon.
-    Per-shape dispatch: the Pallas flash kernels (their tiles derived
-    from the shapes) or the fused-XLA reference, per the winner table."""
-    impl = pick_attention_impl(
-        query.shape[2], key.shape[1] != query.shape[1])
-    if impl == "xla":
-        return _attn_reference(query, key, value, bool(causal), scale)
+    """Registry entry point: usable from mx.nd / mx.sym / gluon.  Always
+    the Pallas flash kernels, their tiles derived from the shapes."""
     return flash_attention(query, key, value, bool(causal), scale)
 
 

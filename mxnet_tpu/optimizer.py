@@ -758,7 +758,7 @@ class LAMB(Optimizer):
     """Layer-wise adaptive Adam for large-batch training (You et al.
     2019 — BERT in 76 minutes).  NEW capability relative to the
     reference; the large-batch companion of LARS for the transformer
-    track (benchmark/transformer_bench.py).
+    track.
 
         m, v   = adam moments (bias-corrected)
         r      = m_hat / (sqrt(v_hat) + eps) + wd * w

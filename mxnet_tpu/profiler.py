@@ -315,7 +315,7 @@ def fleet_route_counts() -> dict:
 # "allgather" for host collectives).  Separate from the event counters:
 # events prove a recovery path RAN, bytes prove a wire optimization is
 # real — the 2-bit compression acceptance asserts its >=8x push-byte
-# reduction against these, and bench.py surfaces wire_bytes_per_step.
+# reduction against these.
 _channel_bytes: dict = {}
 
 
@@ -334,9 +334,8 @@ def channel_bytes() -> dict:
 # The hierarchical kvstore tier's in-host mesh traffic counts under
 # "ici_*" kinds (kvstore_server._send_msg byte_kind) — a separate
 # counter FAMILY from the TCP wire, because the whole point of the tier
-# is moving bytes from the wire onto the mesh: bench.py reports
-# ici_bytes_per_step next to wire_bytes_per_step so the shift is a
-# banked, regression-gateable number.
+# is moving bytes from the wire onto the mesh, and the two families side
+# by side show the shift.
 ICI_BYTE_PREFIX = "ici_"
 
 # Control-plane traffic (heartbeats, roster beats/leaves, codec hellos)
@@ -359,8 +358,7 @@ def is_control_byte_kind(kind: str) -> bool:
 # kinds, because the lane's whole point is that these bytes never cross
 # a socket: when MXNET_KVSTORE_SHM is on, follower<->leader payload
 # moves from ici_* to shm_* and the socket's ici_* drops to control
-# traffic (hellos, heartbeats).  bench.py banks shm_bytes_per_step so
-# the shift is a regression-gateable number.
+# traffic (hellos, heartbeats).
 SHM_BYTE_PREFIX = "shm_"
 
 
@@ -424,7 +422,7 @@ def reset_channel_bytes():
 # Deliberately its own dict, not more _channel_bytes kinds: the
 # fault-injection tests assert channel counters by exact equality, and
 # the hot-path acceptance pin is pickle_bytes == 0 over a measured
-# window — bench.py banks both per-step.
+# window.
 _serialization: dict = {}
 _serialization_lock = threading.Lock()
 
@@ -541,9 +539,7 @@ def reset_wire_counters():
 # -- mesh fan-in clock --------------------------------------------------------
 # Host time the hierarchy-tier LEADER spends blocked in collect_push
 # waiting for every follower's round to arrive — the serialization the
-# parallel acceptor pool + shm lane exist to shrink.  bench.py banks
-# mesh_fanin_ms_per_step next to shm_bytes_per_step so the acceptors ×
-# shm A/B is a regression-gateable number.
+# parallel acceptor pool + shm lane exist to shrink.
 _fanin_lock = threading.Lock()
 _fanin = {"wait_s": 0.0, "rounds": 0}
 
@@ -736,9 +732,8 @@ def _main(argv=None) -> int:
     """``python -m mxnet_tpu.profiler [--dump] [--reset] [--watch S]``
     — the shell face of :func:`snapshot` for scripts and chip runbooks:
     ``--dump`` (the default) prints the full snapshot as ONE JSON line
-    (the same one-line contract bench.py and the autotune executor
-    parse); ``--reset`` zeroes the counters first (combine both for a
-    read-and-rearm); ``--watch S`` repeats the dump every S seconds —
+    (what scripts parse); ``--reset`` zeroes the counters first (combine
+    both for a read-and-rearm); ``--watch S`` repeats the dump every S seconds —
     one JSON line per tick, same contract — so a chip runbook can tail
     live counters (``| jq .wire``) without writing a loop.  ``--ticks
     N`` bounds the watch (0 = until interrupted)."""

@@ -24,10 +24,10 @@ arXiv:1605.08695; MXNet's engine-integrated profiler, arXiv:1512.01274):
   ``MXNET_TRACE_DIR`` is set, append to
   ``<dir>/<role>-<rank>.trace.jsonl``: append-only, fsync'd every
   ``MXNET_TRACE_FLUSH_N`` spans (and at exit), torn-line tolerant on
-  read exactly like the autotune journal — a SIGKILLed server loses at
-  most the unflushed tail, never the file.  ``tools/trace_merge.py
-  --spans`` stitches the per-process files into one chrome://tracing
-  timeline with cross-process flow arrows.
+  read — a SIGKILLed server loses at most the unflushed tail, never the
+  file.  ``tools/trace_merge.py --spans`` stitches the per-process
+  files into one chrome://tracing timeline with cross-process flow
+  arrows.
 
 Master switch: ``MXNET_TRACE=1``.  Off (the default) every entry point
 returns before touching a lock or allocating a record — call sites guard
@@ -468,7 +468,7 @@ def reset() -> None:
 def read_trace_file(path) -> list:
     """Parse one ``*.trace.jsonl`` — TORN-LINE TOLERANT: a process
     SIGKILLed mid-append leaves at most one undecodable line, which is
-    skipped (the autotune journal's resume contract applied to traces).
+    skipped.
     Returns the span records in file order."""
     out = []
     try:

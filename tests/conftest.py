@@ -11,6 +11,8 @@ own process.
 import os
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from cpu_pin import pin_cpu  # noqa: E402
@@ -18,7 +20,7 @@ from cpu_pin import pin_cpu  # noqa: E402
 pin_cpu(8)
 
 # This suite runs WITHOUT jax's persistent compilation cache (only the
-# chip entry points place one: benchmark/_bench_common.place_compile_cache).
+# chip entry points place one: chipbench.common.place_compile_cache).
 # An earlier note here warned that on jax 0.4.37 / XLA:CPU a deserialized
 # executable corrupted params under back-to-back donated dispatches
 # (Module.run_steps / Trainer.step_k).  Re-tested on the installed jax
@@ -28,3 +30,29 @@ pin_cpu(8)
 # stays off here because a fresh checkout starts it empty, and because
 # every XLA:CPU cache hit logs a machine-feature mismatch error
 # (cpu_aot_loader: +prefer-no-scatter) that would bury real output.
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _executed_ops_of_this_module(request):
+    """Audit test_operator's ``_COVERED_ELSEWHERE`` as each claimed module
+    finishes: the ops it names for that file must have executed in that
+    file (tests/test_zz_op_coverage.py ``stale_claims``).  A file is one
+    worker's under ``--dist loadfile``, so the audit holds however the
+    suite is spread.  ``registry.EXECUTED_OPS`` is this module's alone
+    while it runs and the session's again afterwards.  A selection inside
+    the file (``-k``, a ``::`` node id) or a failed test is not audited."""
+    from mxnet_tpu.ops import registry
+    session_wide, failed = registry.EXECUTED_OPS, request.session.testsfailed
+    registry.EXECUTED_OPS = here = set()
+    yield
+    registry.EXECUTED_OPS = session_wide | here
+    config = request.config
+    if (config.option.keyword or any("::" in a for a in config.args)
+            or request.session.testsfailed > failed):
+        return
+    from tests.test_zz_op_coverage import stale_claims
+    relpath = os.path.relpath(str(request.node.path), str(config.rootpath))
+    stale = stale_claims(relpath, here)
+    assert not stale, (
+        "_COVERED_ELSEWHERE (tests/test_operator.py) claims that %s "
+        "executes these ops, but it ran none of them: %r" % (relpath, stale))

@@ -162,7 +162,7 @@ def test_entry_point_strict_passes_on_live_tree():
 @pytest.mark.slow
 def test_entry_point_json_findings_schema():
     """--json: one Finding per line, dataclass fields verbatim —
-    the machine interface CI and the autotune journal consume."""
+    the machine interface CI consumes."""
     import dataclasses
     import json
     from mxnet_tpu.analysis.lint import Finding

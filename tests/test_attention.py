@@ -8,9 +8,6 @@ block skipping and clamped index maps force 128-row tiles on purpose.
 What the interpreter cannot show — that Mosaic accepts the program — is
 covered by the cross-lowering tests at the bottom and by chip_smoke.py on
 the chip."""
-import os
-import time
-
 import numpy as np
 import pytest
 import jax
@@ -658,70 +655,30 @@ def test_ulysses_gqa_compact_path_and_ring_dp_fold():
     np.testing.assert_allclose(out_r, ref, rtol=2e-4, atol=2e-5)
 
 
-def test_attention_impl_dispatch(monkeypatch, tmp_path):
-    """Per-shape winner dispatch (VERDICT r3 item 5): env override, the
-    measured table, and both impls agreeing numerically."""
-    import json
+@pytest.mark.parametrize("impl", [None, "xla"])
+def test_registry_op_always_runs_the_flash_kernels(monkeypatch, impl):
+    """The registry op (mx.nd / mx.sym / gluon) is the Pallas kernels and
+    nothing else: no environment variable and no table picks another
+    implementation, and the result equals the XLA reference."""
     from mxnet_tpu.ops import attention as att
 
+    if impl is None:
+        monkeypatch.delenv("MXNET_ATTENTION_IMPL", raising=False)
+    else:
+        monkeypatch.setenv("MXNET_ATTENTION_IMPL", impl)
     q, k, v = _rand_qkv(S=32, D=16)
-    # both impls produce the same math, so dispatch is free to choose
-    out_flash = att.flash_attention(q, k, v, True, None)
-    out_xla = att._attn_reference(q, k, v, True, None)
-    np.testing.assert_allclose(np.asarray(out_flash),
-                               np.asarray(out_xla), rtol=1e-5, atol=1e-5)
-
-    # env override wins over everything
-    monkeypatch.setenv("MXNET_ATTENTION_IMPL", "xla")
-    assert att.pick_attention_impl(4096, False) == "xla"
-    monkeypatch.setenv("MXNET_ATTENTION_IMPL", "flash")
-    assert att.pick_attention_impl(64, True) == "flash"
-
-    # auto consults the measured table; default (no table) is flash
-    monkeypatch.setenv("MXNET_ATTENTION_IMPL", "auto")
-    table = {"rows": [
-        {"min_seq": 0, "max_seq": 512, "gqa": False, "winner": "xla"},
-        {"min_seq": 513, "max_seq": 1 << 62, "gqa": False,
-         "winner": "flash"},
-    ]}
-    path = tmp_path / "attention_dispatch.json"
-    path.write_text(json.dumps(table))
-    monkeypatch.setattr(att, "_DISPATCH_PATH", str(path))
-    monkeypatch.setattr(att, "_dispatch_cache", None)  # drop cache
-    assert att.pick_attention_impl(256, False) == "xla"
-    assert att.pick_attention_impl(4096, False) == "flash"
-    assert att.pick_attention_impl(256, True) == "flash"  # no gqa row
-
-    # registry op respects the table (xla branch, numerics identical)
+    traced = jax.make_jaxpr(
+        lambda q, k, v: att._flash_attention_op(q, k, v, causal=True))(
+            q, k, v)
+    assert "pallas_call" in str(traced)
     out = mx.nd.flash_attention(mx.nd.NDArray(q), mx.nd.NDArray(k),
                                 mx.nd.NDArray(v), causal=True)
-    np.testing.assert_allclose(out.asnumpy(), np.asarray(out_xla),
-                               rtol=1e-5, atol=1e-5)
-
-    # a table REWRITTEN in the same process is observed (mtime cache) —
-    # the bench-then-use flow must not require a restart.  The stat is
-    # throttled (~2s) for eager-op dispatch cost; expire the throttle
-    # instead of sleeping through it.
-    table["rows"][0]["winner"] = "flash"
-    table["rows"][0]["blocks"] = "256x128"
-    path.write_text(json.dumps(table))
-    os.utime(path, (time.time() + 5, time.time() + 5))
-    monkeypatch.setattr(att, "_dispatch_stat_t", 0.0)
-    assert att.pick_attention_impl(256, False) == "flash"
-    # a `blocks` column in the table changes nothing: the tiles come from
-    # the shapes, and the op passes none
-    seen = []
-    monkeypatch.setattr(
-        att, "flash_attention",
-        lambda *a, **kw: seen.append((a[3:], kw)) or out_flash)
-    mx.nd.flash_attention(mx.nd.NDArray(q), mx.nd.NDArray(k),
-                          mx.nd.NDArray(v), causal=True)
-    assert seen == [((True, None), {})]
-    assert not hasattr(att, "pick_attention_config")
-    monkeypatch.setenv("MXNET_ATTENTION_IMPL", "xla")
-    assert att.pick_attention_impl(256, False) == "xla"
-    monkeypatch.setenv("MXNET_ATTENTION_IMPL", "auto")
-    monkeypatch.setattr(att, "_dispatch_cache", None)
+    np.testing.assert_allclose(
+        out.asnumpy(), np.asarray(att._attn_reference(q, k, v, True, None)),
+        rtol=1e-5, atol=1e-5)
+    for gone in ("pick_attention_impl", "_load_dispatch_table",
+                 "pick_attention_config"):
+        assert not hasattr(att, gone)
 
 
 # --- the program the chip compiles ------------------------------------------

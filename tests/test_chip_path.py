@@ -118,36 +118,57 @@ def test_fused_step_flops_is_a_number():
 
 
 # -- compile cache placement ----------------------------------------------------
+_CACHE_OPTIONS = ("jax_compilation_cache_dir",
+                  "jax_persistent_cache_min_compile_time_secs",
+                  "jax_persistent_cache_min_entry_size_bytes")
+
+
 @pytest.fixture
 def cache_config():
-    """place_compile_cache mutates a process-wide jax option; hand the
+    """place_compile_cache mutates process-wide jax options; hand the
     suite back exactly what it had (tier-1 runs without a cache)."""
-    before = jax.config.jax_compilation_cache_dir
+    before = {k: getattr(jax.config, k) for k in _CACHE_OPTIONS}
     yield
-    jax.config.update("jax_compilation_cache_dir", before)
+    for k, v in before.items():
+        jax.config.update(k, v)
 
 
 def test_compile_cache_env_set_touches_nothing(monkeypatch, cache_config):
-    from benchmark import _bench_common as bc
+    from chipbench import common as bc
     before = jax.config.jax_compilation_cache_dir
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
     assert bc.place_compile_cache() == "/some/dir"
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == "/some/dir"
     assert jax.config.jax_compilation_cache_dir == before
     assert not os.path.exists("/some/dir")
 
 
 def test_compile_cache_unset_goes_to_the_fixed_checkout_path(
         monkeypatch, cache_config):
-    from benchmark import _bench_common as bc
+    """Before ``import jax`` the fixed in-checkout path is placed through
+    the environment (a fresh interpreter shows it); in a process that has
+    jax already, nothing is placed and the caller is told so."""
+    from chipbench import common as bc
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    assert bc.place_compile_cache() == bc.COMPILE_CACHE_DIR
-    assert jax.config.jax_compilation_cache_dir == bc.COMPILE_CACHE_DIR
+    assert bc.place_compile_cache() is None
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
     assert bc.COMPILE_CACHE_DIR == os.path.join(ROOT, ".jax_compile_cache")
     with open(os.path.join(ROOT, ".gitignore")) as f:
         assert ".jax_compile_cache/" in f.read().split()
+    out = _run("import sys\n"
+               "from chipbench.common import place_compile_cache\n"
+               "assert 'jax' not in sys.modules\n"
+               "print(place_compile_cache())\n"
+               "import jax\n"
+               "print(jax.config.jax_compilation_cache_dir)\n",
+               JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=None)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [bc.COMPILE_CACHE_DIR] * 2
 
 
 def test_only_one_place_sets_the_compile_cache():
+    """The cache is placed through ``JAX_COMPILATION_CACHE_DIR``
+    (chipbench.common.place_compile_cache); no code sets jax's option."""
     hits = []
     for top, dirs, files in os.walk(ROOT):
         dirs[:] = [d for d in dirs if not d.startswith(".")
@@ -158,16 +179,16 @@ def test_only_one_place_sets_the_compile_cache():
                     if "jax_compilation_cache_dir" in f.read():
                         hits.append(os.path.relpath(
                             os.path.join(top, name), ROOT))
-    assert hits == [os.path.join("benchmark", "_bench_common.py")]
+    assert hits == []
 
 
 # -- peaks ------------------------------------------------------------------------
 def test_peak_table_is_exact_and_unknown_kinds_are_errors():
-    from benchmark._bench_common import peak_flops
-    assert peak_flops("TPU v5 lite") == 197e12
+    from chipbench.common import load_peaks
+    assert load_peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
     for kind in ("TPU v5 lite pod", "tpu v5 lite", "v5", "TPU v9", "cpu"):
-        with pytest.raises(KeyError, match="no peak FLOP/s recorded"):
-            peak_flops(kind)
+        with pytest.raises(KeyError, match="no peaks recorded"):
+            load_peaks(kind)
 
 
 # -- one process per chip ----------------------------------------------------------

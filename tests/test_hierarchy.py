@@ -12,8 +12,7 @@ fused×elastic _PullHandle replan — the ISSUE 14 tentpole, CPU-provable:
   two flat pushes applied in either order.
 * **the wire actually shrinks** — the hierarchy run's TCP byte counters
   sit strictly below the flat run's, with the difference showing up in
-  the new "ici_*" family (profiler.ici_bytes_total; bench.py reports
-  ici_bytes_per_step from the same counters).
+  the new "ici_*" family (profiler.ici_bytes_total).
 * **roster-bump-mid-pull replan** — an in-flight pull_async whose
   server dies mid-round repairs the roster from inside wait(),
   re-issues ONLY the unserved tail under the new stripe layout

@@ -3,9 +3,9 @@
 Round-1 regression: ``ops/detection.py`` had a module-level
 ``jnp.float32(-1.0)`` that dispatched an eager JAX primitive at import time,
 forcing TPU-backend initialization during ``import mxnet_tpu``.  A chip
-belongs to one process, so a parent that merely imports the package (the
-autotune harness, a launcher) would take the chip from the child that needs
-it.  Import must be hermetic: zero device dispatch, zero backend init.
+belongs to one process, so a parent that merely imports the package (a
+launcher) would take the chip from the child that needs it.  Import must
+be hermetic: zero device dispatch, zero backend init.
 """
 import os
 import subprocess
@@ -18,8 +18,6 @@ import sys
 sys.path.insert(0, @ROOT@)
 from jax._src import xla_bridge
 import mxnet_tpu
-# the autotune parent must stay off the chip its trial children need
-import mxnet_tpu.autotune.__main__
 assert not xla_bridge.backends_are_initialized(), (
     "import mxnet_tpu initialized JAX backend(s): %r" %
     list(xla_bridge._backends))

@@ -1098,7 +1098,6 @@ _COVERED_ELSEWHERE = {
     '_contrib_ChunkedLMLoss': 'tests/test_chunked_loss.py',
     'Embedding': 'tests/test_gluon.py',
     'Dropout': 'tests/test_autograd.py',
-    'SequenceMask': 'tests/test_rnn.py',
     # spatial + contrib tail (round 2): tests/test_spatial_contrib.py
     'GridGenerator': 'tests/test_spatial_contrib.py',
     'BilinearSampler': 'tests/test_spatial_contrib.py',

@@ -24,7 +24,7 @@ _SKIP = {
 }
 
 _GREP_DIRS = ["mxnet_tpu", "tools", "cpp", "tests", "examples", "ci",
-              "benchmark", "docs", "bench.py", "__graft_entry__.py"]
+              "docs", "__graft_entry__.py"]
 
 
 def _tokens():

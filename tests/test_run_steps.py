@@ -629,7 +629,7 @@ def test_run_steps_large_k_chip_config():
     """Chip-session smoke: a larger K at the bench's step composition
     (SGD momentum, BN network).  Slow-marked — CI runs it, the default
     gate skips it; on a real chip this is the dispatch-amortization
-    measurement path (bench.py BENCH_STEPS_PER_CALL)."""
+    measurement path."""
     data, label = _data(k=32)
     mx.random.seed(0)
     m1 = _make_module()

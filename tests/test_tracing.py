@@ -394,7 +394,7 @@ def test_ledger_stats_bank_outlives_eviction():
 
 def test_profiler_cli_dump_one_json_line():
     """``python -m mxnet_tpu.profiler --dump`` prints the snapshot as
-    exactly one JSON line (the bench/autotune stdout contract)."""
+    exactly one JSON line."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("DMLC_ROLE", None)
     out = subprocess.run(

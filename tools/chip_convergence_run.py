@@ -25,7 +25,7 @@ import numpy as np
 
 
 def main():
-    from benchmark._bench_common import make_mark, place_compile_cache
+    from chipbench.common import make_mark, place_compile_cache
     # three modes: chip artifact (default), CPU smoke (script check,
     # no artifact), CPU artifact (FULL run on the virtual-CPU platform —
     # convergence evidence that needs no chip, honestly labeled)

@@ -37,13 +37,37 @@ first host event (documented in the output metadata,
 "clock_alignment").
 """
 import argparse
+import glob
 import json
 import os
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from tools.xplane_summary import device_planes, find_xplane, load  # noqa: E402,E501
+def find_xplane(path):
+    if os.path.isfile(path):
+        return path
+    hits = sorted(glob.glob(os.path.join(
+        path, "**", "*.xplane.pb"), recursive=True))
+    if not hits:
+        raise SystemExit("no .xplane.pb under %s" % path)
+    return hits[-1]  # latest run
+
+
+def load(path):
+    from tensorflow.tsl.profiler.protobuf import xplane_pb2
+    sp = xplane_pb2.XSpace()
+    with open(path, "rb") as f:
+        sp.ParseFromString(f.read())
+    return sp
+
+
+def device_planes(space):
+    """TPU device planes (or CPU-host XLA planes when no TPU present)."""
+    tpu = [p for p in space.planes if "/device:TPU" in p.name]
+    if tpu:
+        return tpu
+    return [p for p in space.planes if "Host Threads" not in p.name
+            and p.lines]
 
 
 def xplane_events(space, pid_base):
