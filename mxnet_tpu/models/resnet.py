@@ -63,26 +63,8 @@ def residual_unit_v2(data, num_filter, stride, dim_match, name,
         return conv2 + shortcut
 
 
-def space_to_depth_stem_weight(w7):
-    """Convert a (C_out, C_in, 7, 7) stem weight into the (C_out, 4*C_in,
-    4, 4) weight the ``stem='s2d'`` graph uses.  Zero-pads 7x7 -> 8x8 at the
-    top-left, then folds each 2x2 spatial phase into channels — the exact
-    inverse of the input space-to-depth rearrangement, so the composed op is
-    mathematically identical to the original stride-2 conv (MLPerf-ResNet
-    TPU trick; the padded tap multiplies only zeros)."""
-    import numpy as np
-    w7 = np.asarray(w7)
-    co, ci = w7.shape[:2]
-    w8 = np.zeros((co, ci, 8, 8), w7.dtype)
-    w8[:, :, 1:, 1:] = w7
-    # w8[o, c, 2*di+a, 2*dj+b] -> w_sd[o, c*4 + 2*a + b, di, dj]
-    w = w8.reshape(co, ci, 4, 2, 4, 2)          # (o, c, di, a, dj, b)
-    w = w.transpose(0, 1, 3, 5, 2, 4)           # (o, c, a, b, di, dj)
-    return w.reshape(co, ci * 4, 4, 4)
-
-
 def resnet(units, num_stages, filter_list, num_classes, image_shape,
-           bottle_neck=True, stem="conv7", layout="NCHW"):
+           bottle_neck=True, layout="NCHW"):
     """``layout="NHWC"`` runs the whole activation path channels-last (the
     MLPerf-TPU convention): the NCHW ``data`` input is transposed ONCE at
     the graph entry (XLA folds it into the first conv's relayout), every
@@ -108,38 +90,11 @@ def resnet(units, num_stages, filter_list, num_classes, image_shape,
                                kernel=(3, 3), stride=(1, 1), pad=(1, 1),
                                no_bias=True, layout=layout, name="conv0")
     else:  # imagenet stem
-        if stem == "s2d":
-            # TPU-native stem (MLPerf-ResNet space-to-depth trick): fold
-            # 2x2 spatial phases into channels so the first conv sees 12
-            # input channels instead of 3 — 4x better MXU occupancy on the
-            # most underfilled conv in the network.  Mathematically
-            # EQUIVALENT to the 7x7/s2 conv (weights related by
-            # space_to_depth_stem_weight; tests/test_models.py asserts
-            # forward equality).  conv0 weight shape becomes (64, 12, 4, 4).
-            n_, h_, w_ = nchannel, height // 2, width // 2
-            if nhwc:
-                # (N,H,W,C) -> (N,h,w,C*4) with channel index c*4+2a+b —
-                # IDENTICAL phase order to the NCHW path, so one stem
-                # weight serves both layouts
-                x = sym.Reshape(data, shape=(-1, h_, 2, w_, 2, n_))
-                x = sym.transpose(x, axes=(0, 1, 3, 5, 2, 4))
-                x = sym.Reshape(x, shape=(-1, h_, w_, n_ * 4))
-            else:
-                x = sym.Reshape(data, shape=(-1, n_, h_, 2, w_, 2))
-                x = sym.transpose(x, axes=(0, 1, 3, 5, 2, 4))
-                x = sym.Reshape(x, shape=(-1, n_ * 4, h_, w_))
-            body = sym.Convolution(data=x, num_filter=filter_list[0],
-                                   kernel=(4, 4), stride=(1, 1), pad=(2, 2),
-                                   no_bias=True, layout=layout, name="conv0")
-            # symmetric pad 2 yields one extra row/col vs the original's
-            # effective (4,3) asymmetric padding — drop the trailing edge
-            h_ax, w_ax = (1, 2) if nhwc else (2, 3)
-            body = sym.slice_axis(body, axis=h_ax, begin=0, end=h_)
-            body = sym.slice_axis(body, axis=w_ax, begin=0, end=w_)
-        else:
-            body = sym.Convolution(data=data, num_filter=filter_list[0],
-                                   kernel=(7, 7), stride=(2, 2), pad=(3, 3),
-                                   no_bias=True, layout=layout, name="conv0")
+        # 7x7/s2 over 3 channels: the Convolution op itself computes it
+        # through space-to-depth (ops/nn.py); the weight stays (64, 3, 7, 7)
+        body = sym.Convolution(data=data, num_filter=filter_list[0],
+                               kernel=(7, 7), stride=(2, 2), pad=(3, 3),
+                               no_bias=True, layout=layout, name="conv0")
         body = sym.BatchNorm(data=body, fix_gamma=False, eps=BN_EPS,
                              momentum=BN_MOM, axis=bn_ax, name="bn0")
         body = sym.Activation(data=body, act_type="relu", name="relu0")
@@ -166,7 +121,7 @@ def resnet(units, num_stages, filter_list, num_classes, image_shape,
 
 
 def get_symbol(num_classes=1000, num_layers=50, image_shape="3,224,224",
-               stem="conv7", layout="NCHW", **kwargs):
+               layout="NCHW", **kwargs):
     """Depth → unit table from symbols/resnet.py get_symbol."""
     if isinstance(image_shape, str):
         image_shape = tuple(int(x) for x in image_shape.split(","))
@@ -206,4 +161,4 @@ def get_symbol(num_classes=1000, num_layers=50, image_shape="3,224,224",
     return resnet(units=units, num_stages=num_stages,
                   filter_list=filter_list, num_classes=num_classes,
                   image_shape=image_shape, bottle_neck=bottle_neck,
-                  stem=stem, layout=layout)
+                  layout=layout)

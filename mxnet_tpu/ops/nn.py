@@ -20,7 +20,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax._src import source_info_util
 
+from .. import tracing
 from ..base import tag_for_remat as _ckpt_name
 
 from .registry import register, alias
@@ -73,6 +75,96 @@ def _check_layout(layout, rank):
     return layout == "NHWC"
 
 
+# A strided convolution over a handful of input channels starves the MXU:
+# its contraction is C_in deep a tap (forward, weight gradient), its data
+# gradient has C_in output channels.  Folding each s x s block of pixels
+# into channels ("space-to-depth", what the MLPerf ResNet-50 TPU
+# submissions do to the stem) makes it a stride-1 convolution over
+# C_in * s * s channels with ceil(k / s) taps an axis: the same sums (the
+# taps past k multiply zeros), s * s times the depth.  The rule reads the
+# operands' shapes and nothing else; the weight argument, its gradient and
+# every checkpoint keep (O, C_in, k, k).
+# What it costs is copies of the input (pad, phase transpose, re-tiling)
+# and of its gradient, which grow with C_in, where the plain convolution's
+# time grows with k * k whatever C_in is; so the rule is k * k against
+# C_in.  Set on a TPU v5 lite (PERF.md, PR 33: b256 bf16, ms a call of
+# forward + data gradient reduced to [C_in] + weight gradient, plain |
+# folded).  Folded, k*k/C_in >= 12: 7x7/2 C3 224^2 8.63 | 6.72 (64^2
+# 0.77 | 0.51, NHWC 6.82 | 4.52), C1 7.45 | 4.47, C4 8.30 | 7.16; 11x11/4
+# C3 5.47 | 2.40; 5x5/2 C1 3.24 | 2.40.  Left as written: 5x5/2 C3 4.15 |
+# 4.08 and 7x7/2 C8 2.40 | 2.32 (level), 7x7/2 C16 3.26 | 4.83; 3x3/2
+# slower folded at every C_in (C3 2.39 | 3.19, C1 2.12 | 2.32, C32 2.24 |
+# 5.83); two taps a stride (4x4/2 C3 5.10 | 4.67, level at 64^2) were
+# timed at that one shape, and more than 128 folded channels not at all.
+_FOLD_MIN_KK_PER_C_IN = 12
+_FOLD_MIN_TAPS = 3
+_FOLD_MAX_CHANNELS = 128
+
+
+def _folds_stride(data, weight, stride, dilate, pad, num_group, nhwc):
+    """The stride to fold into channels, or 0 where the convolution stays
+    as written: 2-D, one group, no dilation, a square stride under a square
+    kernel, inside the ladder above, on an input the kernel fits in."""
+    if data.ndim != 4 or num_group != 1 or any(d != 1 for d in dilate):
+        return 0
+    c, (k, kw), (s, sw) = weight.shape[1], weight.shape[2:], stride
+    if k != kw or s != sw or s < 2 or -(-k // s) < _FOLD_MIN_TAPS:
+        return 0
+    if k * k < _FOLD_MIN_KK_PER_C_IN * c or c * s * s > _FOLD_MAX_CHANNELS:
+        return 0
+    size = data.shape[1:3] if nhwc else data.shape[2:]
+    return s if all(n + 2 * p >= k for n, p in zip(size, pad)) else 0
+
+
+def _fold_blocks(x, s, nhwc=False):
+    """The s x s blocks of the two spatial axes folded into channels:
+    (N, C, H, W) -> (N, C*s*s, H/s, W/s), channel c*s*s + s*a + b."""
+    if nhwc:
+        n, h, w, c = x.shape
+        x = x.reshape(n, h // s, s, w // s, s, c).transpose(0, 1, 3, 5, 2, 4)
+        return x.reshape(n, h // s, w // s, c * s * s)
+    n, c, h, w = x.shape
+    x = x.reshape(n, c, h // s, s, w // s, s).transpose(0, 1, 3, 5, 2, 4)
+    return x.reshape(n, c * s * s, h // s, w // s)
+
+
+def _conv_space_to_depth(data, weight, s, pad, nhwc):
+    """The stride-s convolution of `data` with the (O, C, k, k) `weight`
+    as one stride-1 VALID convolution of their s x s foldings."""
+    _, c, k, _ = weight.shape
+    taps = -(-k // s)
+    sp0 = 1 if nhwc else 2
+    cfg = [(0, 0, 0)] * 4
+    for i in (0, 1):
+        size = data.shape[sp0 + i]
+        out = (size + 2 * pad[i] - k) // s + 1
+        # input index s*i + u with no offset: p before, and after whatever
+        # (a crop where negative) makes the length s * (out - 1 + taps)
+        cfg[sp0 + i] = (pad[i], s * (out - 1 + taps) - size - pad[i], 0)
+    x = _fold_blocks(lax.pad(data, jnp.zeros((), data.dtype), cfg), s, nhwc)
+    w = jnp.pad(weight, ((0, 0), (0, 0)) + ((0, s * taps - k),) * 2)
+    # the barrier's transpose stands between the folded weight gradient
+    # and its un-folding: without it XLA:TPU's simplifier matches the two
+    # into the plain convolution's weight gradient (window 112 x 112,
+    # rhs_dilate 2 for the ResNet stem) and its time (PERF.md, PR 33)
+    w = lax.optimization_barrier(_fold_blocks(w, s))
+    tracing.instant("mx.conv.space_to_depth", "ops", args={
+        "node": _node_name(), "c_in": c, "kernel": k, "stride": s,
+        "folded_channels": c * s * s, "folded_kernel": taps,
+        "layout": "NHWC" if nhwc else "NCHW"})
+    return lax.conv_general_dilated(
+        x, w, window_strides=(1, 1), padding="VALID",
+        dimension_numbers=("NHWC", "OIHW", "NHWC") if nhwc else _CONV_DN[2])
+
+
+def _node_name():
+    """The symbol node being traced (executor: jax.named_scope(<node>)),
+    '' for an eager call or shape inference."""
+    scopes = [e.name for e in source_info_util.current_name_stack().stack
+              if isinstance(e, source_info_util.Scope)]
+    return scopes[-1] if scopes else ""
+
+
 @register("Convolution", arg_names=["data", "weight", "bias"],
           attr_defaults={"kernel": (), "stride": (), "dilate": (), "pad": (),
                          "num_filter": 0, "num_group": 1, "no_bias": False,
@@ -86,17 +178,21 @@ def _convolution(data, weight, bias=None, kernel=(), stride=(), dilate=(),
     dilate = _pair(dilate, rank) if dilate else (1,) * rank
     pad = _pair(pad, rank) if pad else (0,) * rank
     nhwc = _check_layout(layout, rank)
-    # NHWC activations (reference: conv layout param, convolution.cc) keep
-    # the WEIGHT in MXNet's OIHW — checkpoints stay layout-agnostic and
-    # XLA relayouts the filter once at compile time
-    dn = ("NHWC", "OIHW", "NHWC") if nhwc else _CONV_DN[rank]
-    out = lax.conv_general_dilated(
-        data, weight,
-        window_strides=stride,
-        padding=tuple((p, p) for p in pad),
-        rhs_dilation=dilate,
-        dimension_numbers=dn,
-        feature_group_count=num_group)
+    fold = _folds_stride(data, weight, stride, dilate, pad, num_group, nhwc)
+    if fold:
+        out = _conv_space_to_depth(data, weight, fold, pad, nhwc)
+    else:
+        # NHWC activations (reference: conv layout param, convolution.cc)
+        # keep the WEIGHT in MXNet's OIHW — checkpoints stay
+        # layout-agnostic and XLA relayouts the filter once at compile time
+        dn = ("NHWC", "OIHW", "NHWC") if nhwc else _CONV_DN[rank]
+        out = lax.conv_general_dilated(
+            data, weight,
+            window_strides=stride,
+            padding=tuple((p, p) for p in pad),
+            rhs_dilation=dilate,
+            dimension_numbers=dn,
+            feature_group_count=num_group)
     if not no_bias and bias is not None:
         out = out + (bias if nhwc
                      else bias.reshape((1, -1) + (1,) * rank))

@@ -56,3 +56,24 @@ def _executed_ops_of_this_module(request):
     assert not stale, (
         "_COVERED_ELSEWHERE (tests/test_operator.py) claims that %s "
         "executes these ops, but it ran none of them: %r" % (relpath, stale))
+
+
+@pytest.fixture
+def conv_fold_instants(monkeypatch):
+    """``MXNET_TRACE=1`` around a test, the ring emptied.  Yields
+    ``said(nodes_only=False)``: the ``args`` of every
+    ``mx.conv.space_to_depth`` instant since (ops/nn.py: a ``Convolution``
+    that folded its stride); ``nodes_only`` drops those from eager calls
+    and shape inference, which run the op under no node's scope."""
+    from mxnet_tpu import tracing
+    monkeypatch.setenv("MXNET_TRACE", "1")
+    tracing.reconfigure()
+    tracing.reset()
+
+    def said(nodes_only=False):
+        return [r["args"] for r in tracing.ring_records()
+                if r["name"] == "mx.conv.space_to_depth"
+                and (r["args"]["node"] or not nodes_only)]
+    yield said
+    monkeypatch.delenv("MXNET_TRACE")
+    tracing.reconfigure()

@@ -22,11 +22,11 @@ import mxnet_tpu as mx
 from mxnet_tpu import models
 
 
-def _lowered_resnet_step_hlo(compute_dtype, stem="conv7",
+def _lowered_resnet_step_hlo(compute_dtype,
                              num_layers=8, image_shape=(3, 28, 28)):
     import jax.numpy as jnp
     sym = models.resnet(num_classes=10, num_layers=num_layers,
-                        image_shape=image_shape, stem=stem)
+                        image_shape=image_shape)
     mod = mx.mod.Module(sym, compute_dtype=compute_dtype and
                         jnp.dtype(compute_dtype))
     batch = 2
@@ -84,14 +84,14 @@ def test_fp32_mode_keeps_fp32_convolution():
     assert convs and all("f32" in dts for dts in convs)
 
 
-def _sweep_step_hlo(stem, remat_policy):
-    """Lower the fused step in a sweep configuration (s2d stem and/or
-    remat) — the configs the chip sweeps measure; an fp32
-    activation leak in one of them would waste the chip session.
+def _sweep_step_hlo(remat_policy):
+    """Lower the fused step under a remat setting — the configs the chip
+    sweeps measure; an fp32 activation leak in one of them would waste the
+    chip session.
 
-    The stem only exists on the imagenet branch (height > 32,
+    The 7x7/s2 stem only exists on the imagenet branch (height > 32,
     models/resnet.py), so this lowers a 64x64 ResNet-18 — 28x28 would
-    silently test the cifar stem regardless of `stem`.
+    silently test the cifar stem, which ``Convolution`` does not fold.
     """
     import os
     old = {k: os.environ.pop(k, None)
@@ -101,8 +101,7 @@ def _sweep_step_hlo(stem, remat_policy):
             os.environ["MXNET_BACKWARD_DO_MIRROR"] = "1"
             if remat_policy not in ("1", "full"):
                 os.environ["MXNET_REMAT_POLICY"] = remat_policy
-        return _lowered_resnet_step_hlo("bfloat16", stem=stem,
-                                        num_layers=18,
+        return _lowered_resnet_step_hlo("bfloat16", num_layers=18,
                                         image_shape=(3, 64, 64))
     finally:
         for k, v in old.items():
@@ -112,19 +111,25 @@ def _sweep_step_hlo(stem, remat_policy):
                 os.environ[k] = v
 
 
-@pytest.mark.parametrize("stem,remat", [
-    ("s2d", None),
-    ("s2d", "save_matmuls"),
-    ("s2d", "1"),       # b512_s2d_remat: the full-remat config the
-                        # session actually measures pairs with s2d
-])
-def test_sweep_configs_keep_bf16_convs(stem, remat):
-    hlo = _sweep_step_hlo(stem, remat)
-    if stem == "s2d":
-        # non-vacuous stem check: the s2d conv0 weight is (64, 12, 4, 4)
-        assert "x12x4x4x" in hlo.replace("bf16", "").replace("f32", ""), \
-            "s2d stem not present in lowered HLO"
+@pytest.mark.parametrize("remat", [
+    None,
+    "save_matmuls",
+    "1",        # full remat: the stem is then traced twice
+], ids=["s2d-None", "s2d-save_matmuls", "s2d-1"])
+def test_sweep_configs_keep_bf16_convs(remat):
+    """The default builder's stem reaches the lowered step as the folded
+    convolution ``Convolution`` makes of it (ops/nn.py): 12 input
+    channels, 4 x 4 taps, bf16 operands — and no convolution of the step
+    takes an fp32 operand, under each remat setting."""
+    hlo = _sweep_step_hlo(remat)
+    operands = [m.group(1) for m in re.finditer(
+        r"stablehlo\.convolution[^\n]*:\s*\(([^)]*)\)", hlo)]
+    # non-vacuous stem check: the forward convolves the folded input with
+    # the folded weight, and nothing in the step is a 7 x 7 over 3 channels
+    assert "tensor<2x12x35x35xbf16>, tensor<64x12x4x4xbf16>" in operands, \
+        "folded stem not present in lowered HLO"
+    assert not re.search(r"stablehlo\.convolution[^\n]*x3x7x7x", hlo)
     convs = _op_operand_dtypes(hlo, "convolution")
     assert convs, "no convolutions found in lowered step"
     for dts in convs:
-        assert all(d == "bf16" for d in dts), (stem, remat, dts)
+        assert all(d == "bf16" for d in dts), (remat, dts)

@@ -74,40 +74,103 @@ def test_unknown_network():
         models.get_symbol("nonexistent")
 
 
-def test_s2d_stem_equivalent_to_conv7():
-    """stem='s2d' (space-to-depth, MLPerf-TPU trick) computes the SAME
-    function as the reference 7x7/s2 stem once weights are mapped through
-    space_to_depth_stem_weight."""
-    from mxnet_tpu.models.resnet import space_to_depth_stem_weight
+def _plain_stem(monkeypatch):
+    """Make ``Convolution`` compute every convolution as written (what the
+    tree did before the op folded strided few-channel ones)."""
+    from mxnet_tpu.ops import nn
+    monkeypatch.setattr(nn, "_FOLD_MIN_TAPS", 1 << 30)
+
+
+def test_s2d_stem_equivalent_to_conv7(monkeypatch, conv_fold_instants):
+    """The default builder's 7x7/s2 stem runs through space-to-depth
+    inside ``Convolution`` (the MLPerf-TPU trick, now the op's own) and
+    computes the SAME function as the plain 7x7/s2 convolution from the
+    same ``(64, 3, 7, 7)`` weight; only ``conv0`` folds."""
+    from mxnet_tpu import tracing
     rs = np.random.RandomState(3)
     B = 2
     x = rs.uniform(-1, 1, (B, 3, 64, 64)).astype('f')
-    kw = dict(num_layers=18, num_classes=10, image_shape="3,64,64")
-    ref = models.resnet(stem="conv7", **kw)
-    s2d = models.resnet(stem="s2d", **kw)
+    net = models.resnet(num_layers=18, num_classes=10, image_shape="3,64,64")
 
-    ex1 = ref.simple_bind(mx.cpu(), data=x.shape, softmax_label=(B,),
-                          grad_req='null')
+    def bind():
+        ex = net.simple_bind(mx.cpu(), data=x.shape, softmax_label=(B,),
+                             grad_req='null')
+        ex.arg_dict['data'][:] = x
+        return ex
+
+    ex1 = bind()
+    assert ex1.arg_dict['conv0_weight'].shape == (64, 3, 7, 7)
     for name, arr in ex1.arg_dict.items():
-        if name in ('data', 'softmax_label'):
-            continue
-        arr[:] = rs.uniform(-0.05, 0.05, arr.shape).astype('f')
-    ex2 = s2d.simple_bind(mx.cpu(), data=x.shape, softmax_label=(B,),
-                          grad_req='null')
-    for name, arr in ex2.arg_dict.items():
-        if name in ('data', 'softmax_label'):
-            continue
-        if name == 'conv0_weight':
-            arr[:] = space_to_depth_stem_weight(
-                ex1.arg_dict['conv0_weight'].asnumpy())
-        else:
-            arr[:] = ex1.arg_dict[name].asnumpy()
-
-    ex1.arg_dict['data'][:] = x
-    ex2.arg_dict['data'][:] = x
+        if name not in ('data', 'softmax_label'):
+            arr[:] = rs.uniform(-0.05, 0.05, arr.shape).astype('f')
     o1 = ex1.forward(is_train=False)[0].asnumpy()
+    said = conv_fold_instants(nodes_only=True)
+    assert said and all(
+        a == {"node": "conv0", "c_in": 3, "kernel": 7, "stride": 2,
+              "folded_channels": 12, "folded_kernel": 4, "layout": "NCHW"}
+        for a in said), said
+
+    _plain_stem(monkeypatch)
+    tracing.reset()
+    ex2 = bind()
+    for name, arr in ex2.arg_dict.items():
+        if name not in ('data', 'softmax_label'):
+            arr[:] = ex1.arg_dict[name].asnumpy()
     o2 = ex2.forward(is_train=False)[0].asnumpy()
+    assert conv_fold_instants(nodes_only=True) == []
     np.testing.assert_allclose(o1, o2, rtol=1e-4, atol=1e-5)
+
+
+def test_checkpoint_of_plain_stem_loads_into_folded(monkeypatch, tmp_path,
+                                                    conv_fold_instants):
+    """A checkpoint written by a tree whose stem was a plain 7x7/s2
+    convolution loads as it is: ``conv0_weight`` is (64, 3, 7, 7) in
+    ``get_params()`` and on disk on both sides, and the first training
+    step gives the same loss to float32 rounding."""
+    rs = np.random.RandomState(11)
+    B = 4
+    x = rs.uniform(-1, 1, (B, 3, 64, 64)).astype('f')
+    y = rs.randint(0, 10, (B,)).astype('f')
+    net = models.resnet(num_layers=18, num_classes=10, image_shape="3,64,64")
+    batch = mx.io.DataBatch(data=[mx.nd.array(x)], label=[mx.nd.array(y)])
+
+    def first_step(arg_params=None, aux_params=None):
+        mod = mx.mod.Module(net)
+        mod.bind(data_shapes=[("data", x.shape)],
+                 label_shapes=[("softmax_label", y.shape)])
+        mx.random.seed(5)
+        mod.init_params(mx.initializer.Xavier(), arg_params=arg_params,
+                        aux_params=aux_params)
+        mod.init_optimizer(optimizer="sgd",
+                           optimizer_params={"learning_rate": 0.1,
+                                             "momentum": 0.9})
+        before = {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+        mod.forward(batch, is_train=True)
+        probs = mod.get_outputs()[0].asnumpy()
+        mod.update()
+        loss = -np.log(probs[np.arange(B), y.astype(int)]).mean()
+        return mod, before, loss
+
+    with monkeypatch.context() as m:
+        _plain_stem(m)
+        parent, p0, loss_parent = first_step()
+        assert conv_fold_instants(nodes_only=True) == []
+        prefix = str(tmp_path / "parent")
+        # the parameters the step started from, as the parent would save them
+        mx.model.save_checkpoint(prefix, 0, net,
+                                 {k: mx.nd.array(v) for k, v in p0.items()},
+                                 parent.get_params()[1])
+    _, args, auxs = mx.model.load_checkpoint(prefix, 0)
+    assert args["conv0_weight"].shape == (64, 3, 7, 7)
+    # aux states moved in the parent's step; its loss did not depend on them
+    change, c0, loss_change = first_step(args, None)
+    assert any(a["node"] == "conv0" for a in conv_fold_instants())
+    assert change.get_params()[0]["conv0_weight"].shape == (64, 3, 7, 7)
+    np.testing.assert_array_equal(c0["conv0_weight"], p0["conv0_weight"])
+    np.testing.assert_allclose(loss_change, loss_parent, rtol=1e-5)
+    change.save_checkpoint(str(tmp_path / "change"), 1)
+    _, args2, _ = mx.model.load_checkpoint(str(tmp_path / "change"), 1)
+    assert args2["conv0_weight"].shape == (64, 3, 7, 7)
 
 
 def test_vit_trains_and_gqa():
@@ -139,46 +202,51 @@ def test_vit_trains_and_gqa():
     assert acc > 0.7, acc
 
 
-def test_nhwc_layout_matches_nchw():
+@pytest.mark.parametrize("stem", ["conv7", "s2d"])
+def test_nhwc_layout_matches_nchw(stem, monkeypatch, conv_fold_instants):
     """layout='NHWC' (channels-last activation path, MLPerf-TPU
     convention) computes the SAME function and gradients as the default
-    NCHW graph from identical (layout-agnostic OIHW) weights — both
-    stems, forward and backward."""
+    NCHW graph from identical (layout-agnostic OIHW) weights, forward and
+    backward — with the stem folded by ``Convolution`` in both layouts
+    (``s2d``, the default) and with it computed as written (``conv7``)."""
     rs = np.random.RandomState(7)
     B = 2
     x = rs.uniform(-1, 1, (B, 3, 64, 64)).astype('f')
     y = rs.randint(0, 10, (B,)).astype('f')
-    for stem in ("conv7", "s2d"):
-        kw = dict(num_layers=18, num_classes=10, image_shape="3,64,64",
-                  stem=stem)
-        nchw = models.resnet(layout="NCHW", **kw)
-        nhwc = models.resnet(layout="NHWC", **kw)
-        ex1 = nchw.simple_bind(mx.cpu(), data=x.shape, softmax_label=(B,),
-                               grad_req='write')
-        for name, arr in ex1.arg_dict.items():
-            if name in ('data', 'softmax_label'):
-                continue
-            arr[:] = rs.uniform(-0.05, 0.05, arr.shape).astype('f')
-        ex2 = nhwc.simple_bind(mx.cpu(), data=x.shape, softmax_label=(B,),
-                               grad_req='write')
-        for name, arr in ex2.arg_dict.items():
-            if name in ('data', 'softmax_label'):
-                continue
-            assert arr.shape == ex1.arg_dict[name].shape, name
-            arr[:] = ex1.arg_dict[name].asnumpy()
-        for ex in (ex1, ex2):
-            ex.arg_dict['data'][:] = x
-            ex.arg_dict['softmax_label'][:] = y
-        o1 = ex1.forward(is_train=True)[0].asnumpy()
-        o2 = ex2.forward(is_train=True)[0].asnumpy()
-        np.testing.assert_allclose(o1, o2, rtol=1e-4, atol=1e-5)
-        ex1.backward()
-        ex2.backward()
-        for name in ex1.grad_dict:
-            if name in ('data', 'softmax_label'):
-                continue
-            g1 = ex1.grad_dict[name].asnumpy()
-            g2 = ex2.grad_dict[name].asnumpy()
-            np.testing.assert_allclose(
-                g1, g2, rtol=2e-3, atol=2e-5,
-                err_msg=f"{stem} grad mismatch for {name}")
+    if stem == "conv7":
+        _plain_stem(monkeypatch)
+    kw = dict(num_layers=18, num_classes=10, image_shape="3,64,64")
+    nchw = models.resnet(layout="NCHW", **kw)
+    nhwc = models.resnet(layout="NHWC", **kw)
+    ex1 = nchw.simple_bind(mx.cpu(), data=x.shape, softmax_label=(B,),
+                           grad_req='write')
+    for name, arr in ex1.arg_dict.items():
+        if name in ('data', 'softmax_label'):
+            continue
+        arr[:] = rs.uniform(-0.05, 0.05, arr.shape).astype('f')
+    ex2 = nhwc.simple_bind(mx.cpu(), data=x.shape, softmax_label=(B,),
+                           grad_req='write')
+    for name, arr in ex2.arg_dict.items():
+        if name in ('data', 'softmax_label'):
+            continue
+        assert arr.shape == ex1.arg_dict[name].shape, name
+        arr[:] = ex1.arg_dict[name].asnumpy()
+    for ex in (ex1, ex2):
+        ex.arg_dict['data'][:] = x
+        ex.arg_dict['softmax_label'][:] = y
+    o1 = ex1.forward(is_train=True)[0].asnumpy()
+    o2 = ex2.forward(is_train=True)[0].asnumpy()
+    np.testing.assert_allclose(o1, o2, rtol=1e-4, atol=1e-5)
+    ex1.backward()
+    ex2.backward()
+    for name in ex1.grad_dict:
+        if name in ('data', 'softmax_label'):
+            continue
+        g1 = ex1.grad_dict[name].asnumpy()
+        g2 = ex2.grad_dict[name].asnumpy()
+        np.testing.assert_allclose(
+            g1, g2, rtol=2e-3, atol=2e-5,
+            err_msg=f"{stem} grad mismatch for {name}")
+    said = conv_fold_instants(nodes_only=True)
+    assert sorted({a["layout"] for a in said}) == \
+        ([] if stem == "conv7" else ["NCHW", "NHWC"])

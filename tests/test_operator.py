@@ -628,6 +628,136 @@ def test_convolution_grad():
                            numeric_eps=1e-2, rtol=5e-2, atol=2e-2)
 
 
+def _plain_conv(x, w, stride, pad, layout, dilate=1, groups=1):
+    """The convolution as written, on jax arrays: the reference the
+    folded path is held to."""
+    import jax
+    rank = x.ndim - 2
+    dn = {1: ("NCH", "OIH", "NCH"), 3: ("NCDHW", "OIDHW", "NCDHW"),
+          2: ("NHWC", "OIHW", "NHWC") if layout == "NHWC"
+          else ("NCHW", "OIHW", "NCHW")}[rank]
+    return jax.lax.conv_general_dilated(
+        x, w, (stride,) * rank, ((pad, pad),) * rank,
+        rhs_dilation=(dilate,) * rank, dimension_numbers=dn,
+        feature_group_count=groups)
+
+
+def _conv_and_grads(fn, x, w, ct):
+    """Output, data gradient and weight gradient of ``fn`` in float32."""
+    import jax
+    import jax.numpy as jnp
+    y, vjp = jax.vjp(fn, x, w)
+    return [np.asarray(a.astype(jnp.float32))
+            for a in (y,) + tuple(vjp(ct.astype(y.dtype)))]
+
+
+# kernel, stride, pad, C_in, H, W, num_filter: the engaged rungs of the
+# ladder in PERF.md (PR 33) at small batch, and the corners of the padding
+# arithmetic
+_FOLDED = [
+    (7, 2, 3, 3, 224, 224, 4),      # the ResNet / DenseNet / Inception-BN stem
+    (7, 2, 3, 3, 64, 64, 8),        # the same in chipbench's tiny cells
+    (11, 4, 2, 3, 227, 227, 4),     # AlexNet: 48 channels, 3 x 3 taps
+    (7, 2, 3, 3, 65, 47, 4),        # odd sizes: the padding after is a crop
+    (7, 2, 0, 1, 33, 34, 4),        # one plane, pad 0, H != W
+    (7, 2, 3, 4, 40, 40, 4),        # the rule's edge: 49 >= 12 * 4
+    (5, 2, 2, 1, 33, 32, 4),        # three taps a stride, one plane
+    (7, 3, 4, 2, 20, 31, 4),        # stride 3, pad over the kernel's half
+]
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-4), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("layout", ["NCHW", "NHWC"])
+@pytest.mark.parametrize("bias", [False, True], ids=["nobias", "bias"])
+@pytest.mark.parametrize("k,s,p,c,h,w_,o", _FOLDED,
+                         ids=["k%ds%dp%dc%d_%dx%d" % t[:6] for t in _FOLDED])
+def test_convolution_folds_stride_into_channels(conv_fold_instants, k, s, p,
+                                                c, h, w_, o, bias, layout,
+                                                dtype, tol):
+    """A strided convolution over few channels runs as a stride-1
+    convolution of space-to-depth foldings (ops/nn.py) and gives the plain
+    convolution's output, data gradient and weight gradient; the weight
+    and its gradient keep (O, C, k, k)."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.nn import _convolution
+    _EXERCISED.add('Convolution')
+    rs = np.random.RandomState(k * 100 + h)
+    x = rs.uniform(-1, 1, (2, c, h, w_)).astype(np.float32)
+    if layout == "NHWC":
+        x = x.transpose(0, 2, 3, 1)
+    w = (rs.uniform(-1, 1, (o, c, k, k)) * (c * k * k) ** -0.5) \
+        .astype(np.float32)
+    b = rs.uniform(-1, 1, (o,)).astype(np.float32) if bias else None
+
+    def folded(x, w):
+        return _convolution(x, w, None if b is None else jnp.asarray(b, x.dtype),
+                            kernel=(k, k), stride=(s, s), pad=(p, p),
+                            num_filter=o, no_bias=not bias, layout=layout)
+
+    def plain(x, w):
+        y = _plain_conv(x, w, s, p, layout)
+        if b is None:
+            return y
+        return y + (b if layout == "NHWC" else b.reshape(1, -1, 1, 1))
+
+    ct = rs.uniform(-1, 1, plain(jnp.asarray(x), jnp.asarray(w)).shape) \
+        .astype(np.float32)
+    want = _conv_and_grads(plain, jnp.asarray(x), jnp.asarray(w),
+                           jnp.asarray(ct))
+    cast = jnp.dtype(dtype)
+    got = _conv_and_grads(folded, jnp.asarray(x, cast), jnp.asarray(w, cast),
+                          jnp.asarray(ct))
+    assert got[2].shape == (o, c, k, k)
+    for name, a, e in zip(("out", "dx", "dw"), got, want):
+        assert a.shape == e.shape, name
+        assert np.abs(a - e).max() <= tol * max(1.0, np.abs(e).max()), name
+    said = conv_fold_instants()
+    assert said and all(a == {
+        "node": "", "c_in": c, "kernel": k, "stride": s,
+        "folded_channels": c * s * s, "folded_kernel": -(-k // s),
+        "layout": layout} for a in said), said
+
+
+# what the rule must leave alone: (data shape, weight shape, attrs)
+_NOT_FOLDED = {
+    "c64_stride2": ((2, 64, 16, 16), (8, 64, 3, 3), dict(stride=2, pad=1)),
+    # the ladder's rungs that are level or slower folded
+    "mobilenet_3x3_s2": ((2, 3, 32, 32), (8, 3, 3, 3), dict(stride=2, pad=1)),
+    "dcgan_4x4_s2": ((2, 3, 32, 32), (8, 3, 4, 4), dict(stride=2, pad=1)),
+    "k5_c3": ((2, 3, 32, 32), (8, 3, 5, 5), dict(stride=2, pad=2)),
+    "k7_c8": ((2, 8, 32, 32), (8, 8, 7, 7), dict(stride=2, pad=3)),
+    "over_128_channels": ((1, 3, 64, 64), (2, 3, 33, 33), dict(stride=8)),
+    "stride1": ((2, 3, 16, 16), (8, 3, 3, 3), dict(stride=1, pad=1)),
+    "groups2": ((2, 4, 16, 16), (8, 2, 3, 3),
+                dict(stride=2, pad=1, groups=2)),
+    "dilate2": ((2, 3, 16, 16), (8, 3, 3, 3), dict(stride=2, pad=2, dilate=2)),
+    "vit_patch_k_eq_s": ((2, 3, 32, 32), (8, 3, 16, 16), dict(stride=16)),
+    "shortcut_k_lt_s": ((2, 4, 16, 16), (8, 4, 1, 1), dict(stride=2)),
+    "rank1": ((2, 3, 33), (8, 3, 5), dict(stride=2, pad=2)),
+    "rank3": ((2, 3, 9, 9, 9), (4, 3, 3, 3, 3), dict(stride=2, pad=1)),
+    "input_under_kernel": ((2, 3, 5, 5), (4, 3, 7, 7), dict(stride=2)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_NOT_FOLDED))
+def test_convolution_not_folded(conv_fold_instants, case):
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.nn import _convolution
+    xs, ws, at = _NOT_FOLDED[case]
+    rank = len(xs) - 2
+    rs = np.random.RandomState(len(case))
+    x = jnp.asarray(rs.uniform(-1, 1, xs).astype(np.float32))
+    w = jnp.asarray(rs.uniform(-1, 1, ws).astype(np.float32))
+    stride, pad = at.get("stride", 1), at.get("pad", 0)
+    dilate, groups = at.get("dilate", 1), at.get("groups", 1)
+    got = _convolution(x, w, None, kernel=ws[2:], stride=(stride,) * rank,
+                       pad=(pad,) * rank, dilate=(dilate,) * rank,
+                       num_group=groups, num_filter=ws[0], no_bias=True)
+    want = _plain_conv(x, w, stride, pad, None, dilate, groups)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert conv_fold_instants() == []
+
+
 def test_deconvolution_vs_torch():
     import torch
     import torch.nn.functional as F
