@@ -43,7 +43,7 @@ AMP_FP32_OPS = frozenset({
     "SoftmaxOutput", "SoftmaxActivation", "softmax", "log_softmax",
     "log_softmax_mx", "LinearRegressionOutput", "LogisticRegressionOutput",
     "MAERegressionOutput", "MakeLoss", "SVMOutput", "CTCLoss",
-    "softmax_cross_entropy",
+    "softmax_cross_entropy", "_contrib_ExpectedExitLoss",
 })
 
 # Ops with a SPLIT precision contract: the listed input indices are cast to
@@ -53,7 +53,82 @@ AMP_FP32_OPS = frozenset({
 # (N,C,H,W) activation never round-trips HBM in fp32 — the TPU equivalent of
 # the reference's fused cuDNN BN (cudnn_batch_norm-inl.h keeps fp32 stats
 # over an fp16 data path).
-AMP_SPLIT_OPS = {"BatchNorm": (0,)}
+AMP_SPLIT_OPS = {"BatchNorm": (0,), "RMSNorm": (0,)}
+
+# Inputs that are not activations, by the role of the argument: an op's
+# label or index input holds whole numbers (MXNet iterators give labels as
+# float32), and bfloat16 keeps eight bits of them: id 49151 would become
+# 49152 and 257 would become 256.  They are never cast, and neither is a
+# value on its way to one through ops that only move elements around
+# (AMP_MOVES_ONLY).  Keyed by the op's registered name.
+AMP_INDEX_INPUTS = {
+    "Embedding": (0,), "take": (1,), "batch_take": (1,), "one_hot": (0,),
+    "gather_nd": (1,), "scatter_nd": (1,), "_scatter_set_nd": (2,),
+    "_contrib_ChunkedLMLoss": (3,), "SoftmaxOutput": (1,),
+    "softmax_cross_entropy": (1,),
+}
+AMP_MOVES_ONLY = frozenset({
+    "Reshape", "Flatten", "transpose", "expand_dims", "slice_axis", "slice",
+    "squeeze", "SwapAxis", "tile", "repeat", "Concat", "BlockGrad", "_copy",
+    "broadcast_to", "broadcast_axis",
+})
+
+
+def _amp_uncast_inputs(nodes):
+    """{(id(node), input position)} of the inputs mixed precision leaves in
+    their own dtype: index and label inputs with what only feeds them
+    (above), and the inputs of every node no Variable reaches: a table of
+    constants (rotary angles, a position range, a mask) is computed in the
+    dtype it was declared in and rounded once, where an activation meets
+    it."""
+    consumers = {}
+    for n in nodes:
+        for pos, (src, i) in enumerate(n.inputs):
+            consumers.setdefault((id(src), i), []).append((id(n), pos))
+    uncast = set()
+    for n in reversed(nodes):
+        if n.is_variable:
+            continue
+        name = _reg.get(n.op).name
+        roles = AMP_INDEX_INPUTS.get(name, ())
+        if name in AMP_MOVES_ONLY:
+            read = [c for i in range(node_num_outputs(n))
+                    for c in consumers.get((id(n), i), ())]
+            if read and all(c in uncast for c in read):
+                roles = range(len(n.inputs))
+        uncast.update((id(n), pos) for pos in roles)
+    constant = set()
+    for n in nodes:
+        if n.is_variable or _reg.get(n.op).needs_rng:
+            continue
+        if all(id(src) in constant for src, _ in n.inputs):
+            constant.add(id(n))
+            uncast.update((id(n), pos) for pos in range(len(n.inputs)))
+    return frozenset(uncast)
+
+
+def body_interpreter(subgraph, compute_dtype, node):
+    """The interpreter of the sub-Symbol a loop node holds
+    (ops/control_flow.py), or an MXNetError naming what a loop cannot
+    carry: auxiliary states and randomness."""
+    run, _, aux_names = build_interpreter(subgraph, compute_dtype)
+    if aux_names:
+        owners = sorted({f"{n.name} ({n.op})" for n in subgraph.nodes()
+                         if not n.is_variable and any(
+                             src.name in aux_names for src, _ in n.inputs)})
+        raise MXNetError(
+            f"loop node {node!r}: its body holds auxiliary states "
+            f"{aux_names} of {owners}; a state a node updates in place has "
+            f"no meaning across the iterations of one step, so a loop's "
+            f"body may not have one (ops/control_flow.py)")
+    if run.needs_rng:
+        rng = sorted(f"{n.name} ({n.op})" for n in subgraph.nodes()
+                     if not n.is_variable and _reg.get(n.op).needs_rng)
+        raise MXNetError(
+            f"loop node {node!r}: its body holds the RNG ops {rng}; every "
+            f"iteration would draw the same numbers from the node's one "
+            f"key, so a loop's body may not have one (ops/control_flow.py)")
+    return run
 
 
 def maybe_mirror(run):
@@ -113,21 +188,30 @@ def build_interpreter(sym: Symbol, compute_dtype=None):
                if not n.is_variable and _reg.get(n.op).needs_rng]
     rng_index = {nid: i for i, nid in enumerate(rng_ids)}
     cd = jnp.dtype(compute_dtype) if compute_dtype is not None else None
+    uncast = _amp_uncast_inputs(nodes) if cd is not None else frozenset()
+    # a loop node's body: its own interpreter, at the same compute dtype,
+    # built (and refused, where it cannot be a loop's) as the graph is bound
+    bodies = {}
+    for n in nodes:
+        sub = None if n.is_variable else _reg.get(n.op).subgraph_attr
+        if sub:
+            bodies[id(n)] = body_interpreter(n.attrs[sub], compute_dtype,
+                                             n.name)
 
-    def _amp_cast(ins, op):
-        split = AMP_SPLIT_OPS.get(op)
-        if split is not None:
-            return [v.astype(cd)
-                    if (i in split and hasattr(v, "dtype")
-                        and jnp.issubdtype(v.dtype, jnp.floating)
-                        and v.dtype != cd) else v
-                    for i, v in enumerate(ins)]
-        want = jnp.float32 if op in AMP_FP32_OPS else cd
+    def _amp_cast(ins, n):
+        if id(n) in bodies:
+            # the body's nodes cast what they read, use by use: a weight
+            # enters the loop in its master precision, and its gradient is
+            # summed over the iterations in it
+            return ins
+        split = AMP_SPLIT_OPS.get(n.op)
+        want = jnp.float32 if n.op in AMP_FP32_OPS else cd
         return [v.astype(want)
-                if (hasattr(v, "dtype")
+                if ((split is None or i in split)
+                    and (id(n), i) not in uncast and hasattr(v, "dtype")
                     and jnp.issubdtype(v.dtype, jnp.floating)
                     and v.dtype != want) else v
-                for v in ins]
+                for i, v in enumerate(ins)]
 
     def run(arg_vals, aux_vals, key, is_train, _collect=None):
         env = {}
@@ -148,11 +232,13 @@ def build_interpreter(sym: Symbol, compute_dtype=None):
             kwargs.pop("name", None)
             if opdef.takes_is_train:
                 kwargs["is_train"] = is_train
+            if id(n) in bodies:
+                kwargs["_interpret"] = bodies[id(n)]
             # a location, not an operation: the node's name reaches each
             # device event's op_name as jvp(<node>) / transpose(jvp(<node>))
             with jax.named_scope(n.name):
                 if cd is not None:
-                    ins = _amp_cast(ins, n.op)
+                    ins = _amp_cast(ins, n)
                 if opdef.needs_rng:
                     outs = opdef.fn(keys[rng_index[id(n)]], *ins, **kwargs)
                 else:

@@ -36,7 +36,8 @@ from .mobilenet import get_symbol as mobilenet
 from .squeezenet import get_symbol as squeezenet
 from .ssd import ssd_vgg16, ssd_toy
 from . import ssd as _ssd
-from .transformer import transformer_lm, transformer_decode_step
+from .transformer import (transformer_lm, transformer_decode_step,
+                          looped_transformer_lm)
 from .generation import beam_search
 from . import vit as _vit  # module ref BEFORE the function shadows the name
 from .vit import vit

@@ -37,8 +37,28 @@ def _rope_apply(t, cos, sin, hd):
         sym.broadcast_mul(t2, cos) + sym.broadcast_mul(t1, sin), dim=3)
 
 
+def _rope_tables(seq_len, hd, base):
+    """(cos, sin) of ONE angle table shared by every layer (the decode
+    graph does the same): (1, 1, S, hd/2).  Nodes no Variable reaches:
+    under mixed precision they stay float32 (executor._amp_uncast_inputs)."""
+    if hd % 2:
+        raise ValueError(f"rope needs even head_dim, got {hd}")
+    ang = sym.broadcast_mul(
+        sym.Reshape(sym.arange(start=0, stop=seq_len),
+                    shape=(1, 1, seq_len, 1)),
+        sym.Reshape(_rope_inv_freq(hd, base), shape=(1, 1, 1, hd // 2)))
+    return sym.cos(ang), sym.sin(ang)
+
+
+def _dense(x, num_hidden, name, no_bias):
+    # GPT-2's nodes carry no `no_bias` attr: their graph stays as it was
+    kw = {"no_bias": True} if no_bias else {}
+    return sym.FullyConnected(x, num_hidden=num_hidden, name=name, **kw)
+
+
 def _attention_block(x, seq_len, d_model, num_heads, name,
-                     num_kv_heads=None, causal=True, rope_cs=None):
+                     num_kv_heads=None, causal=True, rope_cs=None,
+                     no_bias=False):
     """x: (B, S, d) → (B, S, d) flash attention + projection (causal by
     default — the LM; causal=False gives the bidirectional encoder form
     ViT uses).
@@ -56,8 +76,7 @@ def _attention_block(x, seq_len, d_model, num_heads, name,
             f"d_model {d_model} not divisible by num_heads {h}")
     hd = d_model // h
     flat = sym.Reshape(x, shape=(-1, d_model))
-    qkv = sym.FullyConnected(flat, num_hidden=(h + 2 * hk) * hd,
-                             name=f"{name}_qkv")
+    qkv = _dense(flat, (h + 2 * hk) * hd, f"{name}_qkv", no_bias)
     q = sym.slice_axis(qkv, axis=1, begin=0, end=h * hd)
     k = sym.slice_axis(qkv, axis=1, begin=h * hd, end=(h + hk) * hd)
     v = sym.slice_axis(qkv, axis=1, begin=(h + hk) * hd,
@@ -77,13 +96,12 @@ def _attention_block(x, seq_len, d_model, num_heads, name,
                                       name=f"{name}_flash")
     attn = sym.transpose(attn, axes=(0, 2, 1, 3))     # (B, S, H, hd)
     attn = sym.Reshape(attn, shape=(-1, d_model))
-    out = sym.FullyConnected(attn, num_hidden=d_model,
-                             name=f"{name}_proj")
+    out = _dense(attn, d_model, f"{name}_proj", no_bias)
     return sym.Reshape(out, shape=(-1, seq_len, d_model))
 
 
 def _ffn_block(x, seq_len, d_model, d_ff, name, moe_experts=0, moe_k=1,
-               ffn_type="gelu"):
+               ffn_type="gelu", no_bias=False):
     flat = sym.Reshape(x, shape=(-1, d_model))
     if ffn_type == "swiglu" and moe_experts:
         raise ValueError(
@@ -92,16 +110,17 @@ def _ffn_block(x, seq_len, d_model, d_ff, name, moe_experts=0, moe_k=1,
     if ffn_type == "swiglu":
         # SwiGLU (Shazeer 2020): silu(xW1) * xW3 -> W2.  One fused
         # projection emits both halves so the MXU sees a single matmul.
-        both = sym.FullyConnected(flat, num_hidden=2 * d_ff,
-                                  name=f"{name}_fc1")   # [gate | lin]
+        both = _dense(flat, 2 * d_ff, f"{name}_fc1", no_bias)  # [gate | lin]
         gate = sym.slice_axis(both, axis=1, begin=0, end=d_ff)
         lin = sym.slice_axis(both, axis=1, begin=d_ff, end=None)
         hdn = gate * sym.sigmoid(gate) * lin
-        out = sym.FullyConnected(hdn, num_hidden=d_model,
-                                 name=f"{name}_fc2")
+        out = _dense(hdn, d_model, f"{name}_fc2", no_bias)
         return sym.Reshape(out, shape=(-1, seq_len, d_model))
     if ffn_type not in ("gelu", "swiglu"):
         raise ValueError(f"ffn_type must be gelu|swiglu, got {ffn_type!r}")
+    if moe_experts and no_bias:
+        raise ValueError("no_bias with moe_experts>0 is not supported — "
+                         "the MoE expert FFN has biases")
     if moe_experts:
         gate = sym.Variable(f"{name}_gate_weight",
                             shape=(d_model, moe_experts))
@@ -116,11 +135,9 @@ def _ffn_block(x, seq_len, d_model, d_ff, name, moe_experts=0, moe_k=1,
                               num_experts=moe_experts, k=moe_k,
                               activation="gelu", name=f"{name}_moe")
     else:
-        hdn = sym.FullyConnected(flat, num_hidden=d_ff,
-                                 name=f"{name}_fc1")
+        hdn = _dense(flat, d_ff, f"{name}_fc1", no_bias)
         hdn = hdn * sym.sigmoid(hdn * 1.702)   # gelu (sigmoid approx)
-        out = sym.FullyConnected(hdn, num_hidden=d_model,
-                                 name=f"{name}_fc2")
+        out = _dense(hdn, d_model, f"{name}_fc2", no_bias)
     return sym.Reshape(out, shape=(-1, seq_len, d_model))
 
 
@@ -165,17 +182,7 @@ def transformer_lm(vocab_size, seq_len, num_layers=2, d_model=128,
         x = sym.broadcast_add(x, sym.expand_dims(pos, axis=0))
     rope_cs = None
     if pos_type == "rope":
-        hd_ = d_model // num_heads
-        if hd_ % 2:
-            raise ValueError(f"rope needs even head_dim, got {hd_}")
-        # ONE angle table shared by every layer (the decode graph does
-        # the same): (1, 1, S, hd/2)
-        ang = sym.broadcast_mul(
-            sym.Reshape(sym.arange(start=0, stop=seq_len),
-                        shape=(1, 1, seq_len, 1)),
-            sym.Reshape(_rope_inv_freq(hd_, rope_base),
-                        shape=(1, 1, 1, hd_ // 2)))
-        rope_cs = (sym.cos(ang), sym.sin(ang))
+        rope_cs = _rope_tables(seq_len, d_model // num_heads, rope_base)
     for i in range(num_layers):
         name = f"layer{i}"
         a = _attention_block(sym.LayerNorm(x, name=f"{name}_ln1"),
@@ -206,6 +213,87 @@ def transformer_lm(vocab_size, seq_len, num_layers=2, d_model=128,
     logits = sym.FullyConnected(hidden, num_hidden=vocab_size,
                                 name="lm_head")
     return sym.SoftmaxOutput(data=logits, label=label, name="softmax")
+
+
+def _sandwich_layer(x, seq_len, d_model, num_heads, d_ff, name,
+                    num_kv_heads=None, rope_cs=None, norm_eps=1e-6):
+    """One bias-free decoder layer with a norm before AND after each
+    sub-block, four gains a layer: ``a = x + N2(Attn(N1(x)))``,
+    ``y = a + N4(FFN(N3(a)))``, N = RMSNorm, FFN = SwiGLU."""
+    def norm(t, which):
+        return sym.RMSNorm(t, eps=norm_eps, name=f"{name}_{which}")
+
+    a = _attention_block(norm(x, "ln1"), seq_len, d_model, num_heads, name,
+                         num_kv_heads=num_kv_heads, rope_cs=rope_cs,
+                         no_bias=True)
+    x = x + norm(a, "ln1_post")
+    f = _ffn_block(norm(x, "ln2"), seq_len, d_model, d_ff, name,
+                   ffn_type="swiglu", no_bias=True)
+    return x + norm(f, "ln2_post")
+
+
+def looped_transformer_lm(vocab_size, seq_len, num_layers=2, d_model=128,
+                          num_heads=4, num_kv_heads=None, d_ff=None,
+                          loop_steps=4, rope_base=1e6, norm_eps=1e-6,
+                          exit_beta=0.05, ce_chunks=8):
+    """Looped causal LM train symbol (Ouro; Zhu et al. 2025, "Scaling
+    Latent Reasoning via Looped Language Models"): the whole stack of
+    ``num_layers`` sandwich-normed, bias-free RoPE / SwiGLU layers and the
+    final RMSNorm is run ``loop_steps`` times over its own output with the
+    SAME weights -- one loop node (``sym.contrib.foreach``) whose body is
+    traced once and rematerialised one loop step at a time in the backward
+    pass (``remat=True``: sixteen layer applications' activations at 4096
+    tokens do not fit a chip beside the parameters).  An untied head reads every loop step's
+    state, an exit gate ``sigmoid(h . w + b)`` gives each token a
+    distribution over the step to stop at, and the objective is the
+    expected cross-entropy under it plus ``exit_beta`` times its negative
+    entropy (``_contrib_ExpectedExitLoss``), per token.
+
+    data (B, S) token ids, softmax_label (B, S) next-token ids.  Outputs:
+    [0] ``softmax`` of the last loop step's logits, (B*S, V),
+    gradient-blocked: what is served with the exit threshold at 1 and what
+    a metric reads; [1] the per-token objective (B*S,) as a ``MakeLoss``
+    head (Module's ``rescale_grad`` = 1/batch makes the step that of the
+    token sum averaged over the batch, as ``transformer_lm``'s).  The
+    per-step cross-entropies go through ``chunked_lm_loss``: no step's
+    (B*S, V) logits are kept for the backward pass."""
+    d_ff = d_ff or 4 * d_model
+    if int(loop_steps) < 1:
+        raise ValueError(f"loop_steps must be >= 1, got {loop_steps}")
+    steps = int(loop_steps)
+    data = sym.Variable("data")
+    x = sym.Embedding(data, input_dim=vocab_size, output_dim=d_model,
+                      name="tok_embed")
+
+    def stack(_, h):
+        # inside the body: a body may use no computed Symbol from outside
+        rope_cs = _rope_tables(seq_len, d_model // num_heads, rope_base)
+        for i in range(num_layers):
+            h = _sandwich_layer(h, seq_len, d_model, num_heads, d_ff,
+                                f"layer{i}", num_kv_heads=num_kv_heads,
+                                rope_cs=rope_cs, norm_eps=norm_eps)
+        h = sym.RMSNorm(h, eps=norm_eps, name="final_norm")
+        return h, h     # every step's state is read, and feeds the next
+
+    states, _ = sym.contrib.foreach(stack, None, x, num_iter=steps,
+                                    remat=True, name="loop")   # (T,B,S,d)
+    flat = sym.Reshape(states, shape=(-1, d_model))           # step-major
+    label = sym.Reshape(sym.Variable("softmax_label"), shape=(-1,))
+    head = sym.Variable("lm_head_weight", shape=(vocab_size, d_model))
+    ce = sym.chunked_lm_loss(flat, head, sym.zeros((vocab_size,)),
+                             sym.tile(label, reps=(steps,)),
+                             num_chunks=ce_chunks, name="step_ce")
+    gate = sym.FullyConnected(flat, num_hidden=1, name="exit_gate")
+    objective = sym.contrib.ExpectedExitLoss(gate, ce, steps=steps,
+                                             beta=exit_beta,
+                                             name="exit_loss")[0]
+    last = sym.BlockGrad(sym.Reshape(
+        sym.slice_axis(states, axis=0, begin=steps - 1, end=steps),
+        shape=(-1, d_model)))
+    logits = sym.FullyConnected(last, weight=head, num_hidden=vocab_size,
+                                no_bias=True, name="lm_head")
+    probs = sym.BlockGrad(sym.softmax(logits, axis=-1), name="softmax")
+    return sym.Group([probs, sym.MakeLoss(objective, name="objective")])
 
 
 def get_symbol(vocab_size=1000, seq_len=128, **kwargs):

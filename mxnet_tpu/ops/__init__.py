@@ -26,3 +26,4 @@ from . import moe           # noqa: F401
 from . import spatial       # noqa: F401
 from . import contrib_ops   # noqa: F401
 from . import chunked_loss  # noqa: F401
+from . import control_flow  # noqa: F401

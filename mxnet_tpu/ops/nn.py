@@ -402,6 +402,22 @@ def _layer_norm(data, gamma, beta, axis=-1, eps=1e-5, output_mean_var=False, **k
     return out, jnp.squeeze(mean, ax), jnp.squeeze(var, ax)
 
 
+@register("RMSNorm", arg_names=["data", "gamma"],
+          attr_defaults={"axis": -1, "eps": 1e-6})
+def _rms_norm(data, gamma, axis=-1, eps=1e-6, **kw):
+    """Root-mean-square norm (Zhang & Sennrich 2019; the norm of the Llama
+    line and of Ouro): ``data / sqrt(mean(data^2) + eps) * gamma`` along
+    ``axis``, no centring and no offset.  The statistics and the scaling
+    are taken in float32 whatever the data's dtype, and the result is cast
+    back to it; under mixed precision only the data is cast
+    (executor.AMP_SPLIT_OPS), the gain keeps its master precision."""
+    ax = axis % data.ndim
+    x = data.astype(jnp.promote_types(data.dtype, jnp.float32))
+    y = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=ax, keepdims=True) + eps)
+    bshape = tuple(data.shape[ax] if i == ax else 1 for i in range(data.ndim))
+    return (y * gamma.astype(x.dtype).reshape(bshape)).astype(data.dtype)
+
+
 @register("LRN", arg_names=["data"],
           attr_defaults={"alpha": 1e-4, "beta": 0.75, "knorm": 2.0, "nsize": 5})
 def _lrn(data, alpha=1e-4, beta=0.75, knorm=2.0, nsize=5, **kw):
@@ -700,6 +716,33 @@ def _makeloss(data, grad_scale=1.0, valid_thresh=0.0, normalization="null", **kw
                          "'null'/'batch'/'valid', got %r" % normalization)
     return _makeloss_core(data, float(grad_scale), float(valid_thresh),
                           normalization)
+
+
+@register("_contrib_ExpectedExitLoss", arg_names=["gate", "loss"],
+          num_outputs=2, aliases=("expected_exit_loss",),
+          attr_defaults={"steps": 1, "beta": 0.0})
+def _expected_exit_loss(gate, loss, steps=1, beta=0.0, **kw):
+    """The objective of a looped model with a learned exit (Zhu et al.
+    2025, "Scaling Latent Reasoning via Looped Language Models"): ``gate``
+    holds the exit gate's logit and ``loss`` the loss of each of ``steps``
+    loop steps for each of N items, step-major (anything that reshapes to
+    ``(steps, N)``).  With ``lambda_t = sigmoid(gate_t)`` the exit
+    distribution is ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for
+    ``t < steps`` and the rest of the mass at the last step; returns the
+    per-item ``sum_t p_t loss_t + beta sum_t p_t log p_t`` as ``(N,)`` and
+    ``p`` as ``(steps, N)``.  Everything in float32 (or wider), in log
+    space (executor.AMP_FP32_OPS)."""
+    steps = int(steps)
+    ft = jnp.promote_types(loss.dtype, jnp.float32)
+    z = gate.astype(ft).reshape(steps, -1)
+    per = loss.astype(ft).reshape(steps, -1)
+    log_stay = jax.nn.log_sigmoid(-z)[:-1]          # log(1 - lambda_j)
+    before = jnp.concatenate([jnp.zeros_like(z[:1]),
+                              jnp.cumsum(log_stay, axis=0)])
+    log_p = before + jnp.concatenate(
+        [jax.nn.log_sigmoid(z)[:-1], jnp.zeros_like(z[:1])])
+    p = jnp.exp(log_p)
+    return jnp.sum(p * (per + beta * log_p), axis=0), p
 
 
 @register("softmax_cross_entropy", arg_names=["data", "label"])

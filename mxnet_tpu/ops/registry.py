@@ -65,6 +65,11 @@ class OpDef:
     doc: str = ""
     # variadic input op (Concat, add_n, ...): single list input
     variadic: bool = False
+    # name of the attr that holds a sub-Symbol (control flow: the node holds
+    # a graph).  The executor hands such an op ``_interpret``, the
+    # interpreter of that sub-Symbol built with the parent's compute dtype;
+    # tojson / load_json write and read the attr as a nested graph
+    subgraph_attr: Optional[str] = None
 
     def __call__(self, *args, **kwargs):
         return self.fn(*args, **kwargs)
@@ -73,7 +78,7 @@ class OpDef:
 def register(name, *, num_outputs=1, needs_rng=False, num_aux=0,
              differentiable=True, takes_is_train=False, arg_names=None,
              aux_names=None, attr_defaults=None, variadic=False,
-             aliases=(), num_visible=None):
+             aliases=(), num_visible=None, subgraph_attr=None):
     """Decorator: register a pure-jax op implementation under an MXNet name."""
     def _reg(fn):
         op = OpDef(name=name, fn=fn, num_outputs=num_outputs,
@@ -84,7 +89,8 @@ def register(name, *, num_outputs=1, needs_rng=False, num_aux=0,
                    arg_names=list(arg_names) if arg_names else None,
                    aux_names=list(aux_names) if aux_names else None,
                    attr_defaults=dict(attr_defaults or {}),
-                   doc=fn.__doc__ or "", variadic=variadic)
+                   doc=fn.__doc__ or "", variadic=variadic,
+                   subgraph_attr=subgraph_attr)
         if name in _OP_REGISTRY:
             raise MXNetError(f"op {name!r} registered twice")
         _OP_REGISTRY[name] = op

@@ -6,6 +6,8 @@ from .register import init_symbol_module
 init_symbol_module(globals())
 
 
+from .control_flow import foreach as _contrib_foreach   # sym.contrib.foreach
+
 from ..base import ContribNamespace as _ContribNS
 contrib = _ContribNS(globals())
 

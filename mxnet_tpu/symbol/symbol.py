@@ -71,6 +71,9 @@ def node_num_outputs(node: Node) -> int:
         if node.op == "Custom":
             from .. import operator as _custom_mod
             return _custom_mod.num_outputs_for(node.attrs)
+        if node.op == "_foreach":
+            return int(node.attrs["num_out_data"]) \
+                + len(node.attrs["state_names"])
         return 1
     return n
 
@@ -171,6 +174,13 @@ def _ln_param_shapes(attrs, in_shapes):
     return {"gamma": (data[ax],), "beta": (data[ax],)}
 
 
+def _rms_param_shapes(attrs, in_shapes):
+    data = in_shapes.get("data")
+    if data is None:
+        return {}
+    return {"gamma": (data[int(attrs.get("axis", -1)) % len(data)],)}
+
+
 def _embedding_param_shapes(attrs, in_shapes):
     return {"weight": (int(attrs["input_dim"]), int(attrs["output_dim"]))}
 
@@ -200,13 +210,40 @@ def _rnn_param_shapes(attrs, in_shapes):
     return shapes
 
 
+def _input_names(node, opdef):
+    """Names of ``node``'s inputs, position by position: the op's declared
+    arguments less those its attrs drop; a loop node's are in its attrs."""
+    if node.op == "_foreach":
+        a = node.attrs
+        return list(a["data_names"]) + list(a["state_names"]) \
+            + list(a["free_names"])
+    skip = _skip_args(node.op, node.attrs)
+    return [a for a in (opdef.arg_names or []) + (opdef.aux_names or [])
+            if a not in skip]
+
+
+def _foreach_param_shapes(attrs, in_shapes):
+    """Shapes of a loop node's free variables (the body's weights) from
+    its data and states: the body's own inference, given one slice of each
+    scanned input."""
+    known = {n: tuple(in_shapes[n][1:]) for n in attrs["data_names"]
+             if n in in_shapes}
+    known.update({n: tuple(in_shapes[n]) for n in attrs["state_names"]
+                  + attrs["free_names"] if n in in_shapes})
+    shapes, _ = _infer_graph_shapes(attrs["subgraph"], known, {})
+    return {n: shapes[n] for n in attrs["free_names"]
+            if shapes.get(n) is not None}
+
+
 PARAM_SHAPE_INFER = {
+    "_foreach": _foreach_param_shapes,
     "FullyConnected": _fc_param_shapes,
     "Convolution": _conv_param_shapes,
     "Deconvolution": _deconv_param_shapes,
     "BatchNorm": _bn_param_shapes,
     "InstanceNorm": _in_param_shapes,
     "LayerNorm": _ln_param_shapes,
+    "RMSNorm": _rms_param_shapes,
     "L2Normalization": lambda a, s: {},
     "Embedding": _embedding_param_shapes,
     "LeakyReLU": _prelu_param_shapes,
@@ -466,6 +503,9 @@ class Symbol:
                            for src, idx in n.inputs],
             }
             attrs = {k: _attr_to_str(v) for k, v in n.attrs.items()}
+            sub = n.op and _reg.get(n.op).subgraph_attr
+            if sub:     # a loop node: the graph it holds, nested
+                attrs[sub] = n.attrs[sub].tojson()
             attrs.update({k: str(v) for k, v in n._user_attrs.items()})
             if attrs:
                 jn["attrs"] = attrs
@@ -707,6 +747,9 @@ def load_json(json_str: str) -> Symbol:
                 raise MXNetError(f"cannot load graph: unknown op {op!r}")
             op_attrs = {k: _parse_attr(v, opdef.attr_defaults.get(k))
                         for k, v in attrs.items() if not k.startswith("__")}
+            if opdef.subgraph_attr:
+                op_attrs[opdef.subgraph_attr] = load_json(
+                    attrs[opdef.subgraph_attr])
             inputs = [(nodes[i], idx)
                       for i, idx in map(_entry, jn["inputs"])]
             # pre-nnvm JSON omits implicit inputs (BatchNorm's
@@ -1116,10 +1159,7 @@ def _infer_graph_shapes(sym: Symbol, known_shapes: Dict[str, tuple],
         # back-fill parameter shapes from data shapes
         infer_hook = PARAM_SHAPE_INFER.get(n.op)
         argmap = {}
-        names = (opdef.arg_names or []) + (opdef.aux_names or [])
-        skip = _skip_args(n.op, n.attrs)
-        names = [a for a in names if a not in skip]
-        for an, (src, idx) in zip(names, n.inputs):
+        for an, (src, idx) in zip(_input_names(n, opdef), n.inputs):
             argmap[an] = (src, idx)
         if infer_hook:
             in_shapes = {an: val[(id(src), idx)].shape

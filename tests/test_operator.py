@@ -1226,6 +1226,10 @@ _COVERED_ELSEWHERE = {
     '_contrib_MoE': 'tests/test_moe_pipeline.py',
     'moe_ffn': 'tests/test_moe_pipeline.py',
     '_contrib_ChunkedLMLoss': 'tests/test_chunked_loss.py',
+    '_foreach': 'tests/test_control_flow.py',
+    'RMSNorm': 'tests/test_looped_lm.py',
+    '_contrib_ExpectedExitLoss': 'tests/test_looped_lm.py',
+    'expected_exit_loss': 'tests/test_looped_lm.py',
     'Embedding': 'tests/test_gluon.py',
     'Dropout': 'tests/test_autograd.py',
     # spatial + contrib tail (round 2): tests/test_spatial_contrib.py

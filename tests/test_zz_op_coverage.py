@@ -48,6 +48,28 @@ def test_covered_elsewhere_claims_executed():
     assert stale_claims("tests/test_module.py", ()) == []
 
 
+def test_a_loop_node_and_the_ops_of_its_body_count_as_executed():
+    """``_foreach`` is claimed for tests/test_control_flow.py; the ops of
+    a loop's body run under the body's own interpreter, inside a scan, and
+    are recorded there like any node's."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import sym
+    from mxnet_tpu.ops import registry
+    loops = "tests/test_control_flow.py"
+    assert stale_claims(loops, ()) == ["_foreach"]
+    seen, registry.EXECUTED_OPS = registry.EXECUTED_OPS, set()
+    try:
+        _, last = sym.contrib.foreach(
+            lambda _, h: (None, sym.arctanh(h * 0.5)), None,
+            sym.Variable("data"), num_iter=2, name="loop")
+        last.eval(data=mx.nd.ones((2,)))
+        ran = set(registry.EXECUTED_OPS)
+    finally:
+        registry.EXECUTED_OPS = seen | registry.EXECUTED_OPS
+    assert {"_foreach", "arctanh", "_mul_scalar"} <= ran
+    assert stale_claims(loops, ran) == []
+
+
 def test_claimed_files_exist(request):
     from tests.test_operator import _COVERED_ELSEWHERE
     root = str(request.config.rootpath)
