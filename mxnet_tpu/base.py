@@ -9,6 +9,7 @@ ABI can be added later for non-Python bindings.
 from __future__ import annotations
 
 import ast
+import contextlib
 import os
 import threading
 from typing import Any, Callable, Dict, Optional
@@ -77,16 +78,69 @@ declare_env("MXNET_REMAT_POLICY", str, "full",
             "only the elementwise chains between them")
 
 
-def tag_for_remat(x, name):
-    """checkpoint_name, applied ONLY when the save_matmuls remat policy is
-    active (trace-time env check, same read point as executor.maybe_mirror).
-    The name primitive is semantically an identity, but it measurably
-    hinders XLA/GSPMD optimization when present for no reason — a
-    multi-process dp x tp transformer step ran ~50% slower with
-    unconditional tags."""
-    if not env("MXNET_BACKWARD_DO_MIRROR", False) \
-            or os.environ.get("MXNET_REMAT_POLICY") != "save_matmuls":
+# What a rematerialised loop keeps (ops/control_flow.py): while ``_foreach``
+# traces a body, the innermost loop's entry is on this stack: a dict
+# {name: bytes an iteration} where the node says ``remat=True``, None where
+# it does not.  Per thread, as a trace is.
+class _LoopBodies(threading.local):
+    def __init__(self):
+        self.stack = []
+
+
+_LOOP_BODIES = _LoopBodies()
+LOOP_KEPT_NAMES = ("attn_out", "attn_lse", "matmul_out", "conv_out")
+
+
+@contextlib.contextmanager
+def loop_body(kept):
+    """Entered by ``_foreach`` around the trace of its body.  ``kept`` is
+    the dict ``tag_for_remat`` fills for a body with ``remat=True``, None
+    for one without.  A loop in a loop pushes its own entry, so each body
+    answers for itself."""
+    _LOOP_BODIES.stack.append(kept)
+    try:
+        yield
+    finally:
+        _LOOP_BODIES.stack.pop()
+
+
+def _loop_keeps(contraction, width):
+    """Whether a rematerialised loop body keeps the output of a matmul or
+    convolution with this contraction length and output width."""
+    return contraction >= width
+
+
+def tag_for_remat(x, name, contraction=None, width=None):
+    """``checkpoint_name(x, name)`` where a checkpoint's policy reads it,
+    the identity everywhere else.  Two readers:
+
+    * the body of a loop node with ``remat=True``, while it is traced
+      (``loop_body``): its checkpoint keeps ``LOOP_KEPT_NAMES``.  The flash
+      kernel's output and log-sum-exp (no ``contraction``) are always
+      named; a matmul's or convolution's output, of ``contraction`` K
+      products an element and ``width`` N columns, where K >= N
+      (``_loop_keeps``): it is then no larger than the operand it was made
+      from, and K multiply-adds an element to make again against one write
+      and one read to keep.  Set from a ladder on the chip (PERF.md
+      section 6, PR 35): keeping the wider outputs too was faster still
+      and did not leave the process room.
+    * ``MXNET_BACKWARD_DO_MIRROR`` with ``MXNET_REMAT_POLICY=save_matmuls``
+      (trace-time env check, same read point as executor.maybe_mirror):
+      every matmul and convolution output.
+
+    Never unconditional: the name primitive is semantically an identity,
+    but a multi-process dp x tp transformer step ran ~50% slower with
+    tags it had no use for, and a program without a rematerialised loop
+    must lower as it did before the tags existed."""
+    kept = _LOOP_BODIES.stack[-1] if _LOOP_BODIES.stack else None
+    in_loop = kept is not None and (contraction is None
+                                    or _loop_keeps(contraction, width))
+    if not in_loop and (
+            not env("MXNET_BACKWARD_DO_MIRROR", False)
+            or os.environ.get("MXNET_REMAT_POLICY") != "save_matmuls"):
         return x
+    if in_loop:
+        kept[name] = kept.get(name, 0) + x.size * x.dtype.itemsize
     from jax.ad_checkpoint import checkpoint_name
     return checkpoint_name(x, name)
 declare_env("MXNET_PROFILER_MODE", str, "symbolic_only",

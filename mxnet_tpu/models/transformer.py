@@ -243,7 +243,10 @@ def looped_transformer_lm(vocab_size, seq_len, num_layers=2, d_model=128,
     SAME weights -- one loop node (``sym.contrib.foreach``) whose body is
     traced once and rematerialised one loop step at a time in the backward
     pass (``remat=True``: sixteen layer applications' activations at 4096
-    tokens do not fit a chip beside the parameters).  An untied head reads every loop step's
+    tokens do not fit a chip beside the parameters; kept of each step are
+    the flash kernel's output and log-sum-exp and the outputs of ``proj``
+    and ``fc2``, the matmuls whose contraction is at least their width,
+    the rest is made again).  An untied head reads every loop step's
     state, an exit gate ``sigmoid(h . w + b)`` gives each token a
     distribution over the step to stop at, and the objective is the
     expected cross-entropy under it plus ``exit_beta`` times its negative

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .. import tracing
+from .. import base, tracing
 from .registry import register
 
 _NEG_INF = -1e30
@@ -628,6 +628,10 @@ def _fa_fwd(q, k, v, causal, scale, block_q, block_k):
     out, lse = _flash_fwd(q, k, v, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k,
                           return_lse=True)
+    # what a rematerialised loop body keeps of this op: named here, inside
+    # the rule, so that the forward kernel is not run again for either
+    out = base.tag_for_remat(out, "attn_out")
+    lse = base.tag_for_remat(lse, "attn_lse")
     return out, (q, k, v, out, lse)
 
 

@@ -14,7 +14,10 @@ It lowers to one ``jax.lax.scan`` over the interpreter of the sub-Symbol:
 the body is traced once however many iterations run, a free variable's
 gradient is the sum over the iterations, and with ``remat=True`` the
 scanned body is a ``jax.checkpoint``: the backward pass keeps each
-iteration's carried state and recomputes one iteration's forward at a time.
+iteration's carried state and what is dear to make again (the flash
+kernel's output and log-sum-exp, a matmul's or convolution's output where
+its contraction is at least its width: ``base.tag_for_remat``) and
+recomputes the rest of one iteration's forward at a time.
 
 What a body may hold: any registered op without auxiliary state or
 randomness, other ``_foreach`` nodes included.  Refused by name when the
@@ -28,7 +31,7 @@ from __future__ import annotations
 import jax
 from jax import lax
 
-from ..base import MXNetError
+from ..base import LOOP_KEPT_NAMES, MXNetError, loop_body
 from .nn import _node_name
 from .registry import register
 
@@ -70,11 +73,16 @@ def _foreach(*ins, subgraph=None, data_names=(), state_names=(),
     run = _interpret or body_interpreter(subgraph, None, node)
     arg_names = subgraph.list_arguments()
     num_out_data = int(num_out_data)
+    # {name: bytes} of what the body's checkpoint keeps of one iteration,
+    # filled by base.tag_for_remat while the body is traced
+    kept = {} if remat else None
 
     def iteration(free, carry, xs):
         # under a trace: once per program that holds the node, not once
         # per iteration (chipbench: loop_body_traces)
         profiler.record_dispatch("loop.body_trace")
+        if kept is not None:
+            kept.clear()        # a scan may trace its body a second time
         vals = dict(zip(free_names, free))
         vals.update(zip(data_names, xs))
         vals.update(zip(state_names, carry))
@@ -84,11 +92,16 @@ def _foreach(*ins, subgraph=None, data_names=(), state_names=(),
         return new, tuple(outs[:num_out_data])
 
     if remat:
-        iteration = jax.checkpoint(iteration)
+        iteration = jax.checkpoint(
+            iteration, policy=jax.checkpoint_policies.save_only_these_names(
+                *LOOP_KEPT_NAMES))
+    # the operators name what is kept while the body is traced, and the
+    # attention op when the scan is differentiated: both inside this call
+    with loop_body(kept):
+        final, stacked = lax.scan(lambda c, xs: iteration(free, c, xs),
+                                  tuple(states), tuple(data), length=iters)
     tracing.instant("mx.loop.lower", "ops", args={
         "node": node, "iterations": iters, "remat": bool(remat),
         "carried": [[list(s.shape), str(s.dtype)] for s in states],
-        "weights_lifted": len(free_names)})
-    final, stacked = lax.scan(lambda c, xs: iteration(free, c, xs),
-                              tuple(states), tuple(data), length=iters)
+        "weights_lifted": len(free_names), "kept": dict(kept or {})})
     return tuple(stacked) + tuple(final)

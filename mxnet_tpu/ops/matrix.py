@@ -187,7 +187,8 @@ def _dot(lhs, rhs, transpose_a=False, transpose_b=False, **kw):
         lhs = jnp.transpose(lhs)
     if transpose_b:
         rhs = jnp.transpose(rhs)
-    return _ckpt_name(jnp.tensordot(lhs, rhs, axes=1), "matmul_out")
+    return _ckpt_name(jnp.tensordot(lhs, rhs, axes=1), "matmul_out",
+                      rhs.shape[0], int(np.prod(rhs.shape[1:])))
 
 
 @register("batch_dot", arg_names=["lhs", "rhs"],
@@ -197,7 +198,8 @@ def _batch_dot(lhs, rhs, transpose_a=False, transpose_b=False, **kw):
         lhs = jnp.swapaxes(lhs, -1, -2)
     if transpose_b:
         rhs = jnp.swapaxes(rhs, -1, -2)
-    return _ckpt_name(jnp.matmul(lhs, rhs), "matmul_out")
+    return _ckpt_name(jnp.matmul(lhs, rhs), "matmul_out",
+                      lhs.shape[-1], rhs.shape[-1])
 
 
 @register("tile", arg_names=["data"], attr_defaults={"reps": ()})

@@ -74,13 +74,13 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, num_experts, k=1,
     # tagged so MXNET_REMAT_POLICY=save_matmuls keeps the expensive expert
     # matmul outputs and recomputes only the activation/bias chains
     h = _ckpt_name(jnp.einsum("ecd,edf->ecf", expert_in, w1),
-                   "matmul_out") + b1[:, None, :]
+                   "matmul_out", *w1.shape[1:]) + b1[:, None, :]
     if activation == "relu":
         h = jax.nn.relu(h)
     elif activation == "gelu":
         h = jax.nn.gelu(h)
     expert_out = _ckpt_name(jnp.einsum("ecf,efd->ecd", h, w2),
-                            "matmul_out") + b2[:, None, :]
+                            "matmul_out", *w2.shape[1:]) + b2[:, None, :]
     out = jnp.einsum("tec,ecd->td", combine, expert_out)   # (T, d)
     return out.reshape(orig_shape)
 

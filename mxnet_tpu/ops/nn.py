@@ -47,7 +47,7 @@ def _fully_connected(data, weight, bias=None, num_hidden=0, no_bias=False,
     out = jnp.matmul(data, weight.T)
     if not no_bias and bias is not None:
         out = out + bias
-    return _ckpt_name(out, "matmul_out")
+    return _ckpt_name(out, "matmul_out", weight.shape[-1], weight.shape[0])
 
 
 # --------------------------------------------------------------------------
@@ -196,10 +196,11 @@ def _convolution(data, weight, bias=None, kernel=(), stride=(), dilate=(),
     if not no_bias and bias is not None:
         out = out + (bias if nhwc
                      else bias.reshape((1, -1) + (1,) * rank))
-    # identity outside remat; under MXNET_REMAT_POLICY=save_matmuls the
-    # backward keeps conv outputs and recomputes only the cheap
-    # elementwise chains (executor.maybe_mirror)
-    return _ckpt_name(out, "conv_out")
+    # identity outside remat; a rematerialised loop body (by K and N) and
+    # MXNET_REMAT_POLICY=save_matmuls (executor.maybe_mirror) keep conv
+    # outputs and recompute only the cheap elementwise chains
+    return _ckpt_name(out, "conv_out", weight.size // weight.shape[0],
+                      weight.shape[0])
 
 
 @register("Deconvolution", arg_names=["data", "weight", "bias"],

@@ -35,9 +35,12 @@ def foreach(body, data, init_states, name=None, num_iter=None, remat=False):
 
     Returns ``(outs, states)``: each per-iteration output stacked on a new
     leading axis, and the states as they end, in the forms ``body``
-    returned.  ``remat=True`` makes the backward pass recompute one
-    iteration's forward at a time (``jax.checkpoint`` of the scanned body)
-    instead of keeping every iteration's activations."""
+    returned.  ``remat=True`` (this body's activations do not fit) makes
+    the backward pass keep of each iteration only what is dear to make
+    again -- the flash kernel's output and log-sum-exp, and the output of
+    a matmul or convolution whose contraction is at least its width -- and
+    recompute the rest of one iteration's forward at a time
+    (``jax.checkpoint`` of the scanned body, ``base.tag_for_remat``)."""
     data_syms, data_is_list = _as_list(data, "data")
     state_syms, states_is_list = _as_list(init_states, "init_states")
     if not data_syms and num_iter is None:

@@ -58,22 +58,50 @@ def _executed_ops_of_this_module(request):
         "executes these ops, but it ran none of them: %r" % (relpath, stale))
 
 
-@pytest.fixture
-def conv_fold_instants(monkeypatch):
+def _instants(monkeypatch, name):
     """``MXNET_TRACE=1`` around a test, the ring emptied.  Yields
-    ``said(nodes_only=False)``: the ``args`` of every
-    ``mx.conv.space_to_depth`` instant since (ops/nn.py: a ``Convolution``
-    that folded its stride); ``nodes_only`` drops those from eager calls
-    and shape inference, which run the op under no node's scope."""
+    ``said()``: the ``args`` of every instant called ``name`` since."""
     from mxnet_tpu import tracing
     monkeypatch.setenv("MXNET_TRACE", "1")
     tracing.reconfigure()
     tracing.reset()
-
-    def said(nodes_only=False):
-        return [r["args"] for r in tracing.ring_records()
-                if r["name"] == "mx.conv.space_to_depth"
-                and (r["args"]["node"] or not nodes_only)]
-    yield said
+    yield lambda: [r["args"] for r in tracing.ring_records()
+                   if r["name"] == name]
     monkeypatch.delenv("MXNET_TRACE")
     tracing.reconfigure()
+
+
+@pytest.fixture
+def conv_fold_instants(monkeypatch):
+    """``said(nodes_only=False)``: the ``args`` of every
+    ``mx.conv.space_to_depth`` instant of the test (ops/nn.py: a
+    ``Convolution`` that folded its stride); ``nodes_only`` drops those
+    from eager calls and shape inference, which run the op under no node's
+    scope."""
+    for said in _instants(monkeypatch, "mx.conv.space_to_depth"):
+        yield lambda nodes_only=False: [a for a in said()
+                                        if a["node"] or not nodes_only]
+
+
+@pytest.fixture
+def loop_lower_instants(monkeypatch):
+    """``said()``: the ``args`` of every ``mx.loop.lower`` instant of the
+    test (ops/control_flow.py: a loop node lowered to its scan)."""
+    yield from _instants(monkeypatch, "mx.loop.lower")
+
+
+@pytest.fixture
+def jaxpr_eqns():
+    """``walk(jaxpr)``: every equation of a jaxpr and of the jaxprs its
+    equations hold, each as ``(path, eqn)``; ``path`` names the primitives
+    that enclose it, outermost first (``("scan", "remat2")``: inside a
+    checkpoint's backward inside a scan)."""
+    def walk(jaxpr, path=()):
+        for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+            yield path, eqn
+            for held in eqn.params.values():
+                for sub in (held if isinstance(held, (list, tuple))
+                            else (held,)):
+                    if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
+                        yield from walk(sub, path + (eqn.primitive.name,))
+    return walk

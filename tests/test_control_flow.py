@@ -62,12 +62,12 @@ def values(seed=0):
                                                ).astype(np.float32)}
 
 
-def fwd_bwd(symbol, compute_dtype):
+def fwd_bwd(symbol, compute_dtype, vals=None):
     """(outputs, {argument: gradient}) under fixed random cotangents."""
     ex = Executor.simple_bind(symbol, mx.cpu(), grad_req="write",
                               shapes={"data": (BATCH, WIDTH)},
                               compute_dtype=compute_dtype)
-    for n, v in values().items():
+    for n, v in (vals or values()).items():
         ex.arg_dict[n]._set_data(jnp.asarray(v))
     outs = ex.forward(is_train=True)
     rng = np.random.default_rng(1)
@@ -292,3 +292,123 @@ def test_remat_is_a_checkpoint_in_the_program():
     assert "checkpoint" in jaxpr(True) or "remat" in jaxpr(True)
     assert "checkpoint" not in jaxpr(False) and "remat" not in jaxpr(False)
     assert jaxpr(True).count("scan") >= 1
+
+
+# -- what a rematerialised body keeps (base.tag_for_remat) ----------------------
+def ffn_loop(remat, nested=False):
+    """A body with a matmul of each kind: ``up`` (WIDTH -> 2 WIDTH: K < N,
+    made again in the backward pass), ``down`` (2 WIDTH -> WIDTH: K >= N,
+    kept) and an RMSNorm chain; ``nested`` runs that loop twice inside a
+    rematerialised loop of its own."""
+    up, down, gain = (sym.Variable("up_weight"), sym.Variable("down_weight"),
+                      sym.Variable("norm_gamma"))
+
+    def body(_, h):
+        y = sym.FullyConnected(h, weight=up, no_bias=True,
+                               num_hidden=2 * WIDTH, name="up")
+        y = sym.FullyConnected(sym.tanh(y), weight=down, no_bias=True,
+                               num_hidden=WIDTH, name="down")
+        h = sym.RMSNorm(y + h, gamma=gain, name="norm")
+        return h, h
+
+    def outer(_, h):
+        _, h = sym.contrib.foreach(body, None, h, num_iter=STEPS,
+                                   remat=remat, name="in")
+        return h, h
+    _, last = sym.contrib.foreach(outer if nested else body, None,
+                                  sym.Variable("data"), remat=remat,
+                                  num_iter=2 if nested else STEPS,
+                                  name="loop")
+    return last
+
+
+def ffn_values():
+    rng = np.random.default_rng(2)
+    return {"data": rng.normal(size=(BATCH, WIDTH)).astype(np.float32),
+            "up_weight": (rng.normal(size=(2 * WIDTH, WIDTH)) * 0.4
+                          ).astype(np.float32),
+            "down_weight": (rng.normal(size=(WIDTH, 2 * WIDTH)) * 0.4
+                            ).astype(np.float32),
+            "norm_gamma": 1 + 0.1 * rng.normal(size=(WIDTH,)
+                                               ).astype(np.float32)}
+
+
+def grad_jaxpr(symbol, vals, compute_dtype=None):
+    """The jaxpr of d(sum of output 0)/d(every argument but the data)."""
+    from mxnet_tpu.executor import build_interpreter
+    run, names, _ = build_interpreter(symbol, compute_dtype)
+
+    def f(*args):
+        return run(args, (), None, True)[0][0].astype(jnp.float32).sum()
+    return jax.make_jaxpr(jax.grad(f, argnums=tuple(
+        i for i, n in enumerate(names) if n != "data")))(
+        *[jnp.asarray(vals[n]) for n in names])
+
+
+def test_a_rematerialised_body_keeps_its_long_matmuls(jaxpr_eqns):
+    """In the gradient of a ``remat=True`` loop the checkpoint's backward
+    (``remat2`` inside the backward scan) makes ``up`` again and both
+    matmuls' two gradients, five ``dot_general`` for the six of a whole
+    recomputation, and the RMSNorm chain; ``down``'s output is named in
+    the forward scan and kept."""
+    found = list(jaxpr_eqns(grad_jaxpr(ffn_loop(True), ffn_values())))
+
+    def at(path, prim):         # directly under these primitives
+        return [e for p, e in found if p == path and e.primitive.name == prim]
+    named, = at(("scan",), "name")
+    assert named.params["name"] == "matmul_out"
+    assert named.outvars[0].aval.shape == (BATCH, WIDTH)
+    assert sum(e.primitive.name == "name" for _, e in found) == 1
+    assert len(at(("scan",), "dot_general")) == 2        # forward: up, down
+    assert len(at(("scan", "remat2"), "dot_general")) == 5
+    assert at(("scan", "remat2"), "rsqrt") and at(("scan", "remat2"), "tanh")
+
+
+@pytest.mark.parametrize("nested", [False, True], ids=["flat", "nested"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_remat_changes_no_gradient(dtype, nested):
+    """What is kept is what the forward computed: ``remat=True`` gives the
+    gradients of ``remat=False``, in float32 bit for bit, for a loop in a
+    loop too."""
+    cd = None if dtype == "float32" else jnp.bfloat16
+    outs, grads = fwd_bwd(ffn_loop(True, nested), cd, ffn_values())
+    want_outs, want = fwd_bwd(ffn_loop(False, nested), cd, ffn_values())
+    np.testing.assert_array_equal(outs[0], want_outs[0])
+    assert sorted(grads) == ["data", "down_weight", "norm_gamma",
+                             "up_weight"]
+    for n in grads:
+        assert np.abs(want[n]).max() > 0
+        if cd is None:
+            np.testing.assert_array_equal(grads[n], want[n])
+        else:
+            np.testing.assert_allclose(grads[n], want[n], rtol=3e-2,
+                                       atol=3e-2 * np.abs(want[n]).max())
+
+
+@pytest.mark.parametrize("nested", [False, True], ids=["flat", "nested"])
+def test_a_loop_without_remat_names_nothing(jaxpr_eqns, nested):
+    """The tags are identities outside a rematerialised body: the program
+    of a ``remat=False`` loop holds no ``name`` primitive (the guard for
+    every program that has no such loop), a ``remat=True`` one does."""
+    def prims(remat):
+        return {eqn.primitive.name for _, eqn in jaxpr_eqns(
+            grad_jaxpr(ffn_loop(remat, nested), ffn_values()))}
+    assert "name" in prims(True) and "remat2" in prims(True)
+    assert not {"name", "remat2"} & prims(False)
+
+
+def test_the_lowering_says_what_it_keeps(loop_lower_instants):
+    """``mx.loop.lower`` carries ``kept``: the names the body's checkpoint
+    keeps and the bytes an iteration they hold, from the shapes."""
+    fwd_bwd(ffn_loop(True, nested=True), jnp.bfloat16, ffn_values())
+    said = loop_lower_instants()
+    # shape inference runs the op under no node's scope, in float32
+    assert said[0]["node"] == "" and said[0]["kept"] == {
+        "matmul_out": BATCH * WIDTH * 4}
+    last = {a["node"]: a["kept"] for a in said}
+    # the inner body holds the matmuls, the outer one only the inner loop
+    assert last["in"] == {"matmul_out": BATCH * WIDTH * 2}
+    assert last["loop"] == {}
+    fwd_bwd(ffn_loop(False), None, ffn_values())
+    assert all(a["kept"] == {} and a["remat"] is False
+               for a in loop_lower_instants()[len(said):])

@@ -126,7 +126,8 @@ def start(symbol, seed=0):
             params[n] = (rng.normal(size=s) / np.sqrt(s[-1])
                          ).astype(np.float32)
     # a gate that matters: exits spread over the steps
-    params["exit_gate_weight"] *= 4
+    if "exit_gate_weight" in params:
+        params["exit_gate_weight"] *= 4
     data = rng.integers(0, BUILDER["vocab_size"], shapes["data"])
     label = rng.integers(0, BUILDER["vocab_size"], shapes["data"])
     return params, data.astype(np.int32), label.astype(np.float32), shapes
@@ -249,3 +250,113 @@ def test_the_model_lists_each_looped_weight_once_and_saves_what_it_lists(
     assert loaded.list_arguments() == names and not aux
     assert sorted(args) == sorted(n for n in names
                                   if n not in ("data", "softmax_label"))
+
+
+# -- what the loop keeps for its backward pass (base.tag_for_remat) -------------
+def grad_jaxpr(symbol, params, data, label):
+    """The jaxpr of d(the last output's sum)/d(every parameter), bf16."""
+    run, names, _ = build_interpreter(symbol, jnp.bfloat16)
+    vals = dict(params, data=data, softmax_label=label)
+
+    def f(*args):
+        return run(args, (), None, True)[0][-1].astype(jnp.float32).sum()
+    return jax.make_jaxpr(jax.grad(f, argnums=tuple(
+        i for i, n in enumerate(names) if n in params)))(
+        *[jnp.asarray(vals[n]) for n in names])
+
+
+def looped_symbol(remat=True):
+    kw = dict(BUILDER)
+    symbol = models.looped_transformer_lm(kw.pop("vocab_size"),
+                                          TRAFFIC["seq_len"], **kw)
+    loop, = [n for n in symbol.nodes() if n.op == "_foreach"]
+    loop.attrs["remat"] = remat         # the model itself always says True
+    return symbol
+
+
+def test_the_loop_keeps_the_kernels_residuals_and_the_long_matmuls(
+        jaxpr_eqns):
+    """The gradient of the model's rematerialised loop: ``flash_fwd`` runs
+    in the forward scan alone, its output and log-sum-exp named there
+    beside the two matmuls a layer whose contraction is at least their
+    width (``proj`` 64 -> 64, ``fc2`` 96 -> 64); the checkpoint's backward
+    makes ``qkv`` and ``fc1`` again (K < N) with every RMSNorm, and runs
+    the backward kernel on what was kept."""
+    symbol = looped_symbol()
+    params, data, label, _ = start(symbol)
+    found = list(jaxpr_eqns(grad_jaxpr(symbol, params, data, label)))
+    layers = BUILDER["num_layers"]
+
+    def at(path, prim):         # directly under these primitives
+        return [e for p, e in found if p == path and e.primitive.name == prim]
+    kernels = {}                # kernel: is each call under the checkpoint
+    for p, e in found:
+        if e.primitive.name == "pallas_call":
+            kernels.setdefault(e.params["name"], []).append("remat2" in p)
+    assert kernels == {"flash_fwd": [False] * layers,
+                       "flash_bwd_dkv_dq": [True] * layers}
+    named = at(("scan",), "name")
+    assert len(named) == sum(e.primitive.name == "name" for _, e in found)
+    assert sorted(e.params["name"] for e in named) == sorted(
+        ["attn_out", "attn_lse", "matmul_out", "matmul_out"] * layers)
+    assert [e.outvars[0].aval.shape for e in named
+            if e.params["name"] == "matmul_out"] == [
+        (32, BUILDER["d_model"])] * (2 * layers)
+    # four matmuls a layer: 2 made again + 8 gradients, for 4 + 8
+    assert len(at(("scan", "remat2"), "dot_general")) == 10 * layers
+    assert len(at(("scan", "remat2"), "rsqrt")) == 4 * layers + 1
+
+
+def test_keeping_changes_no_number_of_the_models_step():
+    """Module's fused step over the rematerialised loop against the same
+    loop keeping every activation: the same outputs, and the same
+    first-step deltas to float32's rounding (the two programs fuse their
+    chains differently; tests/test_control_flow.py has a body small enough
+    to be equal bit for bit)."""
+    params, data, label, shapes = start(looped_symbol())
+    outs, deltas = module_step(looped_symbol(True), params, data, label,
+                               shapes)
+    want_outs, want = module_step(looped_symbol(False), params, data, label,
+                                  shapes)
+    for got, ref in zip(outs, want_outs):
+        np.testing.assert_array_equal(got, ref)
+    for n in sorted(params):
+        err = np.linalg.norm(deltas[n] - want[n]) / np.linalg.norm(want[n])
+        assert err < 2e-6, (n, err)
+
+
+@pytest.mark.parametrize("model", ["transformer_lm", "loop_without_remat"])
+def test_a_step_without_a_rematerialised_loop_names_nothing(jaxpr_eqns,
+                                                            model):
+    """The tags are identities there: no ``name`` primitive and no
+    checkpoint in the gradient of ``transformer_lm`` (GPT-2's layout:
+    flash attention, ``FullyConnected``) nor of a loop that keeps its
+    activations -- the programs of every model without such a loop lower
+    as they did before the tags existed."""
+    if model == "transformer_lm":
+        symbol = models.transformer_lm(
+            BUILDER["vocab_size"], TRAFFIC["seq_len"], num_layers=2,
+            d_model=64, num_heads=4, d_ff=96)
+    else:
+        symbol = looped_symbol(remat=False)
+    params, data, label, _ = start(symbol)
+    prims = {e.primitive.name for _, e in jaxpr_eqns(
+        grad_jaxpr(symbol, params, data, label))}
+    assert "pallas_call" in prims and "dot_general" in prims
+    assert not {"name", "remat2"} & prims
+
+
+def test_the_lowering_says_what_the_loop_keeps(loop_lower_instants):
+    """``kept`` of ``mx.loop.lower``, from the shapes: a layer keeps its
+    attention output (B, H, S, D) and log-sum-exp (B, H, S) float32, and
+    the outputs of ``proj`` and ``fc2`` (B S, d); bf16 under AMP."""
+    symbol = looped_symbol()
+    params, data, label, _ = start(symbol)
+    grad_jaxpr(symbol, params, data, label)
+    said = [a for a in loop_lower_instants() if a["node"] == "loop"]
+    tokens = TRAFFIC["batch"] * TRAFFIC["seq_len"]
+    layers, d = BUILDER["num_layers"], BUILDER["d_model"]
+    assert said[-1]["kept"] == {"attn_out": layers * tokens * d * 2,
+                                "attn_lse": layers * tokens
+                                * BUILDER["num_heads"] * 4,
+                                "matmul_out": layers * 2 * tokens * d * 2}
