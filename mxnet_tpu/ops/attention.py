@@ -88,6 +88,38 @@ def _round_up(x, m):
 # 0.125, 0.123); not causal, 512x1024 reads 0.491 for 0.526.
 _TILE_FWD = (1024, 1024)
 _TILE_BWD = (512, 512)
+# Rows r of a strip of a causal diagonal tile (0: the diagonal tile
+# masked whole): a forward tile of up to 512 rows (``fwd``), a wider one
+# (``fwd_wide``, only in a sequence of more than one tile), a backward
+# tile (``bwd``).  Strip s of b / r meets only the (s + 1) r keys
+# (forward) or b - s r queries (backward) on its side of the diagonal
+# and masks only its r x r block on it.  From the ladders on the v5e
+# (bf16, causal, device ms a call, masked tile -> strips of 128 / 256 /
+# 512; PERF.md §6, PR 38):
+#   flash_fwd, 1024-row tiles  S 1024 D 64 (one tile) 0.2145 -> 0.2659 /
+#                     0.2674 / 0.2746; S 1024 D 128 GQA 8/2 0.1028 ->
+#                     0.1327 / 0.1339 / 0.1352; S 2048 D 64 0.8140 ->
+#                     0.8583 / 0.7663 / 0.7242; S 4096 D 128 0.6840 ->
+#                     0.7034 / 0.6564 / 0.6345; S 1100 (640 rows) 0.4629
+#                     -> 0.5154 (128 only)
+#   flash_fwd, one tile of up to 512 rows  S 512 D 64 0.1401 -> 0.0917 /
+#                     0.1348; B 16 0.5656 -> 0.3724 (128); GQA 8/2 D 128
+#                     0.0715 -> 0.0476 / 0.0687; S 384 0.0946 -> 0.0696,
+#                     S 256 0.0721 -> 0.0600 (128); 512-row tiles forced
+#                     at S 1024 0.3724 -> 0.3616 / 0.3802, at S 4096 D 128
+#                     1.1369 -> 1.1242 (128)
+#   flash_bwd_dkv_dq, 512-row  S 1024 D 64 0.4180 -> 0.3545 / 0.3748 /
+#                     0.4174; S 4096 D 128 1.2354 -> 1.1689 / 1.1876 /
+#                     1.2301; GQA 8/2 D 128 0.2044 -> 0.1740 / 0.1834 /
+#                     0.2039; S 2048 1.4085 -> 1.2830 / 1.3244 / 1.4102;
+#                     S 1100 (384 rows) 0.5559 -> 0.5237; S 512 0.1465 ->
+#                     0.1090 / 0.1211 (128)
+# At one 1024-row tile every width loses (not understood: §7).  The
+# preferred tiles stay: the forward at 512 rows is the row above, the
+# backward at 1024 rows in strips of 128 reads 0.3160 at S 1024 D 64 but
+# needs the two dQ and dK/dV kernels from S 2048 or GQA 8/2 D 128 on
+# (2 x 0.9172, 2 x 0.1236): a rule for it is open (§7).
+_DIAG_STRIP = {"fwd": 128, "fwd_wide": 512, "bwd": 128}
 # What a grid step may take of VMEM by _vmem_bytes' count.  Mosaic grants
 # a kernel 16 MiB unless told otherwise, and nothing here asks for more.
 _VMEM_BUDGET = 14 << 20
@@ -111,6 +143,7 @@ class _Geometry(NamedTuple):
     vmem_bytes: int   # _vmem_bytes of these tiles
     derived: bool     # no explicit block was given
     merged: bool      # backward: one kernel, dQ accumulated in VMEM
+    diag_strip: int   # causal, square on the diagonal: its strips' rows
 
 
 def _vmem_bytes(block_q, block_k, D, itemsize, forward, dq_rows=0):
@@ -159,7 +192,13 @@ def _tile_rows(S, block, preferred):
     return min(block, _round_up(S, _LANES))
 
 
-def _geometry(q, k, block_q, block_k, forward):
+def _strip_rows(block, preferred):
+    """Rows of a diagonal tile's strips: ``preferred`` where it divides
+    ``block`` (a 640-row tile has no strips of 512), else 0."""
+    return preferred if preferred and block % preferred == 0 else 0
+
+
+def _geometry(q, k, block_q, block_k, forward, causal=False):
     """The one place that sizes a call: the tiles from what it can see —
     the two sequence lengths, the head dim, the operand dtype, the query
     heads a KV head — for ``flash_fwd`` (``forward``) or for the backward.
@@ -170,8 +209,14 @@ def _geometry(q, k, block_q, block_k, forward):
     dQ's whole-sequence accumulator included; where it does not (S 8192, D 128, four query heads a KV
     head: 33 MB), the same tiles are counted for the dQ and dK/dV
     kernels apart.  Sequences are padded to whole tiles; the head dim is
-    a whole block dim and travels unpadded.  All of it on every backend,
-    so the interpreted CPU tests trace the program the chip compiles."""
+    a whole block dim and travels unpadded.  A ``causal`` call with
+    Sq == Sk whose tiles came out square has every tile at a static
+    offset from the diagonal: its diagonal tiles run in strips of
+    ``diag_strip`` rows (_DIAG_STRIP, _strip_rows) and the tiles below
+    them unmasked — a forward tile of over 512 rows only where the
+    sequence is more than one tile; any other call has ``diag_strip``
+    0.  All of it on every backend, so the interpreted CPU tests trace
+    the program the chip compiles."""
     B, H, Sq, D = q.shape
     Hk, Sk = k.shape[1], k.shape[2]
     if H % Hk:
@@ -205,20 +250,47 @@ def _geometry(q, k, block_q, block_k, forward):
         else:
             pref_q = bq // 2
     Skp = _round_up(Sk, bk)
+    strip = 0
+    if causal and Sq == Sk and bq == bk:
+        if not forward:
+            strip = _strip_rows(bq, _DIAG_STRIP["bwd"])
+        elif bq <= 512:
+            strip = _strip_rows(bq, _DIAG_STRIP["fwd"])
+        elif Sqp > bq:
+            strip = _strip_rows(bq, _DIAG_STRIP["fwd_wide"])
     return _Geometry(B, H, Hk, G, Sq, Sk, D, bq, bk, Sqp, Skp,
-                     Sqp // bq, Skp // bk, vmem, derived, merged)
+                     Sqp // bq, Skp // bk, vmem, derived, merged, strip)
 
 
-def _say_geometry(kernel, geo, causal, grid, **more):
+def _tile_kinds(geo, causal, strip):
+    """The live score tiles of one head by kind: below the diagonal and
+    unmasked (``tiles_full``), on it in strips (``tiles_diag``), or masked
+    whole by _tile_mask (``tiles_masked``: every live tile of a causal
+    call that is not stripped, the last k tile's of one with K padding)."""
+    full = diag = masked = 0
+    if strip:
+        full, diag = geo.nq * (geo.nq - 1) // 2, geo.nq
+    elif causal:
+        bq, bk = geo.block_q, geo.block_k
+        masked = sum(min((i * bq + bq - 1) // bk, geo.nk - 1) + 1
+                     for i in range(geo.nq))
+    elif geo.Skp != geo.Sk:
+        masked = geo.nq
+    return {"tiles_full": full, "tiles_diag": diag, "tiles_masked": masked}
+
+
+def _say_geometry(kernel, geo, causal, grid, strip, **more):
     """One trace instant a compile (this runs while jit traces the
     kernel's caller, never per call): what engaged, for whoever reads the
-    kernel's time beside it."""
+    kernel's time beside it.  ``strip``: the diagonal strips' rows this
+    kernel runs (0: none)."""
     tracing.instant("mx.attention.geometry", "attention", args={
         "kernel": kernel, "Sq": geo.Sq, "Sk": geo.Sk, "D": geo.D,
         "G": geo.G, "causal": bool(causal), "block_q": geo.block_q,
         "block_k": geo.block_k, "grid": list(grid),
         "grid_steps": math.prod(grid), "vmem_bytes": geo.vmem_bytes,
-        "derived": geo.derived, **more})
+        "derived": geo.derived, "diag_strip": strip,
+        **_tile_kinds(geo, causal, strip), **more})
 
 
 def _pad_heads(x, Sp):
@@ -253,12 +325,28 @@ def _tile_mask(q_lo, k_lo, shape, Sk, Skp, causal, q_axis):
     return valid
 
 
+def _mask_cols(s, valid, lo):
+    """``s`` with its columns [lo, lo + width of ``valid``) masked by
+    ``valid`` and the others as they are: a diagonal strip builds its
+    mask over its one r x r block on the diagonal, not over the strip."""
+    hi = lo + valid.shape[1]
+    parts = [jnp.where(valid, s[:, lo:hi], _NEG_INF)]
+    if lo:
+        parts.insert(0, s[:, :lo])
+    if hi < s.shape[1]:
+        parts.append(s[:, hi:])
+    return jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
+
+
 def _kv_spec(pl, D, G, block_q, block_k, causal):
     """K/V BlockSpec of a (B*H, q blocks, k blocks) grid: program b walks
     q heads and its KV head is b // G (GQA sharing).  Causal: k blocks
     strictly above the diagonal contribute nothing; their programs are
     skipped (``last_k``) and re-name the last needed block, so no DMA is
-    issued for them.  Returns (spec, last_k)."""
+    issued for them.  Of the live ones, where _geometry gave the call a
+    ``diag_strip``, a tile below the diagonal runs with no mask and the
+    one on it in strips; otherwise each is masked whole (_tile_mask).
+    Returns (spec, last_k)."""
     def last_k(i):
         return (i * block_q + block_q - 1) // block_k
 
@@ -287,15 +375,20 @@ def _flash_fwd(q, k, v, causal=False, scale=None, block_q=None,
     (block_k, D) K/V tile per program, so VMEM residency is set by the
     block sizes (_geometry derives them from the shapes; block_q/block_k
     force them) and not by the sequence length; the online-softmax state
-    (m, l, acc) lives in VMEM scratch across the k axis."""
+    (m, l, acc) lives in VMEM scratch across the k axis.  Causal with a
+    ``diag_strip`` r (_geometry): a tile below the diagonal runs with no
+    mask, and the diagonal tile as b / r strips, strip s taking query
+    rows [s r, (s + 1) r) against keys [0, (s + 1) r) only, masked on
+    its last r x r block, into the same rows of m, l and acc."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    geo = _geometry(q, k, block_q, block_k, forward=True)
+    geo = _geometry(q, k, block_q, block_k, forward=True, causal=causal)
     (B, H, Hk, G, Sq, Sk, D, block_q, block_k, Sqp, Skp, nq,
      nk, *_) = geo
+    strip = geo.diag_strip
     grid = (B * H, nq, nk)
-    _say_geometry("flash_fwd", geo, causal, grid)
+    _say_geometry("flash_fwd", geo, causal, grid, strip)
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
@@ -314,28 +407,45 @@ def _flash_fwd(q, k, v, causal=False, scale=None, block_q=None,
             l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
             acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-        def step():
+        def step(mask, rows=slice(None)):
+            """Query rows ``rows`` of the tile against the keys they
+            meet (a strip's: as many as it has rows past the tile's
+            start), their scores through ``mask``."""
+            kv = slice(None) if rows.stop is None else slice(0, rows.stop)
             s = lax.dot_general(
-                q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                q_ref[0, rows], k_ref[0, kv], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # (BQ, BK)
-            valid = _tile_mask(i * block_q, j * block_k,
-                               (block_q, block_k), Sk, Skp, causal, 0)
-            if valid is not None:
-                s = jnp.where(valid, s, _NEG_INF)
-            m_prev = m_ref[...]                     # (BQ, 128), lanes equal
+            s = mask(s)
+            m_prev = m_ref[rows]                    # (BQ, 128), lanes equal
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new[:, :1])
-            l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
-            m_ref[...] = m_new
-            acc_ref[...] = acc_ref[...] * alpha[:, :1] + lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            l_ref[rows] = l_ref[rows] * alpha + p.sum(axis=-1, keepdims=True)
+            m_ref[rows] = m_new
+            acc_ref[rows] = acc_ref[rows] * alpha[:, :1] + lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, kv], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-        if causal:
-            pl.when(j <= last_k(i))(step)
+        def whole(s):
+            valid = _tile_mask(i * block_q, j * block_k,
+                               (block_q, block_k), Sk, Skp, causal, 0)
+            return s if valid is None else jnp.where(valid, s, _NEG_INF)
+
+        def diagonal():
+            for lo in range(0, block_q, strip):
+                valid = _tile_mask(i * block_q + lo, j * block_k + lo,
+                                   (strip, strip), Sk, Skp, True, 0)
+                step(lambda s: _mask_cols(s, valid, lo),
+                     slice(lo, lo + strip))
+
+        if strip:
+            if nq > 1:
+                pl.when(j < i)(lambda: step(lambda s: s))
+            pl.when(j == i)(diagonal)
+        elif causal:
+            pl.when(j <= last_k(i))(lambda: step(whole))
         else:
-            step()
+            step(whole)
 
         @pl.when(j == nk - 1)
         def _():
@@ -410,20 +520,28 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
     from ``flash_bwd_dq`` and dK/dV from ``flash_bwd_dkv``: the same
     tiles, the same ``p_and_ds_t``, each rebuilding it.  One tile a
     program as in the forward; the tiles are the backward's own
-    (_geometry, ``forward=False``), not the forward's."""
+    (_geometry, ``forward=False``), not the forward's.  Causal with a
+    ``diag_strip`` r, the dK/dV walk (merged or not) takes a tile below
+    the diagonal with no mask and the diagonal one as b / r strips of
+    its transposed tile: strip s, k rows [s r, (s + 1) r), meets query
+    columns [s r, b) only, masked on its first r x r block, and feeds
+    those rows of dK and dV and those columns' rows of the dQ slot.
+    ``flash_bwd_dq`` masks each tile whole as before."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    geo = _geometry(q, k, block_q, block_k, forward=False)
+    geo = _geometry(q, k, block_q, block_k, forward=False, causal=causal)
     (B, H, Hk, G, Sq, Sk, D, block_q, block_k, Sqp, Skp, nq,
      nk, *_) = geo
-    merged = geo.merged
+    merged, strip = geo.merged, geo.diag_strip
     dq_grid, dkv_grid = (B * H, nq, nk), (B * Hk, nk, G * nq)
     if merged:
-        _say_geometry("flash_bwd_dkv_dq", geo, causal, dkv_grid, merged=True)
+        _say_geometry("flash_bwd_dkv_dq", geo, causal, dkv_grid, strip,
+                      merged=True)
     else:
-        _say_geometry("flash_bwd_dq", geo, causal, dq_grid, merged=False)
-        _say_geometry("flash_bwd_dkv", geo, causal, dkv_grid, merged=False)
+        _say_geometry("flash_bwd_dq", geo, causal, dq_grid, 0, merged=False)
+        _say_geometry("flash_bwd_dkv", geo, causal, dkv_grid, strip,
+                      merged=False)
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
@@ -451,24 +569,34 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
         # causal: q blocks strictly before this k block see nothing
         return (j * block_k) // block_q
 
-    def p_and_ds_t(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, i, j):
-        """Transposed tiles pT, dsT: (BK, BQ).  k @ q^T keeps every
-        per-query statistic a (1, BQ) row that broadcasts over sublanes —
-        no row->column relayout of lse/delta inside the kernel."""
-        s_t = lax.dot_general(k_ref[0], q_ref[0], (((1,), (1,)), ((), ())),
+    def p_and_ds_t(q_ref, k_ref, v_ref, g_ref, lse_ref, dlt_ref, mask,
+                   kr=slice(None), qc=slice(None)):
+        """Transposed tiles pT, dsT: (BK, BQ), or of the tile's k rows
+        ``kr`` against its query columns ``qc``, the scores through
+        ``mask``.  k @ q^T keeps every per-query statistic a (1, BQ) row
+        that broadcasts over sublanes — no row->column relayout of
+        lse/delta inside the kernel."""
+        s_t = lax.dot_general(k_ref[0, kr], q_ref[0, qc],
+                              (((1,), (1,)), ((), ())),
                               preferred_element_type=f32) * scale
-        valid = _tile_mask(i * block_q, j * block_k, (block_k, block_q),
-                           Sk, Skp, causal, 1)
-        if valid is not None:
-            s_t = jnp.where(valid, s_t, _NEG_INF)
-        p_t = jnp.exp(s_t - lse_ref[0, :1, :])
-        dp_t = lax.dot_general(v_ref[0], g_ref[0], (((1,), (1,)), ((), ())),
+        s_t = mask(s_t)
+        p_t = jnp.exp(s_t - lse_ref[0, :1, qc])
+        dp_t = lax.dot_general(v_ref[0, kr], g_ref[0, qc],
+                               (((1,), (1,)), ((), ())),
                                preferred_element_type=f32)
-        return p_t, p_t * (dp_t - dlt_ref[0, :1, :]) * scale
+        return p_t, p_t * (dp_t - dlt_ref[0, :1, qc]) * scale
 
-    def dq_of(ds_t, k_ref):
+    def whole(i, j):
+        """The mask of tile (i, j) as it stands: _tile_mask's."""
+        def mask(s_t):
+            valid = _tile_mask(i * block_q, j * block_k, (block_k, block_q),
+                               Sk, Skp, causal, 1)
+            return s_t if valid is None else jnp.where(valid, s_t, _NEG_INF)
+        return mask
+
+    def dq_of(ds_t, k_ref, kr=slice(None)):
         return lax.dot_general(
-            ds_t.astype(k_ref.dtype), k_ref[0],
+            ds_t.astype(k_ref.dtype), k_ref[0, kr],
             (((0,), (0,)), ((), ())), preferred_element_type=f32)
 
     def dq_alone():
@@ -484,7 +612,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
 
             def step():
                 _, ds_t = p_and_ds_t(q_ref, k_ref, v_ref, g_ref, lse_ref,
-                                     dlt_ref, i, j)
+                                     dlt_ref, whole(i, j))
                 acc_ref[...] += dq_of(ds_t, k_ref)
 
             if causal:
@@ -537,22 +665,33 @@ def _flash_bwd(q, k, v, out, lse, g, causal=False, scale=None,
             def _():
                 dq_acc[t] = jnp.zeros(dq_acc.shape[1:], f32)
 
-        def step():
+        def step(mask, kr=slice(None), qc=slice(None)):
             p_t, ds_t = p_and_ds_t(q_ref, k_ref, v_ref, g_ref, lse_ref,
-                                   dlt_ref, i, j)
-            dv_acc[...] += lax.dot_general(
-                p_t.astype(g_ref.dtype), g_ref[0],
+                                   dlt_ref, mask, kr, qc)
+            dv_acc[kr] += lax.dot_general(
+                p_t.astype(g_ref.dtype), g_ref[0, qc],
                 (((1,), (0,)), ((), ())), preferred_element_type=f32)
-            dk_acc[...] += lax.dot_general(
-                ds_t.astype(q_ref.dtype), q_ref[0],
+            dk_acc[kr] += lax.dot_general(
+                ds_t.astype(q_ref.dtype), q_ref[0, qc],
                 (((1,), (0,)), ((), ())), preferred_element_type=f32)
             if merged:
-                dq_acc[t] += dq_of(ds_t, k_ref)
+                dq_acc[t, qc] += dq_of(ds_t, k_ref, kr)
 
-        if causal:
-            pl.when(i >= first_q(j))(step)
+        def diagonal():
+            for lo in range(0, block_k, strip):
+                valid = _tile_mask(i * block_q + lo, j * block_k + lo,
+                                   (strip, strip), Sk, Skp, True, 1)
+                step(lambda s_t: _mask_cols(s_t, valid, 0),
+                     slice(lo, lo + strip), slice(lo, None))
+
+        if strip:
+            if nq > 1:
+                pl.when(i > j)(lambda: step(lambda s_t: s_t))
+            pl.when(i == j)(diagonal)
+        elif causal:
+            pl.when(i >= first_q(j))(lambda: step(whole(i, j)))
         else:
-            step()
+            step(whole(i, j))
 
         @pl.when(t == G * nq - 1)
         def _():

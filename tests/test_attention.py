@@ -169,8 +169,10 @@ def test_flash_backward_multi_block():
 # (Sq, Sk, H, Hk, causal, block_q, block_k): several tiles each way at
 # forced 128-row and rectangular tiles — GQA groups of 2 and 1, Sq != Sk,
 # K padding (Sk 300 in tiles of 128 / 256), causal skipping with
-# block_q != block_k (the clamped K/V and q index maps)
+# block_q != block_k (the clamped K/V and q index maps); square causal
+# tiles run their diagonal in strips (one of 128 at 128, two of 256 at 512)
 _FORCED_TILES = [
+    (1024, 1024, 2, 1, True, 512, 512),
     (300, 300, 2, 2, True, 128, 128),
     (300, 300, 2, 1, False, 128, 128),
     (384, 384, 2, 1, True, 256, 128),
@@ -288,6 +290,149 @@ def test_flash_backward_merged_equals_two_kernels_bf16(monkeypatch):
         assert x.dtype == jnp.bfloat16
         np.testing.assert_array_equal(np.asarray(x, np.float32),
                                       np.asarray(y, np.float32))
+
+
+# --- the causal diagonal in strips (ops/attention.py _DIAG_STRIP) ----------
+# (S, H, Hk, dtype, block): forced 256-row tiles in strips of 128 — two
+# a diagonal tile, tiles below it unmasked — at S 512 / 1024, GQA 4/1 and
+# 8/2, K padding (S 1100: 5 tiles of 256, the last one padded); derived,
+# a sequence of one tile, with no tile below the diagonal (S 384: three
+# strips; S 200: two, in a tile padded to 256)
+_STRIPS = [
+    (512, 2, 2, jnp.float32, 256),
+    (1024, 2, 2, jnp.float32, 256),
+    (512, 4, 1, jnp.float32, 256),
+    (512, 8, 2, jnp.float32, 256),
+    (1100, 2, 1, jnp.float32, 256),
+    (512, 2, 2, jnp.bfloat16, 256),
+    (1024, 4, 1, jnp.bfloat16, 256),
+    (1100, 8, 2, jnp.bfloat16, 256),
+    (384, 2, 1, jnp.float32, None),
+    (200, 4, 1, jnp.bfloat16, None),
+]
+# against the reference: the forced-tile tolerances in float32; in bf16
+# the outputs' own rounding.  Against the masked tile: the same sums
+# with exact zeros left out, to the last bit but for their order
+_TOL = {jnp.float32: (1e-5, 2e-3, 1e-6), jnp.bfloat16: (2e-2, 6e-2, 1e-2)}
+
+
+def _strip_inputs(S, H, Hk, dtype):
+    rng = np.random.RandomState(S + 10 * H + Hk)
+    mk = lambda h: jnp.asarray(rng.randn(1, h, S, 16), dtype)
+    return mk(H), mk(Hk), mk(Hk), mk(H)
+
+
+def _striped_and_masked(monkeypatch, fn, q, k, block):
+    """``fn()`` with the diagonal in strips of 128, and again with the
+    strips off: the masked tile the parent ran."""
+    from mxnet_tpu.ops import attention as A
+    monkeypatch.setattr(A, "_DIAG_STRIP",
+                        {"fwd": 128, "fwd_wide": 128, "bwd": 128})
+    for forward in (True, False):
+        geo = A._geometry(q, k, block, block, forward, causal=True)
+        assert geo.diag_strip == 128 and (geo.nq > 1) == (block == 256)
+    striped = fn()
+    monkeypatch.setattr(A, "_DIAG_STRIP", dict.fromkeys(A._DIAG_STRIP, 0))
+    assert A._geometry(q, k, block, block, True, causal=True).diag_strip == 0
+    return striped, fn()
+
+
+def _close(x, y, tol):
+    np.testing.assert_allclose(np.asarray(x, np.float32),
+                               np.asarray(y, np.float32), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("S,H,Hk,dtype,block", _STRIPS)
+def test_flash_forward_diagonal_strips(monkeypatch, S, H, Hk, dtype, block):
+    """out and lse of the strips against the reference and against the
+    masked tile (outside jit's cache, so each traces its own program)."""
+    from mxnet_tpu.ops import attention as A
+    q, k, v, _ = _strip_inputs(S, H, Hk, dtype)
+    (out, lse), (out0, lse0) = _striped_and_masked(
+        monkeypatch, lambda: A._flash_fwd.__wrapped__(
+            q, k, v, causal=True, return_lse=True, block_q=block,
+            block_k=block), q, k, block)
+    ref_tol, _, same_tol = _TOL[dtype]
+    assert out.dtype == dtype and out.shape == q.shape
+    _close(out, _attn_reference(q, k, v, True, None), ref_tol)
+    _close(lse, _ref_lse(q.astype(jnp.float32), k.astype(jnp.float32), True),
+           1e-5 if dtype == jnp.float32 else 1e-2)
+    _close(out, out0, same_tol)
+    _close(lse, lse0, 1e-6)
+
+
+@pytest.mark.parametrize("S,H,Hk,dtype,block", _STRIPS)
+def test_flash_backward_diagonal_strips(monkeypatch, S, H, Hk, dtype, block):
+    """dq, dk, dv of the merged kernel in strips against jax.vjp of the
+    reference and against the masked tile, from the same forward."""
+    from mxnet_tpu.ops import attention as A
+    q, k, v, g = _strip_inputs(S, H, Hk, dtype)
+    out, lse = A._flash_fwd(q, k, v, causal=True, return_lse=True)
+    got, masked = _striped_and_masked(
+        monkeypatch, lambda: A._flash_bwd.__wrapped__(
+            q, k, v, out, lse, g, causal=True, block_q=block,
+            block_k=block), q, k, block)
+    ref = jax.vjp(lambda a, b, c: _attn_reference(a, b, c, True, None),
+                  q, k, v)[1](g)
+    _, grad_tol, same_tol = _TOL[dtype]
+    for x, y, r in zip(got, masked, ref):
+        assert x.shape == r.shape and x.dtype == dtype
+        _close(x, r, grad_tol)
+        _close(x, y, same_tol)
+
+
+@pytest.mark.parametrize("Sq,Sk,causal,D,dtype,blocks,fwd,bwd", [
+    # (Sq, Sk, causal, D, dtype, blocks): the strips' rows (forward,
+    # backward) and the live tiles of a head (full, diagonal, masked);
+    # the forward's tile over 512 rows strips only a sequence of more
+    # than one tile
+    (1024, 1024, True, 64, jnp.bfloat16, (None, None),
+     (0, 0, 0, 1), (128, 1, 2, 0)),                         # gpt2m
+    (4096, 4096, True, 128, jnp.bfloat16, (None, None),
+     (512, 6, 4, 0), (128, 28, 8, 0)),                      # ouro
+    (2048, 2048, True, 64, jnp.bfloat16, (None, None),
+     (512, 1, 2, 0), (128, 6, 4, 0)),
+    (1100, 1100, True, 64, jnp.bfloat16, (None, None),
+     (0, 0, 0, 3), (128, 3, 3, 0)),          # 640 rows: no strips of 512
+    (512, 512, True, 64, jnp.bfloat16, (None, None),
+     (128, 0, 1, 0), (128, 0, 1, 0)),        # one tile, four strips
+    (100, 100, True, 64, jnp.bfloat16, (None, None),
+     (128, 0, 1, 0), (128, 0, 1, 0)),        # one strip: the tile masked
+    (384, 384, True, 64, jnp.float32, (128, 128),
+     (128, 3, 3, 0), (128, 3, 3, 0)),
+    # today's tiles: not causal, Sq != Sk, tiles not square
+    (1024, 1024, False, 64, jnp.bfloat16, (None, None),
+     (0, 0, 0, 0), (0, 0, 0, 0)),
+    (1100, 1100, False, 64, jnp.bfloat16, (None, None),
+     (0, 0, 0, 2), (0, 0, 0, 3)),            # K padding: the last k tiles
+    (1024, 2048, True, 64, jnp.bfloat16, (None, None),
+     (0, 0, 0, 1), (0, 0, 0, 3)),
+    (384, 384, True, 64, jnp.float32, (256, 128),
+     (0, 0, 0, 5), (0, 0, 0, 5)),
+    (4096, 4096, True, 128, jnp.float32, (None, None),
+     (0, 0, 0, 20), (128, 28, 8, 0)),        # forward 1024 x 512
+])
+def test_geometry_strips_only_a_square_causal_diagonal(Sq, Sk, causal, D,
+                                                       dtype, blocks, fwd,
+                                                       bwd):
+    """The path is chosen by causal, Sq == Sk and square tiles alone (a
+    forward tile over 512 rows by more than one of them); the strips are
+    _DIAG_STRIP's rows where they divide the tile, and the tile kinds
+    count one head's live tiles."""
+    from mxnet_tpu.ops import attention as A
+    q = jax.ShapeDtypeStruct((1, 4, Sq, D), dtype)
+    k = jax.ShapeDtypeStruct((1, 2, Sk, D), dtype)
+    for forward, want in ((True, fwd), (False, bwd)):
+        geo = A._geometry(q, k, *blocks, forward, causal=causal)
+        kinds = A._tile_kinds(geo, causal, geo.diag_strip)
+        assert (geo.diag_strip, kinds["tiles_full"], kinds["tiles_diag"],
+                kinds["tiles_masked"]) == want
+        if geo.diag_strip:
+            assert geo.block_q == geo.block_k
+            assert geo.block_q % geo.diag_strip == 0
+            assert geo.diag_strip % A._LANES == 0
+        # the strips change no tile and no count
+        assert geo[:-1] == A._geometry(q, k, *blocks, forward)[:-1]
 
 
 def test_flash_blocks_must_be_lane_multiples():
@@ -449,7 +594,8 @@ def test_geometry_instant_once_a_compile_never_per_call(monkeypatch):
             "kernel": "flash_fwd", "Sq": 200, "Sk": 136, "D": 24, "G": 2,
             "causal": False, "block_q": 256, "block_k": 256,
             "grid": [4, 1, 1], "grid_steps": 4, "derived": True,
-            "vmem_bytes": fwd["vmem_bytes"]}
+            "vmem_bytes": fwd["vmem_bytes"], "diag_strip": 0,
+            "tiles_full": 0, "tiles_diag": 0, "tiles_masked": 1}
         assert 0 < fwd["vmem_bytes"] < 14 << 20
         for _ in range(3):
             flash_attention(q, k, v, False, None)
@@ -494,6 +640,37 @@ def test_geometry_instant_once_a_compile_never_per_call(monkeypatch):
     # off: a fresh compile says nothing
     flash_attention(q, k, v, True, None)
     assert said() == []
+
+
+@pytest.mark.parametrize("causal,kinds", [
+    # (diag_strip, tiles_full, tiles_diag, tiles_masked) of flash_fwd and
+    # flash_bwd_dkv_dq: 3 x 3 tiles of 128, a head's live ones by kind
+    (True, [(128, 3, 3, 0), (128, 3, 3, 0)]),
+    (False, [(0, 0, 0, 0), (0, 0, 0, 0)]),
+])
+def test_geometry_instant_counts_the_tiles_by_kind(monkeypatch, causal,
+                                                   kinds):
+    """How often the strips engage: the instant of each kernel says the
+    strips' rows and one head's live tiles below the diagonal, on it and
+    masked whole; a call that is not causal strips nothing."""
+    from mxnet_tpu import tracing
+    q = jnp.ones((1, 4, 384, 40), jnp.float32)
+    k = v = jnp.ones((1, 2, 384, 40), jnp.float32)
+    monkeypatch.setenv("MXNET_TRACE", "1")
+    tracing.reconfigure()
+    try:
+        tracing.reset()
+        jax.grad(lambda a, b, c: jnp.sum(flash_attention(
+            a, b, c, causal, None, 128, 128)), argnums=(0, 1, 2))(q, k, v)
+        got = [r["args"] for r in tracing.ring_records()
+               if r["name"] == "mx.attention.geometry"]
+    finally:
+        monkeypatch.delenv("MXNET_TRACE")
+        tracing.reconfigure()
+        tracing.reset()
+    assert [a["kernel"] for a in got] == ["flash_fwd", "flash_bwd_dkv_dq"]
+    assert [(a["diag_strip"], a["tiles_full"], a["tiles_diag"],
+             a["tiles_masked"]) for a in got] == kinds
 
 
 # --- Ulysses all-to-all sequence parallelism (parallel/ulysses.py) ---------
@@ -714,7 +891,8 @@ def test_flash_kernels_cross_lower_for_tpu(kernel, D, Hk, S):
             q, kv, kv, q, lse, q)
         # H 4 / Hk 1 at S 4096 is 16384 rows of dQ a KV head: over the
         # count, so that one lowers the two kernels
-        merged = A._geometry(q, kv, None, None, forward=False).merged
+        geo = A._geometry(q, kv, None, None, forward=False, causal=True)
+        merged = geo.merged
         assert merged == ((S, Hk) != (4096, 1))
         names = ["flash_bwd_dkv_dq"] if merged \
             else ["flash_bwd_dq", "flash_bwd_dkv"]
@@ -725,6 +903,12 @@ def test_flash_kernels_cross_lower_for_tpu(kernel, D, Hk, S):
                 return_lse=kernel == "fwd_lse"),
             q, kv, kv)
         names = ["flash_fwd"]
+        geo = A._geometry(q, kv, None, None, forward=True, causal=True)
+    # the backward runs its diagonal in strips, the forward where its
+    # tile is 512 rows or fewer or the sequence more than one tile
+    assert geo.diag_strip == (128 if kernel == "bwd" or S == 512 else
+                              512 if S == 4096 else 0)
+    assert geo.block_q == geo.block_k
     assert txt.count("tpu_custom_call") == len(names)
     for n in names:
         assert 'kernel_name = "%s"' % n in txt
