@@ -27,8 +27,9 @@ Phase C  four chips (only when JAX reports >= 4 devices): the Phase A
 
 Depth is not cut; weights and data are random from fixed seeds.  The last
 stdout line is ``{"ok": true, "device": {...}}`` with the device as JAX
-reports it; the line before it is a report (phases run, wall and compile
-seconds, compile-cache hits) for the records.
+reports it; the line before it is a report (phases run, wall seconds,
+the compile ledger's trace, lower and compile seconds and cache outcomes)
+for the records.
 """
 import gc
 import json
@@ -55,32 +56,8 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 import mxnet_tpu as mx  # noqa: E402
-from mxnet_tpu import models  # noqa: E402
+from mxnet_tpu import models, tracing  # noqa: E402
 from mxnet_tpu.ops import attention  # noqa: E402
-
-# -- what the run spent compiling (jax's own event stream) -----------------
-CLOCK = {"backend_compile_s": 0.0, "trace_lower_s": 0.0,
-         "cache_hits": 0, "cache_misses": 0}
-
-
-def _on_duration(event, secs, **_):
-    if event.endswith("backend_compile_duration"):
-        CLOCK["backend_compile_s"] += secs
-    elif event.endswith(("jaxpr_trace_duration",
-                         "jaxpr_to_mlir_module_duration")):
-        CLOCK["trace_lower_s"] += secs
-
-
-def _on_event(event, **_):
-    if event.endswith("compilation_cache/cache_hits"):
-        CLOCK["cache_hits"] += 1
-    elif event.endswith("compilation_cache/cache_misses"):
-        CLOCK["cache_misses"] += 1
-
-
-jax.monitoring.register_event_duration_secs_listener(_on_duration)
-jax.monitoring.register_event_listener(_on_event)
-
 
 def say(msg):
     print("[chip_smoke +%6.1fs] %s" % (time.perf_counter() - T0, msg),
@@ -361,8 +338,12 @@ def main():
             % len(DEVICES))
         report["phases"]["C"] = "not run: %d device(s)" % len(DEVICES)
     report["wall_s"] = round(time.perf_counter() - T0, 1)
-    report.update({k: round(v, 1) if isinstance(v, float) else v
-                   for k, v in CLOCK.items()})
+    # what the run spent tracing, lowering and compiling: the program's
+    # compile ledger, each stage's union of top-level records
+    compiles = tracing.stats()["compiles"]
+    report.update({"%s_s" % k: round(v, 1)
+                   for k, v in compiles["seconds"].items()})
+    report.update({"cache_%s" % k: n for k, n in compiles["cache"].items()})
     report["jax"] = jax.__version__
     print(json.dumps({"report": report}), flush=True)
     print(json.dumps({"ok": True, "device": {

@@ -50,11 +50,11 @@ def _jit_leaf_key(v):
             "committed": bool(getattr(v, "committed", False))}
 
 
-def _report_step_compile(instant, what, moved):
+def _report_step_compile(instant, what, moved, **extra):
     """One stderr line and one trace instant for a fused step that was
     compiled or built again: ``moved`` maps (field, before, after) to the
     labels it moved on; the line names at most six labels a field, the
-    instant's args carry all of them."""
+    instant's args carry all of them, and ``extra`` too."""
     parts = ["%s %s -> %s on %d: %s%s"
              % (field, was, now, len(labels), ", ".join(labels[:6]),
                 " (+%d more)" % (len(labels) - 6) if len(labels) > 6 else "")
@@ -62,10 +62,11 @@ def _report_step_compile(instant, what, moved):
     line = "%s: %s" % (what, "; ".join(parts) or
                        "nothing jit keys on differs (the cache was "
                        "cleared, or a transform's context changed)")
-    _tracing.instant(instant, "module", args={
-        "moved": [{"field": f, "before": str(a), "after": str(b),
-                   "leaves": labels}
-                  for (f, a, b), labels in moved.items()]})
+    if extra:
+        line += " [%s]" % ", ".join("%s %s" % kv for kv in extra.items())
+    _tracing.instant(instant, "module", args=dict(extra, moved=[
+        {"field": f, "before": str(a), "after": str(b), "leaves": labels}
+        for (f, a, b), labels in moved.items()]))
     print("mxnet_tpu: " + line, file=sys.stderr, flush=True)
 
 
@@ -664,6 +665,9 @@ class Module(BaseModule):
         if sp is not None:
             sp.args = {"step": t}
         _prof.record_dispatch("fused_step.dispatch")
+        # one integer read a step: where the compile ledger stood, so a
+        # compile inside this call can be filed afterwards
+        since = _tracing.compile_count()
         with _tracing.span("mx.module.update.call", "module"):
             outs, new_aux, new_params, new_states = \
                 self._fused_step(*args)
@@ -671,7 +675,7 @@ class Module(BaseModule):
         # this call's arguments apart from the last compile's
         entries = self._fused_step._cache_size()
         if entries != self._fused_cache_size:
-            self._note_step_compiled(names, args, entries)
+            self._note_step_compiled(names, args, entries, since, t)
         with _tracing.span("mx.module.update.writeback", "module"):
             exec_ = self._exec
             if exec_._out_arrays is not None:
@@ -707,13 +711,14 @@ class Module(BaseModule):
         leaves.append(("t", t))
         return leaves
 
-    def _note_step_compiled(self, names, args, entries):
+    def _note_step_compiled(self, names, args, entries, since, step):
         """The fused step's jit cache grew at this call.  The first entry
         is the expected compile; a later one is a recompile: counted
-        (``fused_step.recompile``), marked in the trace, and explained on
-        stderr by what differs, leaf by leaf, between this call's
-        arguments and those of the last compile in what ``jit`` keys on.
-        Runs only when the cache grows."""
+        (``fused_step.recompile``), timed (the compile ledger's records
+        since ``since``, filed as phase ``mx.module.recompile``), marked
+        in the trace, and explained on stderr by what differs, leaf by
+        leaf, between this call's arguments and those of the last compile
+        in what ``jit`` keys on.  Runs only when the cache grows."""
         key = {label: _jit_leaf_key(v)
                for label, v in self._step_arg_leaves(names, args)}
         before, self._fused_jit_key = self._fused_jit_key, key
@@ -721,6 +726,7 @@ class Module(BaseModule):
         if entries <= 1 or before is None:
             return
         _prof.record_dispatch("fused_step.recompile")
+        seconds, cache = _tracing.file_compiles("mx.module.recompile", since)
         moved = {}      # (field, before, after) -> [leaf labels]
         for label, now in key.items():
             was = before.get(label, {})
@@ -732,7 +738,8 @@ class Module(BaseModule):
             "mx.module.update.recompile",
             "the fused step compiled again (jit cache entry %d): between "
             "the last compile's arguments and this call's" % entries,
-            moved)
+            moved, seconds=round(seconds, 6), step=step,
+            cache="/".join(cache) or "none")
 
     def _report_rebuild(self, was, now):
         """The optimizer's hyperparameter signature moved between two

@@ -47,6 +47,12 @@ host-side and share :func:`now_us`: the ring/journal above
 it runs (``profiler`` registers itself through :func:`set_chrome_sink`).
 Set-up phases (:func:`phase`) also feed an always-on clock,
 :func:`phase_seconds`: a handful of entries a process, never per step.
+
+**The compile ledger** (:func:`compile_records`), always on like the
+phase clock: jax's own trace, lowering and backend-compile events, one
+record each, named by the program jax names and filed under the innermost
+set-up phase open on the thread.  Nested events of one stage fold into
+the record that encloses them, so every sum over the ledger is a union.
 """
 from __future__ import annotations
 
@@ -294,22 +300,40 @@ def span(name, cat="span", ctx=None, args=None):
 
 # -- set-up phases -----------------------------------------------------------
 _phases: dict = {}
+# {phase name: occurrences entered}: an occurrence is numbered as it opens,
+# so a record filed under it knows its place in phase_seconds()'s list
+_phase_entered: dict = {}
+
+
+def _phase_stack():
+    st = getattr(_tls, "phases", None)
+    if st is None:
+        st = _tls.phases = []
+    return st
 
 
 class _Phase:
-    __slots__ = ("_name", "_span", "_t0")
+    __slots__ = ("_name", "_span", "_t0", "_key")
 
     def __init__(self, name, cat):
         self._name = name
         self._span = span(name, cat)
 
     def __enter__(self):
+        with _lock:
+            occ = _phase_entered.get(self._name, 0)
+            _phase_entered[self._name] = occ + 1
+        self._key = (self._name, occ)
+        _phase_stack().append(self._key)
         self._t0 = time.monotonic_ns()
         return self._span.__enter__()
 
     def __exit__(self, *exc):
         self._span.__exit__(*exc)
         dur = (time.monotonic_ns() - self._t0) / 1e9
+        st = _phase_stack()
+        if self._key in st:
+            st.remove(self._key)
         with _lock:
             _phases.setdefault(self._name, []).append(dur)
 
@@ -325,6 +349,154 @@ def phase_seconds() -> dict:
     """{phase name: [seconds of each occurrence, in order]}."""
     with _lock:
         return {k: list(v) for k, v in _phases.items()}
+
+
+# -- the compile ledger -----------------------------------------------------
+# jax's set-up events (jax/_src/dispatch.py log_elapsed_time): each opens
+# with record_scalar(event, start) and closes with
+# record_event_time_span(event, start, end, fun_name=...), on its thread
+_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+           "/jax/core/compile/backend_compile_duration": "compile"}
+_CACHE = {"/jax/compilation_cache/cache_hits": "hit",
+          "/jax/compilation_cache/cache_misses": "miss"}
+# top-level records only: hundreds a process.  Bounded all the same, so a
+# program that compiles a new shape forever keeps its newest records
+_ledger = deque(maxlen=16384)
+
+
+class _LedgerCounts:
+    __slots__ = ("appended", "folded", "listener_ns")
+
+    def __init__(self):
+        self.appended = 0       # records appended
+        self.folded = 0         # nested events folded into them
+        self.listener_ns = 0    # spent in the listeners
+
+
+_counts = _LedgerCounts()
+
+
+def _open_events(stage):
+    """The thread's open events of ``stage``, innermost last: for each,
+    the count of nested records folded into it so far."""
+    opened = getattr(_tls, "opened", None)
+    if opened is None:
+        opened = _tls.opened = {"trace": [], "lower": [], "compile": []}
+    return opened[stage]
+
+
+def _on_open(event, value, **_):
+    stage = _STAGES.get(event)
+    if stage is None:
+        return
+    t = time.perf_counter_ns()
+    _open_events(stage).append(0)
+    # a lost update under a thread switch costs one event's nanoseconds
+    _counts.listener_ns += time.perf_counter_ns() - t
+
+
+def _on_cache(event, **_):
+    outcome = _CACHE.get(event)
+    if outcome is not None:
+        _tls.cache = outcome
+
+
+def _on_span(event, start_time, end_time, fun_name="", **_):
+    stage = _STAGES.get(event)
+    if stage is None:
+        return
+    t = time.perf_counter_ns()
+    opened = _open_events(stage)
+    # the inner event always closes first: an event closing while one of
+    # its stage is still open on the thread folds into that one
+    nested = opened.pop() if opened else 0
+    if opened:
+        opened[-1] += nested + 1
+        _counts.listener_ns += time.perf_counter_ns() - t
+        return
+    ph = getattr(_tls, "phases", None)
+    ph, occ = ph[-1] if ph else (None, None)
+    rec = {"stage": stage, "program": str(fun_name),
+           "start_us": start_time * 1e6, "end_us": end_time * 1e6,
+           "phase": ph, "occurrence": occ, "nested": nested,
+           "tid": threading.get_ident()}
+    if stage == "compile":
+        rec["cache"] = getattr(_tls, "cache", None) or "none"
+        _tls.cache = None
+    with _lock:
+        _ledger.append(rec)
+        _counts.appended += 1
+        _counts.folded += nested
+    if _state.on:
+        args = {"program": rec["program"], "phase": ph}
+        if "cache" in rec:
+            args["cache"] = rec["cache"]
+        add_span("mx.compile." + stage, int(start_time * 1e9) - _ANCHOR_NS,
+                 int(end_time * 1e9) - _ANCHOR_NS, cat="compile", args=args)
+    _counts.listener_ns += time.perf_counter_ns() - t
+
+
+def _register_compile_listeners():
+    """Once, at import (never from :func:`reconfigure`).  The opening
+    edge (a scalar event jax sends as each event starts) is what tells a
+    nested event from a top-level one as it closes."""
+    import jax.monitoring as mon
+    mon.register_scalar_listener(_on_open)
+    mon.register_event_time_span_listener(_on_span)
+    mon.register_event_listener(_on_cache)
+
+
+def compile_records() -> list:
+    """The ledger, oldest first: one dict per top-level trace, lowering
+    or backend compile (cache load included) — ``stage`` (trace, lower,
+    compile), ``program`` (jax's name for it), ``start_us``/``end_us``
+    (:func:`now_us`'s epoch), ``phase`` and ``occurrence`` (the innermost
+    set-up phase open on the thread, None outside one), ``nested`` (records
+    of the stage folded into this one), ``tid``, and for a compile
+    ``cache``: hit, miss or none."""
+    with _lock:
+        return [dict(r) for r in _ledger]
+
+
+def compile_count() -> int:
+    """Records appended since the last :func:`reset` (a mark for
+    :func:`file_compiles`: one integer read)."""
+    return _counts.appended
+
+
+def union_seconds(records) -> float:
+    """Seconds covered by the records' [start, end] intervals, overlaps
+    counted once."""
+    total, end = 0.0, None
+    for a, b in sorted((r["start_us"], r["end_us"]) for r in records):
+        if end is None or a > end:
+            total += b - a
+            end = b
+        elif b > end:
+            total += b - end
+            end = b
+    return total / 1e6
+
+
+def file_compiles(name, since):
+    """File the records appended since ``compile_count()`` read
+    ``since`` (this thread's) under phase ``name``, as its next occurrence:
+    they are retagged, and their union goes to the phase clock.  Returns
+    (seconds, the cache outcomes of their compiles).  For a compile that
+    falls outside every phase, found afterwards (a recompile)."""
+    tid = threading.get_ident()
+    with _lock:
+        k = min(_counts.appended - since, len(_ledger))
+        recs = [r for r in list(_ledger)[len(_ledger) - k:]
+                if r["tid"] == tid] if k > 0 else []
+        occ = _phase_entered.get(name, 0)
+        _phase_entered[name] = occ + 1
+        for r in recs:
+            r["phase"], r["occurrence"] = name, occ
+        secs = union_seconds(recs)
+        _phases.setdefault(name, []).append(secs)
+    return secs, [r["cache"] for r in recs if r["stage"] == "compile"]
 
 
 def instant(name, cat="instant", args=None) -> None:
@@ -445,6 +617,7 @@ def ring_records() -> list:
 def stats() -> dict:
     """The tracing block of ``profiler.snapshot()``."""
     phases = phase_seconds()
+    records = compile_records()
     with _lock:
         return {
             "enabled": _state.on,
@@ -453,16 +626,27 @@ def stats() -> dict:
             "ring_max": _state.ring.maxlen,
             "file": trace_file_path(),
             "phases": phases,
+            "compiles": {
+                "records": len(records), "folded": _counts.folded,
+                "listener_s": _counts.listener_ns / 1e9,
+                "seconds": {s: union_seconds(
+                    [r for r in records if r["stage"] == s])
+                    for s in ("trace", "lower", "compile")},
+                "cache": {c: sum(r.get("cache") == c for r in records)
+                          for c in ("hit", "miss", "none")}},
         }
 
 
 def reset() -> None:
-    """Clear the ring, counters and phase clock (tests); the file, being
-    append-only evidence, is left alone."""
+    """Clear the ring, counters, phase clock and compile ledger (tests);
+    the file, being append-only evidence, is left alone."""
     with _lock:
         _state.ring.clear()
         _state.recorded = 0
         _phases.clear()
+        _phase_entered.clear()
+        _ledger.clear()
+        _counts.__init__()
 
 
 def read_trace_file(path) -> list:
@@ -489,4 +673,5 @@ def read_trace_file(path) -> list:
 
 
 reconfigure()
+_register_compile_listeners()
 atexit.register(flush)

@@ -248,9 +248,12 @@ def test_setup_phases_feed_an_always_on_clock():
     step(mod, batch())
     step(mod, batch())
     phases = profiler.phase_seconds()
+    # the second update compiles the step again (its momentum turned
+    # committed), found afterwards and filed as a phase of its own
     assert set(phases) == {"mx.module.bind", "mx.module.init_params",
                            "mx.module.init_optimizer",
-                           "mx.module.build_step", "mx.module.first_update"}
+                           "mx.module.build_step", "mx.module.first_update",
+                           "mx.module.recompile"}
     assert all(len(v) == 1 and v[0] > 0 for v in phases.values())
     # the step is built, traced and compiled inside the first update
     assert phases["mx.module.first_update"][0] \
@@ -330,3 +333,146 @@ def test_a_moved_hyperparameter_rebuilds_the_step_and_says_which(capfd):
     assert profiler.dispatch_counts()["fused_step.rebuild"] == 1
     assert len(profiler.phase_seconds()["mx.module.build_step"]) == 2
     assert len(profiler.phase_seconds()["mx.module.first_update"]) == 1
+
+
+# -- the compile ledger ----------------------------------------------------------
+def fresh_module(hidden):
+    """A Module whose shapes no other test compiles: its init_params and
+    bind trace and compile programs of their own."""
+    net = mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=hidden,
+                                name="fc_fresh")
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    mod = mx.mod.Module(net, context=mx.cpu())
+    mod.bind(data_shapes=[("data", (3, hidden + 2))],
+             label_shapes=[("softmax_label", (3,))])
+    mod.init_params(mx.initializer.Xavier())
+    mod.init_optimizer(optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1,
+                                         "momentum": 0.9})
+    return mod
+
+
+def fresh_batch(hidden):
+    rs = np.random.RandomState(hidden)
+    return mx.io.DataBatch(
+        data=[mx.nd.array(rs.rand(3, hidden + 2).astype("f"))],
+        label=[mx.nd.array(np.array([0, 1, 2], "f"))])
+
+
+def test_setup_is_top_level_records_under_the_phase_that_asked():
+    mod = fresh_module(37)
+    step(mod, fresh_batch(37))
+    recs = tracing.compile_records()
+    phases = {r["phase"] for r in recs}
+    assert {"mx.module.bind", "mx.module.init_params",
+            "mx.module.first_update"} <= phases
+    first = [(r["stage"], r["program"]) for r in recs
+             if r["phase"] == "mx.module.first_update"
+             and r["occurrence"] == 0]
+    assert ("trace", "mx_fused_step") in first
+    assert ("lower", "jit(mx_fused_step)") in first
+    assert ("compile", "jit(mx_fused_step)") in first
+    for r in recs:
+        assert r["stage"] in ("trace", "lower", "compile")
+        assert r["end_us"] >= r["start_us"]
+        assert (r.get("cache") in ("hit", "miss", "none")) \
+            == (r["stage"] == "compile")
+    # the anchored clock's epoch: the records end before now
+    assert max(r["end_us"] for r in recs) <= tracing.now_us() + 1e5
+    summary = tracing.stats()["compiles"]
+    assert summary["records"] == len(recs) and summary["folded"] > 0
+    assert summary["seconds"]["compile"] > 0 and summary["listener_s"] > 0
+
+
+def test_nested_traces_fold_into_the_one_that_encloses_them():
+    mod = fresh_module(41)
+    n0 = tracing.compile_count()
+    t = time.perf_counter()
+    step(mod, fresh_batch(41))
+    wall = time.perf_counter() - t
+    recs = tracing.compile_records()[n0:]
+    traces = [r for r in recs if r["stage"] == "trace"]
+    (fused,) = [r for r in traces if r["program"] == "mx_fused_step"]
+    assert fused["nested"] > 0
+    assert sum((r["end_us"] - r["start_us"]) / 1e6 for r in traces) <= wall
+    # no trace record lies inside another of the same thread
+    for a in traces:
+        for b in traces:
+            assert a is b or a["tid"] != b["tid"] or not (
+                b["start_us"] <= a["start_us"]
+                and a["end_us"] <= b["end_us"])
+
+
+def test_later_steps_add_no_record():
+    mod = fresh_module(43)
+    step(mod, fresh_batch(43))
+    step(mod, fresh_batch(43))
+    n = tracing.compile_count()
+    for i in range(3):
+        step(mod, fresh_batch(43))
+    assert tracing.compile_count() == n
+
+
+def test_the_committed_flip_is_a_recompile_with_a_duration(monkeypatch,
+                                                          capfd):
+    mod = fresh_module(47)
+    _trace_on(monkeypatch)
+    step(mod, fresh_batch(47))
+    assert "mx.module.recompile" not in profiler.phase_seconds()
+    capfd.readouterr()
+    step(mod, fresh_batch(47))
+    (secs,) = profiler.phase_seconds()["mx.module.recompile"]
+    assert secs > 0
+    again = [r for r in tracing.compile_records()
+             if r["phase"] == "mx.module.recompile"]
+    assert {r["stage"] for r in again} == {"trace", "lower", "compile"}
+    assert tracing.union_seconds(again) == pytest.approx(secs)
+    (rec,) = [r for r in tracing.ring_records()
+              if r["name"] == "mx.module.update.recompile"]
+    assert rec["args"]["seconds"] == pytest.approx(secs, abs=1e-6)
+    assert rec["args"]["step"] == 2
+    assert rec["args"]["cache"] in ("hit", "miss", "none")
+    assert "[seconds %s, step 2, cache " % rec["args"]["seconds"] \
+        in capfd.readouterr().err
+    step(mod, fresh_batch(47))
+    assert len(profiler.phase_seconds()["mx.module.recompile"]) == 1
+
+
+def test_compiles_are_spans_under_the_switch(monkeypatch):
+    _trace_on(monkeypatch)
+    n0 = tracing.compile_count()
+    mod = fresh_module(53)
+    step(mod, fresh_batch(53))
+    ledger = tracing.compile_records()[n0:]
+    spans = [r for r in tracing.ring_records()
+             if r["name"].startswith("mx.compile.")]
+    # the top-level records alone, each once, under the phase's span
+    assert len(spans) == len(ledger)
+    assert sorted(s["name"] for s in spans) \
+        == sorted("mx.compile." + r["stage"] for r in ledger)
+    (first,) = [r for r in tracing.ring_records()
+                if r["name"] == "mx.module.first_update"]
+    (lower,) = [s for s in spans if s["name"] == "mx.compile.lower"
+                and s["args"]["program"] == "jit(mx_fused_step)"]
+    assert lower["args"]["phase"] == "mx.module.first_update"
+    # under the jitted call's span, in the first update's trace
+    (call,) = [r for r in tracing.ring_records()
+               if r["name"] == "mx.module.update.call"]
+    assert lower["parent"] == call["span"]
+    assert lower["trace"] == first["trace"]
+    assert {s["args"]["cache"] for s in spans
+            if s["name"] == "mx.compile.compile"} <= {"hit", "miss", "none"}
+
+
+def test_the_listeners_are_registered_once():
+    from jax._src import monitoring
+
+    def count():
+        return (monitoring.get_scalar_listeners().count(tracing._on_open),
+                monitoring.get_event_time_span_listeners().count(
+                    tracing._on_span),
+                monitoring.get_event_listeners().count(tracing._on_cache))
+    assert count() == (1, 1, 1)
+    tracing.reconfigure()
+    tracing.reconfigure()
+    assert count() == (1, 1, 1)
