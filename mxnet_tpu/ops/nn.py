@@ -327,6 +327,90 @@ def _upsampling(*args, scale=1, sample_type="nearest", num_args=1,
 # --------------------------------------------------------------------------
 # Normalization (reference: batch_norm.cc, instance_norm.cc, lrn.cc)
 # --------------------------------------------------------------------------
+def _bn_geometry(data, axis):
+    red = tuple(i for i in range(data.ndim) if i != axis)
+    bshape = tuple(data.shape[axis] if i == axis else 1
+                   for i in range(data.ndim))
+    n = int(np.prod([data.shape[i] for i in red]))
+    return red, bshape, n, jnp.promote_types(data.dtype, jnp.float32)
+
+
+# The one read's variance carries about 1 + 3·m1²/var times the rounding of
+# a sum about the batch mean (m1: the batch mean less the shift).  Where
+# m1²/var passes this margin times the data's precision over the
+# accumulator's, the statistics are taken again about the batch mean:
+# bfloat16 data 4 standard deviations from the shift, float32 data 1/64.
+_BN_ONE_READ_MARGIN = 2.0 ** -12
+
+
+def _bn_train_fwd(data, gamma, beta, shift, axis, eps, fix_gamma):
+    """The batch statistics, accumulated in float32, folded into a
+    per-channel scale and offset that are cast to ``data``'s dtype and
+    applied to it.  Residuals: ``data`` and per-channel vectors only.
+
+    Both sums are taken in one read, about ``shift`` (the gradient-stopped
+    moving mean: it depends on nothing computed from ``data``, so the
+    reduction can ride in the fusion that produces ``data``).  Where the
+    batch mean lies far from it, as at the first step (``shift`` 0), a
+    ``lax.cond`` reads ``data`` once more about the batch mean and
+    corrects both statistics (the corrected two-pass sums)."""
+    red, bshape, n, acc = _bn_geometry(data, axis)
+    k = shift.astype(acc)
+    xk = data.astype(acc) - k.reshape(bshape)
+    m1 = jnp.sum(xk, axis=red) / n
+    m2 = jnp.sum(xk * xk, axis=red) / n
+    var = jnp.maximum(m2 - m1 * m1, 0)
+    far = (jnp.finfo(data.dtype).eps / jnp.finfo(acc).eps
+           * _BN_ONE_READ_MARGIN)
+
+    def recentre():
+        mu = k + m1
+        xc = data.astype(acc) - mu.reshape(bshape)
+        d = jnp.sum(xc, axis=red) / n
+        return mu + d, jnp.sum(xc * xc, axis=red) / n - d * d
+
+    mean, var = lax.cond(jnp.any(m1 * m1 > far * var), recentre,
+                         lambda: (k + m1, var))
+    rstd = lax.rsqrt(var + eps)
+    g = jnp.ones_like(gamma) if fix_gamma else gamma
+    scale = g * rstd
+    offset = beta - mean * scale
+    out = (data * scale.reshape(bshape).astype(data.dtype)
+           + offset.reshape(bshape).astype(data.dtype))
+    return (out, mean, var), (data, mean, rstd, scale, gamma, beta, shift)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _bn_train(data, gamma, beta, shift, axis, eps, fix_gamma):
+    return _bn_train_fwd(data, gamma, beta, shift, axis, eps, fix_gamma)[0]
+
+
+def _bn_train_bwd(axis, eps, fix_gamma, res, cts):
+    """One multi-output reduction (``a = Σ dy``, ``b = Σ dy·x̂``) and one
+    elementwise pass, float32 arithmetic on the stored inputs, centred on
+    the batch mean; the cotangents of the ``mean`` / ``var`` outputs are
+    added exactly, folded into the pass's per-channel coefficients:
+    ``dx = scale·dy + (x − mean)·(2·d_var − scale·rstd·b) / N
+    + (d_mean − scale·a) / N``."""
+    data, mean, rstd, scale, gamma, beta, shift = res
+    dy, d_mean, d_var = cts
+    red, bshape, n, acc = _bn_geometry(data, axis)
+    xc = data.astype(acc) - mean.reshape(bshape)
+    dyf = dy.astype(acc)
+    a = jnp.sum(dyf, axis=red)
+    b = jnp.sum(dyf * xc, axis=red) * rstd
+    c_x = (2 * d_var - scale * rstd * b) / n
+    c_0 = (d_mean - scale * a) / n
+    dx = (dyf * scale.reshape(bshape) + xc * c_x.reshape(bshape)
+          + c_0.reshape(bshape))
+    dgamma = jnp.zeros_like(gamma) if fix_gamma else b.astype(gamma.dtype)
+    return (dx.astype(data.dtype), dgamma, a.astype(beta.dtype),
+            jnp.zeros_like(shift))
+
+
+_bn_train.defvjp(_bn_train_fwd, _bn_train_bwd)
+
+
 @register("BatchNorm", arg_names=["data", "gamma", "beta"],
           aux_names=["moving_mean", "moving_var"], num_aux=2, num_outputs=3,
           num_visible=1, takes_is_train=True,
@@ -348,9 +432,11 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
     float32, and only those small vectors are cast back, so the (N,C,H,W)
     activation never round-trips HBM in fp32.  gamma/beta/moving_* are
     master-precision (fp32) inputs; outputs mean/var/new_moving_* stay fp32.
+    Training runs ``_bn_train``, one custom VJP written for the fewest
+    reads of the activation; inference and ``use_global_stats`` fold the
+    moving statistics.
     """
     ax = axis % data.ndim
-    red = tuple(i for i in range(data.ndim) if i != ax)
     bshape = tuple(data.shape[ax] if i == ax else 1 for i in range(data.ndim))
     g = jnp.ones_like(gamma) if fix_gamma else gamma
     if not jnp.issubdtype(data.dtype, jnp.floating):
@@ -359,15 +445,17 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
         # promote the data path to fp32 instead
         data = data.astype(jnp.float32)
     if is_train and not use_global_stats:
-        xf = data.astype(jnp.float32)
-        mean = jnp.mean(xf, axis=red)
-        var = jnp.var(xf, axis=red)
-        scale = g * lax.rsqrt(var + eps)          # fp32 per-channel
-        offset = beta - mean * scale
-        out = (data * scale.reshape(bshape).astype(data.dtype)
-               + offset.reshape(bshape).astype(data.dtype))
-        new_mm = moving_mean * momentum + mean * (1 - momentum)
-        new_mv = moving_var * momentum + var * (1 - momentum)
+        # one count each time this path is traced (or run eagerly), never
+        # per step of a compiled program
+        from .. import profiler
+        profiler.record_dispatch("batch_norm.train_vjp")
+        out, mean, var = _bn_train(data, gamma, beta,
+                                   lax.stop_gradient(moving_mean),
+                                   ax, float(eps), bool(fix_gamma))
+        # the moving statistics carry no gradient into data
+        mean_, var_ = lax.stop_gradient(mean), lax.stop_gradient(var)
+        new_mm = moving_mean * momentum + mean_ * (1 - momentum)
+        new_mv = moving_var * momentum + var_ * (1 - momentum)
         return out, mean, var, new_mm, new_mv
     scale = g * lax.rsqrt(moving_var + eps)
     offset = beta - moving_mean * scale
